@@ -1,7 +1,7 @@
 # keto-tpu serving image.
 #
 # The compute path is JAX: on a TPU VM, base this on a libtpu-enabled
-# image (or `pip install jax[tpu]` in a derived stage) and the engine
+# image (or `pip install "jax[tpu]==0.9.0"` in a derived stage) and the engine
 # picks the chips up automatically; this default build serves on CPU —
 # identical API surface, the device engine just compiles for the host.
 # The reference ships a static Go binary in a scratch image; a JAX
@@ -13,7 +13,7 @@ COPY pyproject.toml README.md ./
 COPY ketotpu ./ketotpu
 COPY proto ./proto
 COPY spec ./spec
-RUN pip install --no-cache-dir . "jax[cpu]" grpcio protobuf pyyaml
+RUN pip install --no-cache-dir . "jax[cpu]==0.9.0" grpcio protobuf pyyaml
 
 # same default port layout as the reference (serve read 4466 / write
 # 4467 / metrics 4468 / opl 4469)

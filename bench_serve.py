@@ -2546,18 +2546,9 @@ if __name__ == "__main__":
     elif len(sys.argv) > 3 and sys.argv[3] == "batch":
         print(json.dumps(run_batch_bench(concurrency=conc, duration=secs)))
     elif len(sys.argv) > 3 and sys.argv[3] == "northstar":
-        import os
-
-        kw = {}
-        if os.environ.get("JAX_PLATFORMS") == "cpu":
-            # XLA:CPU compiles chip-shaped fused programs minutes-slow;
-            # the CI smoke leg shrinks the program (no retry lanes => no
-            # boosted bodies) and still drives the whole fused path
-            kw = dict(frontier=4096, arena=16384, fused_retry_lanes=0,
-                      max_wave=256)
         res = run_northstar_bench(
             concurrencies=(conc,) if len(sys.argv) > 4 else (1024, 4096),
-            duration=secs, **kw,
+            duration=secs,
         )
         print(json.dumps(res))
         # streaming gates ride the northstar run: the session lane must

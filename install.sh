@@ -12,8 +12,8 @@ here="$(cd "$(dirname "$0")" && pwd)"
 target="${1:-cpu}"
 
 case "$target" in
-  cpu) jax_pkg="jax[cpu]" ;;
-  tpu) jax_pkg="jax[tpu]" ;;
+  cpu) jax_pkg="jax[cpu]==0.9.0" ;;
+  tpu) jax_pkg="jax[tpu]==0.9.0" ;;
   *) echo "usage: $0 [cpu|tpu]" >&2; exit 2 ;;
 esac
 

@@ -135,6 +135,11 @@ def cmd_serve(args) -> int:
 
     if getattr(args, "worker_of", ""):
         return cmd_serve_worker(args)
+    # every serve mode below owns a device engine: place the persistent
+    # compile cache before the first compile (a worker never compiles)
+    from ketotpu import compilewatch
+
+    compilewatch.place_cache()
     if getattr(args, "standby", False):
         return cmd_serve_standby(args)
     workers = int(getattr(args, "workers", 0) or 0)
@@ -226,6 +231,10 @@ def _serve_multiprocess(args, workers: int, front_doors: int = 0) -> int:
 
     def spawn(i: int) -> "subprocess.Popen":
         env = dict(os.environ)
+        # a chip belongs to one process, and that is this one: a worker's
+        # engine is remote, so whatever it imports must find no device to
+        # take (or hang on)
+        env["JAX_PLATFORMS"] = "cpu"
         env.pop("KETO_FRONT_DOOR", None)
         if front_doors > 0:
             if i < front_doors:

@@ -1,9 +1,9 @@
 """XLA compile observatory: every backend compile counted, labelled, logged.
 
-The BENCH_r05 10M-expand cliff (a 350x throughput collapse) was a stray
-XLA recompile of a static-shape schedule landing inside a timed pass —
-and nothing in the system noticed.  This module turns that incident
-class into an alarm: a process-global listener on ``jax.monitoring``'s
+A stray XLA recompile of a static-shape schedule landing on the serving
+path (or inside a timed pass) collapses throughput by orders of magnitude,
+and nothing else in the system would notice.  This module turns that
+incident class into an alarm: a process-global listener on ``jax.monitoring``'s
 ``/jax/core/compile/backend_compile_duration`` event counts every
 backend compile, attributes it to the engine entry point that triggered
 it (host wrappers open a :func:`scope` around their dispatch), emits
@@ -29,16 +29,25 @@ Design constraints the shape of this module falls out of:
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager
 from typing import Callable, Dict, List, Optional, Union
 
+import jax
+from jax import monitoring
+
 # the monitoring event that IS "an XLA compile" (jaxpr trace / MLIR
 # lowering events also exist but fire for cache hits on some paths;
 # backend_compile only fires when XLA actually builds an executable)
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+# fired instead of a build when the persistent cache (place_cache) had
+# the executable; the compile event above still fires around the lookup,
+# with the retrieval's short duration
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 COMPILES_METRIC = "keto_xla_compiles_total"
 COMPILE_SECONDS_METRIC = "keto_xla_compile_seconds"
@@ -62,6 +71,7 @@ class CompileWatch:
         self.compile_seconds_total = 0.0
         self.per_fn: Dict[str, int] = {}
         self.compiles_after_warm = 0
+        self.cache_hits = 0  # compiles the persistent cache answered
         self._warm = False
         self._log: deque = deque(maxlen=int(log_size))
         # bound lazily by the serving registry; None in unit tests/bench
@@ -118,6 +128,11 @@ class CompileWatch:
 
     # -- listener ------------------------------------------------------------
 
+    def _on_cache_hit(self, event: str, **kwargs) -> None:
+        if event == _CACHE_HIT_EVENT:
+            with self._lock:
+                self.cache_hits += 1
+
     def _on_event(self, event: str, duration: float, **kwargs) -> None:
         if event != _COMPILE_EVENT:
             return
@@ -162,9 +177,8 @@ class CompileWatch:
         if warn and logger is not None:
             logger.warning(
                 "XLA COMPILE AFTER WARM: fn=%s sig=%s duration_ms=%.1f — a "
-                "steady-state dispatch hit an uncompiled shape (the "
-                "BENCH_r05 cliff class); audit the static jit args feeding "
-                "this entry point",
+                "steady-state dispatch hit an uncompiled shape; audit the "
+                "static jit args feeding this entry point",
                 fn, entry["signature"], entry["duration_ms"],
             )
 
@@ -178,6 +192,7 @@ class CompileWatch:
                 "per_fn": dict(self.per_fn),
                 "warm": self._warm,
                 "compiles_after_warm": self.compiles_after_warm,
+                "cache_hits": self.cache_hits,
                 "log": [dict(e) for e in self._log],
             }
 
@@ -193,12 +208,8 @@ def get() -> CompileWatch:
         with _watch_lock:
             if _watch is None:
                 w = CompileWatch()
-                try:  # pragma: no cover - exercised wherever jax is present
-                    from jax import monitoring as _mon
-
-                    _mon.register_event_duration_secs_listener(w._on_event)
-                except Exception:  # noqa: BLE001 - jax absent: counters stay 0
-                    pass
+                monitoring.register_event_duration_secs_listener(w._on_event)
+                monitoring.register_event_listener(w._on_cache_hit)
                 _watch = w
     return _watch
 
@@ -209,3 +220,46 @@ def scope(fn: str,
     """Module-level convenience: ``with compilewatch.scope("expand", sig):``"""
     with get().scope(fn, signature):
         yield
+
+
+# -- persistent compile cache --------------------------------------------------
+
+#: where compiled programs persist when the environment names no place: a
+#: fixed path inside the checkout (git-ignored).  The directory is part of
+#: the cache key, so it must not move between runs — no tempfile, pid or
+#: time in it.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
+
+
+def place_cache() -> str:
+    """Turn JAX's persistent compilation cache on; returns its directory.
+
+    Every entry point that compiles for the chip (``serve``,
+    ``chip_smoke.py``) calls this before its first compile: a cold fused
+    wave costs minutes, a cache hit seconds.  ``JAX_COMPILATION_CACHE_DIR``
+    wins when set — JAX reads it itself and nothing is set here.  The tests
+    never call this (tests/conftest.py says why)."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    return DEFAULT_CACHE_DIR
+
+
+@contextmanager
+def cache_off():
+    """No persistent cache inside the block, whatever the environment says
+    (compiles for a described, unattached chip write entries no process
+    here can read back, and warn on every later lookup)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()

@@ -1040,6 +1040,16 @@ class Registry:
                         dev = DeviceCheckEngine(
                             self.store(), self.namespace_manager(), **common
                         )
+                    import jax
+
+                    devices = jax.devices()
+                    self.logger().info(
+                        "engine.kind=tpu runs on platform=%s device_kind=%s "
+                        "count=%d%s",
+                        devices[0].platform, devices[0].device_kind,
+                        len(devices),
+                        f" (mesh over {n_mesh})" if n_mesh > 0 else "",
+                    )
                     ms = float(self.config.get("engine.coalesce_ms") or 0)
                     # concurrent single checks ride one device dispatch
                     # (engine/coalesce.py); 0 disables
@@ -1502,6 +1512,8 @@ class Registry:
                     help="batch items ridden on coalesced waves")
         m.gauge("keto_engine_oracle_fallbacks", eng.fallbacks,
                 help="queries answered by the host oracle")
+        m.gauge("keto_engine_device_failures", eng.device_failures,
+                help="device faults the host path covered for")
         m.gauge("keto_engine_device_retries", eng.retries,
                 help="queries re-run at wider device capacity")
         m.gauge("keto_engine_snapshot_rebuilds", eng.rebuilds,

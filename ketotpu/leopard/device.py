@@ -16,27 +16,19 @@ was a raw compile axis and every incremental rebuild recompiled the
 probe ON THE SERVING PATH — the `leopard_probe` AFTER-WARM warning
 class.)  Device probing is worth the dispatch overhead for large
 batches; small batches stay on the host numpy path (`closure.py`), which
-returns bit-identical verdicts.  Any device failure degrades to the host
-path (never to a wrong answer).
+returns bit-identical verdicts.  A device fault raises: the engine
+(`engine/tpu.py`) counts and logs it, then answers from the host path.
 """
 
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ketotpu import compilewatch
-
-try:  # pragma: no cover - exercised wherever jax is present
-    import jax
-    import jax.numpy as jnp
-
-    _HAS_JAX = True
-except Exception:  # pragma: no cover
-    jax = None
-    jnp = None
-    _HAS_JAX = False
 
 # below this many probes the host searchsorted wins against a device
 # round-trip (dominated by dispatch latency, not the log2(pairs) search)
@@ -66,62 +58,58 @@ def _pair_bucket(n: int, floor: int = 1024) -> int:
 
 def ship_pairs(index) -> Optional[dict]:
     """Device-put the closure pair columns (padded to a power-of-two
-    bucket); None when jax is unavailable or the index is empty.  The
-    host's sorted packed int64 keys split into two int32 columns with the
-    same lexicographic order (the packing IS the lexicographic order of
-    its halves), so a two-column binary search visits the same positions
-    the host searchsorted does."""
-    if not _HAS_JAX or index is None or len(index.elt_packed) == 0:
+    bucket); None when the index is empty.  The host's sorted packed
+    int64 keys split into two int32 columns with the same lexicographic
+    order (the packing IS the lexicographic order of its halves), so a
+    two-column binary search visits the same positions the host
+    searchsorted does."""
+    if index is None or len(index.elt_packed) == 0:
         return None
-    try:
-        n = len(index.elt_packed)
-        cap = _pair_bucket(n)
-        sets = np.full(cap, _PAIR_PAD, np.int32)
-        elts = np.full(cap, _PAIR_PAD, np.int32)
-        sets[:n] = (index.elt_packed >> 32).astype(np.int32)
-        elts[:n] = (index.elt_packed & 0x7FFFFFFF).astype(np.int32)
-        hops = np.zeros(cap, np.int32)
-        hops[:n] = index.elt_hop
-        return {
-            "sets": jax.device_put(sets),
-            "elts": jax.device_put(elts),
-            "hops": jax.device_put(hops),
-        }
-    except Exception:
-        return None
+    n = len(index.elt_packed)
+    cap = _pair_bucket(n)
+    sets = np.full(cap, _PAIR_PAD, np.int32)
+    elts = np.full(cap, _PAIR_PAD, np.int32)
+    sets[:n] = (index.elt_packed >> 32).astype(np.int32)
+    elts[:n] = (index.elt_packed & 0x7FFFFFFF).astype(np.int32)
+    hops = np.zeros(cap, np.int32)
+    hops[:n] = index.elt_hop
+    return {
+        "sets": jax.device_put(sets),
+        "elts": jax.device_put(elts),
+        "hops": jax.device_put(hops),
+    }
 
 
-if _HAS_JAX:
+def probe_in_program(sets, elts, hops, q_set, q_elt):
+    """Traced (non-jitted) probe body: one lexicographic binary
+    search per query over the two sorted int32 pair columns
+    (equivalent to the host's searchsorted over the packed int64
+    keys, which jax's default x64-disabled config cannot represent
+    on device).  The fused wave cascade (engine/fused.py) inlines
+    this as its tier-0 phase — the probe then compiles INTO the wave
+    program instead of costing its own dispatch — and the standalone
+    ``_probe`` below jits the same body for the unfused path, so
+    both paths share one definition and stay bit-identical.  A query
+    set id of -1 (ineligible row) can never match: real ids are
+    non-negative and padding is ``_PAIR_PAD``.  The unrolled step
+    count is derived from the (static) padded capacity, so the
+    compiled search is exact for any occupancy."""
+    cap = sets.shape[0]
+    steps = max(int(cap).bit_length(), 1)
+    lo = jnp.zeros(q_set.shape, jnp.int32)
+    hi = jnp.full(q_set.shape, cap, jnp.int32)
+    for _ in range(steps):
+        mid = (lo + hi) >> 1
+        ms, me = sets[mid], elts[mid]
+        less = (ms < q_set) | ((ms == q_set) & (me < q_elt))
+        lo = jnp.where(less, mid + 1, lo)
+        hi = jnp.where(less, hi, mid)
+    idx = jnp.clip(lo, 0, cap - 1)
+    hit = (sets[idx] == q_set) & (elts[idx] == q_elt)
+    return hit, jnp.where(hit, hops[idx], 0)
 
-    def probe_in_program(sets, elts, hops, q_set, q_elt):
-        """Traced (non-jitted) probe body: one lexicographic binary
-        search per query over the two sorted int32 pair columns
-        (equivalent to the host's searchsorted over the packed int64
-        keys, which jax's default x64-disabled config cannot represent
-        on device).  The fused wave cascade (engine/fused.py) inlines
-        this as its tier-0 phase — the probe then compiles INTO the wave
-        program instead of costing its own dispatch — and the standalone
-        ``_probe`` below jits the same body for the unfused path, so
-        both paths share one definition and stay bit-identical.  A query
-        set id of -1 (ineligible row) can never match: real ids are
-        non-negative and padding is ``_PAIR_PAD``.  The unrolled step
-        count is derived from the (static) padded capacity, so the
-        compiled search is exact for any occupancy."""
-        cap = sets.shape[0]
-        steps = max(int(cap).bit_length(), 1)
-        lo = jnp.zeros(q_set.shape, jnp.int32)
-        hi = jnp.full(q_set.shape, cap, jnp.int32)
-        for _ in range(steps):
-            mid = (lo + hi) >> 1
-            ms, me = sets[mid], elts[mid]
-            less = (ms < q_set) | ((ms == q_set) & (me < q_elt))
-            lo = jnp.where(less, mid + 1, lo)
-            hi = jnp.where(less, hi, mid)
-        idx = jnp.clip(lo, 0, cap - 1)
-        hit = (sets[idx] == q_set) & (elts[idx] == q_elt)
-        return hit, jnp.where(hit, hops[idx], 0)
 
-    _probe = jax.jit(probe_in_program)
+_probe = jax.jit(probe_in_program)
 
 
 def probe_pairs(
@@ -130,25 +118,22 @@ def probe_pairs(
     """Batched (hit, hop) via the device pairs; None => use host path.
     ``keys`` is the host's packed int64 array (-1 = must-miss row); the
     halves split into int32 columns for the device search."""
-    if dev is None or not _HAS_JAX or len(keys) < DEVICE_PROBE_MIN:
+    if dev is None or len(keys) < DEVICE_PROBE_MIN:
         return None
-    try:
-        q_set = np.full(pad_to, -1, np.int32)
-        q_elt = np.full(pad_to, -1, np.int32)
-        q_set[: len(keys)] = (keys >> 32).astype(np.int32)
-        q_elt[: len(keys)] = (keys & 0x7FFFFFFF).astype(np.int32)
-        # a -1 key's high half is -1 (arithmetic shift), keeping the
-        # must-miss contract: no real set id is negative
-        q_elt[: len(keys)][keys < 0] = -1
-        with compilewatch.scope(
-            "leopard_probe",
-            lambda: f"pairs={dev['sets'].shape[0]} pad={pad_to}",
-        ):
-            hit, hop = _probe(
-                dev["sets"], dev["elts"], dev["hops"], q_set, q_elt
-            )
-        hit = np.asarray(hit)[: len(keys)]
-        hop = np.asarray(hop)[: len(keys)]
-        return hit, hop
-    except Exception:
-        return None
+    q_set = np.full(pad_to, -1, np.int32)
+    q_elt = np.full(pad_to, -1, np.int32)
+    q_set[: len(keys)] = (keys >> 32).astype(np.int32)
+    q_elt[: len(keys)] = (keys & 0x7FFFFFFF).astype(np.int32)
+    # a -1 key's high half is -1 (arithmetic shift), keeping the
+    # must-miss contract: no real set id is negative
+    q_elt[: len(keys)][keys < 0] = -1
+    with compilewatch.scope(
+        "leopard_probe",
+        lambda: f"pairs={dev['sets'].shape[0]} pad={pad_to}",
+    ):
+        hit, hop = _probe(
+            dev["sets"], dev["elts"], dev["hops"], q_set, q_elt
+        )
+    hit = np.asarray(hit)[: len(keys)]
+    hop = np.asarray(hop)[: len(keys)]
+    return hit, hop

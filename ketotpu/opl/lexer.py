@@ -1,6 +1,6 @@
 """OPL tokenizer.
 
-A straightforward scanner producing the same token taxonomy as the reference
+A straightforward scanner producing the same kinds of token as the reference
 lexer (`internal/schema/lexer.go:40-89`): identifiers, string literals,
 comments, keywords (class/implements/this/ctx), multi-rune operators
 (``=>``, ``||``, ``&&``) before single-rune ones, and an error token carrying

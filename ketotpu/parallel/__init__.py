@@ -15,22 +15,6 @@ Here scale-out is a first-class device-mesh design:
   one chip's HBM (BASELINE config #5).
 """
 
-import jax
-
-if not hasattr(jax, "shard_map"):
-    # jax < 0.5 ships shard_map under experimental only (and spells the
-    # replication-check knob check_rep); every mesh program here calls
-    # jax.shard_map(..., check_vma=...), so adapt it once at import
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
-
-    jax.shard_map = _compat_shard_map
-
 from ketotpu.parallel.graphshard import (
     build_sharded_snapshot,
     sharded_check,

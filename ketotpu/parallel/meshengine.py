@@ -235,6 +235,19 @@ class MeshCheckEngine(DeviceCheckEngine):
             idx.elt_packed[shards == s] for s in range(self.n_shards)
         ]
 
+    def _place(self, stacks):
+        """Put host stacks (leading axis = shard) ON the mesh, one slice
+        per device, once.  The jitted shard_map programs take them under
+        the same sharding, so a dispatch uploads its query pack and
+        nothing else — handed over as numpy, the whole graph would ride
+        to the devices on every wave."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return jax.device_put(
+            stacks, NamedSharding(self.mesh, PartitionSpec(self.mesh_axis))
+        )
+
     def _install_device_arrays(self) -> None:
         """Ship the SHARDED stacks (base + EMPTY overlays); the replicated
         copy (only batch_expand reads it) is built lazily so device 0
@@ -242,13 +255,14 @@ class MeshCheckEngine(DeviceCheckEngine):
         self._base_device = None
         self._device_arrays = None
         self._expand_extra = None
-        self._shard_snaps, self._stacked_base = (
+        self._shard_snaps, stacked_base = (
             graphshard.build_sharded_snapshot(
                 self.store, self.namespace_manager, self.n_shards,
                 self._vocab, cols=self._cols,
                 replicate=self._replica_map,
             )
         )
+        self._stacked_base = self._place(stacked_base)
         # overlay admission checks relation-level pairs against dyn_pairs;
         # a shard's own slice sees only a subset of the graph's pairs, so
         # a write whose pair lives on other shards would spuriously
@@ -272,9 +286,10 @@ class MeshCheckEngine(DeviceCheckEngine):
         return self._array_shapes(self._stacked)
 
     def _overlay_stacks(self):
-        """Per-shard overlay arrays, padded to common shapes and stacked
-        (leading axis = shard).  Fixed shapes per rebuild: om_/ovt_ tables
-        by ``shard_pair_cap``, ov_dirty by the max shard node count."""
+        """Per-shard overlay arrays, padded to common shapes, stacked
+        (leading axis = shard) and placed on the mesh.  Fixed shapes per
+        rebuild: om_/ovt_ tables by ``shard_pair_cap``, ov_dirty by the
+        max shard node count."""
         ovs = [
             dl.overlay_arrays(o, sn, pair_cap=self.shard_pair_cap)
             for o, sn in zip(self._shard_overlays, self._shard_snaps)
@@ -292,7 +307,7 @@ class MeshCheckEngine(DeviceCheckEngine):
                 for a in arrs
             ]
             out[k] = np.stack(arrs)
-        return out
+        return self._place(out)
 
     def _overlay_apply(self, changes) -> bool:
         """Route each change to its owner shard's overlay (the same
@@ -481,7 +496,7 @@ class MeshCheckEngine(DeviceCheckEngine):
                     self._recover_shard(s)
             elif faults.shard_down(s):
                 self._shard_down[s] = True
-                self._device_failure()
+                self._device_failure(f"mesh shard {s}")
 
     def _recover_shard(self, s: int) -> None:
         """Bring a faulted shard back: re-ship its segments (the whole
@@ -1050,6 +1065,7 @@ class MeshCheckEngine(DeviceCheckEngine):
             self.store, self.namespace_manager, self.n_shards, vocab,
             cols=frozen, replicate=new_map,
         )
+        stacked_base = self._place(stacked_base)
         with self._sync_lock:
             if token != self._gen_token or pin_cursor != self._log_cursor:
                 return False  # a write landed mid-build: next tick retries

@@ -7,8 +7,7 @@ This thread closes the loop.  Every ``observability.watchdog.interval_s``
 it evaluates four rules over those surfaces:
 
 * ``after_warm_compile`` — the compile observatory counted a backend
-  compile after the engine declared itself warm (the BENCH_r05 cliff
-  class);
+  compile after the engine declared itself warm;
 * ``device_ms_drift`` — the wave ledger's device-ms p50 drifted more
   than ``drift_pct`` above a rolling baseline learned over the first
   ``baseline_waves`` waves (and re-learned after each incident);

@@ -40,6 +40,7 @@ from ketotpu.api.types import (
     DeadlineExceededError,
     KetoAPIError,
     RelationTuple,
+    SubjectSet,
     TooManyRequestsError,
 )
 from ketotpu.driver import Provider, Registry
@@ -387,6 +388,63 @@ class TestDeviceFaultFallback:
         assert eng.batch_check(queries) == [want for _, want in CASES]
         assert not dev.is_degraded()
         assert "engine" not in reg.health()
+
+
+    @pytest.mark.parametrize("surface", ["check", "block", "expand"])
+    def test_device_fault_is_loud(self, surface, caplog):
+        """A dead device dispatch is still answered, correctly, from the
+        host — so it must not be silent: the failure count reaches the
+        scrape and the fault's traceback reaches the log, at error level
+        the first time."""
+        import logging
+
+        from ketotpu.engine.columns import ColumnBlock
+        from ketotpu.engine.oracle import ExpandEngine
+
+        reg = Registry(Provider({
+            "namespaces": {
+                "location": str(FIXTURES / "rewrites_namespaces.keto.ts")
+            },
+            "engine": {"kind": "tpu", "frontier": 512, "arena": 1024,
+                       "max_batch": 128, "coalesce_ms": 0},
+        }))
+        reg.store().write_relation_tuples(
+            *[RelationTuple.from_string(s) for s in SEED_TUPLES]
+        )
+        reg.init()
+        dev = reg.check_engine()
+        queries = [RelationTuple.from_string(c) for c, _ in CASES]
+        want = [w for _, w in CASES]
+        root = SubjectSet("Folder", "keto", "viewers")
+        # the registry's logger does not propagate to the root logger
+        # caplog listens on
+        logging.getLogger("ketotpu").addHandler(caplog.handler)
+        faults.configure(device_error_rate=1.0, seed=11)
+        try:
+            if surface == "check":
+                assert dev.batch_check(queries) == want
+            elif surface == "block":
+                allowed, errs = dev.batch_check_block(
+                    ColumnBlock.from_tuples(queries)
+                )
+                assert allowed.tolist() == want and not errs
+            else:
+                tree = dev.batch_expand([root], 3)[0]
+                assert tree is not None and tree == ExpandEngine(
+                    reg.store(), max_depth=5
+                ).build_tree(root, 3)
+        finally:
+            logging.getLogger("ketotpu").removeHandler(caplog.handler)
+        reg.sample_engine_metrics()
+        assert reg.metrics().get_gauge("keto_engine_device_failures") == 1
+        assert "keto_engine_device_failures 1" in reg.metrics().exposition()
+        loud = [
+            r for r in caplog.records
+            if r.levelno == logging.ERROR and r.exc_info
+        ]
+        assert len(loud) == 1
+        assert loud[0].exc_info[0] is faults.FaultInjected
+        assert "host path answers" in loud[0].getMessage()
 
 
 # -- worker RPC: desync, reconnect backoff, budget forwarding ----------------

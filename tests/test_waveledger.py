@@ -118,6 +118,53 @@ def test_compilewatch_warm_alarm():
     assert w.snapshot()["log"][-1]["signature"] == "?"
 
 
+@pytest.fixture
+def cache_dir_restored():
+    import jax
+
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+@pytest.mark.parametrize("placed", ["by_environment", "by_default"])
+def test_compile_cache_placement(placed, monkeypatch, tmp_path,
+                                 cache_dir_restored):
+    """place_cache leaves a cache the environment placed to JAX, and
+    otherwise names one fixed directory inside the checkout: the path is
+    part of the cache key, so nothing of this process may be in it."""
+    import os
+    import tempfile
+    import time
+
+    import jax
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    if placed == "by_environment":
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compilewatch.place_cache() == str(tmp_path)
+        # nothing set in code: JAX reads the variable itself
+        assert jax.config.jax_compilation_cache_dir == before
+        return
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first = compilewatch.place_cache()
+    # another process, another time, another temp dir: the same path
+    monkeypatch.setattr(os, "getpid", lambda: 424242)
+    monkeypatch.setattr(time, "time", lambda: 1.0)
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+    assert compilewatch.place_cache() == first
+    assert first == os.path.join(repo, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == first
+
+
+def test_compilewatch_counts_cache_hits():
+    w = CompileWatch()
+    w._on_cache_hit("/jax/compilation_cache/cache_hits")
+    w._on_cache_hit("/jax/compilation_cache/cache_misses")
+    assert w.snapshot()["cache_hits"] == 1
+
+
 # -- wave <-> request cross-link ---------------------------------------------
 
 
