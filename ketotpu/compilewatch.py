@@ -239,8 +239,17 @@ def place_cache() -> str:
     Every entry point that compiles for the chip (``serve``,
     ``chip_smoke.py``) calls this before its first compile: a cold fused
     wave costs minutes, a cache hit seconds.  ``JAX_COMPILATION_CACHE_DIR``
-    wins when set — JAX reads it itself and nothing is set here.  The tests
-    never call this (tests/conftest.py says why)."""
+    wins when set — JAX reads it itself and no directory is set here.  The
+    tests never call this (tests/conftest.py says why).
+
+    A program's metadata is made part of its cache key.  By default the
+    key ignores it, so a program whose ``jax.named_scope`` names changed
+    (engine/fused.py: the tiers the profiler's trace is read by) would
+    load the executable an older tree left in the directory, which
+    carries the old names or none.  The price: source locations are
+    metadata too, so an edit that moves lines in a traced function
+    compiles once more."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

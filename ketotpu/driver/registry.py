@@ -29,7 +29,7 @@ from typing import Any, Callable, Dict, Optional
 
 import numpy as np
 
-from ketotpu import __version__, compilewatch
+from ketotpu import __version__, compilewatch, hostwaits
 from ketotpu.api.mapper import Mapper
 from ketotpu.api.uuid_map import UUIDMapper
 from ketotpu.driver.config import ConfigError, Provider
@@ -1362,6 +1362,10 @@ class Registry:
         # bind the compile observatory before the first jit fires so the
         # warm-boot compiles are already attributed and counted
         self.compile_watch()
+        # host pauses (gc, scheduler, store lock) count into THIS
+        # registry's metrics from here on; one watch a process, last
+        # bind wins (ketotpu/hostwaits.py)
+        hostwaits.pauses().bind(self.metrics(), self.logger())
         eng = self._device_engine()
         if eng is not None:
             ckpt_path = str(self.config.get("engine.checkpoint") or "")
@@ -1482,24 +1486,14 @@ class Registry:
                     help="DCN peers whose heartbeats carry a health digest")
             m.gauge("keto_fleet_peer_burn_fast_max", peer_burn,
                     help="worst fast-window SLO burn reported by any peer")
-        with self._lock:
-            ledger = self._wave_ledger
-        if ledger is not None:
-            ws = ledger.stats()
-            m = self.metrics()
-            m.gauge("keto_wave_size_mean", ws["wave_size_mean"],
-                    help="mean coalesced wave size over the ledger ring")
-            m.gauge("keto_wave_size_p95", ws["wave_size_p95"],
-                    help="p95 coalesced wave size over the ledger ring")
-            m.gauge("keto_wave_window_wait_ms_p50", ws["window_wait_ms_p50"],
-                    help="p50 per-wave median window wait (ms)")
-            m.gauge("keto_wave_device_ms_p50", ws["device_ms_p50"],
-                    help="p50 per-wave device dispatch time (ms)")
         eng = getattr(outer, "inner", outer)
         if not isinstance(eng, DeviceCheckEngine):
             return
         m = self.metrics()
         if isinstance(outer, CoalescingEngine):
+            # the states the wave threads are in right now hand over their
+            # seconds, so two scrapes' delta adds up to the time between
+            outer.flush_thread_states()
             m.gauge("keto_engine_coalesced_waves", outer.waves,
                     help="coalesced check dispatch waves")
             m.gauge("keto_engine_coalesced_checks", outer.coalesced,
@@ -1743,6 +1737,7 @@ class Registry:
             watchdogs = [self._watchdog, self._overload]
             broker = self._session_broker
             self._session_broker = None
+        hostwaits.pauses().unbind(self.metrics())
         if broker is not None:
             try:
                 broker.shutdown()

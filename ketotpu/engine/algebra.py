@@ -69,14 +69,13 @@ falls back to the sequential oracle.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ketotpu import compilewatch
+from ketotpu import compilewatch, profiler
 from ketotpu.engine import fastpath as fp
 from ketotpu.engine import hashtab
 from ketotpu.engine.optable import (
@@ -617,26 +616,30 @@ def _fast_subrun(g, fb, *, sched, max_width: int, shard=None):
     )
     occ = []  # live leaves ENTERING each level (adaptive-schedule feed)
     for i, (f, a) in enumerate(sched):
-        occ.append(jnp.sum((s["f_qid"] >= 0).astype(jnp.int32)))
-        nxt_f = sched[i + 1][0] if i + 1 < len(sched) else 1
-        children, q_found, q_over, q_dirty = fp.expand_phase(
-            g, s, arena=a, max_width=max_width,
-            probe_only=(i == len(sched) - 1),
-        )
-        if shard is not None:
-            children, q_over = _route(
-                children, n_sh, max(a // n_sh, 8), q_over, axis_name
+        with jax.named_scope(f"level{i}"):
+            occ.append(jnp.sum((s["f_qid"] >= 0).astype(jnp.int32)))
+            nxt_f = sched[i + 1][0] if i + 1 < len(sched) else 1
+            children, q_found, q_over, q_dirty = fp.expand_phase(
+                g, s, arena=a, max_width=max_width,
+                probe_only=(i == len(sched) - 1),
             )
-            # merge found bits across shards before packing so arrived
-            # children of already-found leaves die immediately
-            q_found = jax.lax.psum(q_found.astype(jnp.int32), axis_name) > 0
-        nxt, q_over = fp.pack_phase(
-            children, q_found, q_over, frontier=nxt_f, ns_dim=NS, rel_dim=R
-        )
-        s = dict(
-            nxt, q_found=q_found, q_over=q_over, q_dirty=q_dirty,
-            q_subj=s["q_subj"],
-        )
+            if shard is not None:
+                children, q_over = _route(
+                    children, n_sh, max(a // n_sh, 8), q_over, axis_name
+                )
+                # merge found bits across shards before packing so arrived
+                # children of already-found leaves die immediately
+                q_found = (
+                    jax.lax.psum(q_found.astype(jnp.int32), axis_name) > 0
+                )
+            nxt, q_over = fp.pack_phase(
+                children, q_found, q_over, frontier=nxt_f, ns_dim=NS,
+                rel_dim=R,
+            )
+            s = dict(
+                nxt, q_found=q_found, q_over=q_over, q_dirty=q_dirty,
+                q_subj=s["q_subj"],
+            )
     q_found, q_over, q_dirty = s["q_found"], s["q_over"], s["q_dirty"]
     if shard is not None:
         q_found = jax.lax.psum(q_found.astype(jnp.int32), axis_name) > 0
@@ -648,20 +651,18 @@ def _fast_subrun(g, fb, *, sched, max_width: int, shard=None):
     return q_found, q_over, q_dirty, occ
 
 
-def run_general_packed_timed(g, qpack, *, timer=None, **kw):
-    """run_general_packed plus a host wall-clock ``timer(seconds)`` callback
-    for the dispatch (trace/compile on the first shape, async enqueue
-    after).  run_general_packed itself is jitted with static argnames and
-    cannot carry host-side instrumentation."""
-    t0 = time.perf_counter()
-    with compilewatch.scope(
+def run_general_packed_timed(g, qpack, *, span=profiler.null_span, **kw):
+    """run_general_packed inside the engine span ``check_gen_dispatch``
+    (``span``: the engine's ``_span``): the dispatch's host wall time,
+    trace/compile on the first shape, async enqueue after.
+    run_general_packed itself is jitted with static argnames and cannot
+    carry host-side instrumentation."""
+    with span("check_gen_dispatch", rows=qpack.shape[1]), compilewatch.scope(
         "general_packed",
         lambda: f"Q={qpack.shape[1]} sizes={kw.get('sizes')} "
                 f"fast_b={kw.get('fast_b')}",
     ):
         out = run_general_packed(g, qpack, **kw)
-    if timer is not None:
-        timer(time.perf_counter() - t0)
     return out
 
 
@@ -777,26 +778,28 @@ def _general_body(
     # -- down pass: build the algebra skeleton ------------------------------
     levels: List[Dict[str, jax.Array]] = [_init_roots(qpack, Q)]
     level_base = 0
-    t, count, aux = _classify_level(g, levels[0], q_subj)
-    pmine = None
-    if shard is not None:
-        t, count, aux, pmine = _merge_classified(t, count, aux)
-    q_dirty = _fold_dirty(q_dirty, t, aux)
-    for A in sizes:
-        t, child, vset, q_over = _construct_level(
-            g, t, count, aux, vset, q_over,
-            A=A, level_base=level_base, max_width=max_width, Q=Q,
-            pmine=pmine,
-        )
-        if shard is not None:
-            child = _merge_child(child, pmine)
-        levels[-1] = t
-        level_base += t["kind"].shape[0]
-        levels.append(child)
-        t, count, aux = _classify_level(g, child, q_subj)
+    with jax.named_scope("level0"):
+        t, count, aux = _classify_level(g, levels[0], q_subj)
+        pmine = None
         if shard is not None:
             t, count, aux, pmine = _merge_classified(t, count, aux)
         q_dirty = _fold_dirty(q_dirty, t, aux)
+    for lvl, A in enumerate(sizes, 1):
+        with jax.named_scope(f"level{lvl}"):
+            t, child, vset, q_over = _construct_level(
+                g, t, count, aux, vset, q_over,
+                A=A, level_base=level_base, max_width=max_width, Q=Q,
+                pmine=pmine,
+            )
+            if shard is not None:
+                child = _merge_child(child, pmine)
+            levels[-1] = t
+            level_base += t["kind"].shape[0]
+            levels.append(child)
+            t, count, aux = _classify_level(g, child, q_subj)
+            if shard is not None:
+                t, count, aux, pmine = _merge_classified(t, count, aux)
+            q_dirty = _fold_dirty(q_dirty, t, aux)
     # last level: any task still needing children exhausts the level
     # budget — UNKNOWN + over (host fallback).
     # K_FAST tasks never take skeleton children (count stays 0), so they
@@ -817,10 +820,13 @@ def _general_body(
     # (the merged levels are identical on every shard, so the leaf
     # compaction and fast_id assignment form a SHARED global index space
     # — exactly what the sharded sub-run's psum-merged bits need)
-    levels, fb, q_over, fast_n = _collect_fast(levels, q_subj, q_over, fast_b, Q)
-    found, fover, fdirty, fast_occ = _fast_subrun(
-        g, fb, sched=fast_sched, max_width=max_width, shard=shard
-    )
+    with jax.named_scope("leaves"):
+        levels, fb, q_over, fast_n = _collect_fast(
+            levels, q_subj, q_over, fast_b, Q
+        )
+        found, fover, fdirty, fast_occ = _fast_subrun(
+            g, fb, sched=fast_sched, max_width=max_width, shard=shard
+        )
 
     # map leaf verdicts back: pure-OR checks with depth >= 1 are exactly
     # IS/NOT (OR swallows UNKNOWN at every level); depth <= 0 is the
@@ -847,38 +853,46 @@ def _general_body(
     # (all children of a level-L task live at level L+1 and are resolved
     # by round order; binop.go:18-73, rewrites.go:186-230 semantics)
     for L in range(len(levels) - 1, 0, -1):
-        ch, par = levels[L], levels[L - 1]
-        Fp = par["kind"].shape[0]
-        val = ch["qid"] >= 0
-        pt = jnp.where(val, jnp.clip(ch["parent"], 0, Fp - 1), Fp)
-        zero = jnp.zeros((Fp,), jnp.int32)
-        # folded-NOT parity: a negated edge delivers IS as NOT and vice
-        # versa; UNKNOWN and ERR pass through (rewrites.go:186-200)
-        eff_is = jnp.where(ch["neg"], ch["res"] == R_NOT, ch["res"] == R_IS)
-        eff_not = jnp.where(ch["neg"], ch["res"] == R_IS, ch["res"] == R_NOT)
-        nis = zero.at[pt].add(eff_is.astype(jnp.int32), mode="drop")
-        nnot = zero.at[pt].add(eff_not.astype(jnp.int32), mode="drop")
-        nerr = zero.at[pt].add((ch["res"] == R_ERR).astype(jnp.int32), mode="drop")
-        unres = (par["qid"] >= 0) & ~par["resolved"]
-        val_or = jnp.where((nis > 0) | par["seed"], R_IS, R_NOT)
-        val_and = jnp.where(nis == par["nchild"], R_IS, R_NOT)
-        val_not = jnp.where(
-            nis > 0, R_NOT, jnp.where(nnot > 0, R_IS, R_UNKNOWN)
-        )
-        val_pass = jnp.where(
-            nis > 0, R_IS, jnp.where(nnot > 0, R_NOT, R_UNKNOWN)
-        )
-        v = jnp.select(
-            [nerr > 0, par["cop"] == OP_AND, par["cop"] == OP_NOT,
-             par["cop"] == OP_PASS],
-            [jnp.full((Fp,), R_ERR, jnp.int32), val_and, val_not, val_pass],
-            val_or,
-        )
-        levels[L - 1] = dict(
-            par,
-            res=jnp.where(unres, v, par["res"]),
-            resolved=par["resolved"] | unres,
-        )
+        with jax.named_scope("up"):
+            ch, par = levels[L], levels[L - 1]
+            Fp = par["kind"].shape[0]
+            val = ch["qid"] >= 0
+            pt = jnp.where(val, jnp.clip(ch["parent"], 0, Fp - 1), Fp)
+            zero = jnp.zeros((Fp,), jnp.int32)
+            # folded-NOT parity: a negated edge delivers IS as NOT and vice
+            # versa; UNKNOWN and ERR pass through (rewrites.go:186-200)
+            eff_is = jnp.where(
+                ch["neg"], ch["res"] == R_NOT, ch["res"] == R_IS
+            )
+            eff_not = jnp.where(
+                ch["neg"], ch["res"] == R_IS, ch["res"] == R_NOT
+            )
+            nis = zero.at[pt].add(eff_is.astype(jnp.int32), mode="drop")
+            nnot = zero.at[pt].add(eff_not.astype(jnp.int32), mode="drop")
+            nerr = zero.at[pt].add(
+                (ch["res"] == R_ERR).astype(jnp.int32), mode="drop"
+            )
+            unres = (par["qid"] >= 0) & ~par["resolved"]
+            val_or = jnp.where((nis > 0) | par["seed"], R_IS, R_NOT)
+            val_and = jnp.where(nis == par["nchild"], R_IS, R_NOT)
+            val_not = jnp.where(
+                nis > 0, R_NOT, jnp.where(nnot > 0, R_IS, R_UNKNOWN)
+            )
+            val_pass = jnp.where(
+                nis > 0, R_IS, jnp.where(nnot > 0, R_NOT, R_UNKNOWN)
+            )
+            v = jnp.select(
+                [nerr > 0, par["cop"] == OP_AND, par["cop"] == OP_NOT,
+                 par["cop"] == OP_PASS],
+                [jnp.full((Fp,), R_ERR, jnp.int32), val_and, val_not,
+                 val_pass],
+                val_or,
+            )
+            levels[L - 1] = dict(
+                par,
+                res=jnp.where(unres, v, par["res"]),
+                resolved=par["resolved"] | unres,
+            )
 
     if shard is not None:
         # visited-set overflow (per-shard) and any other owner-local

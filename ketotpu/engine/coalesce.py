@@ -27,7 +27,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ketotpu import deadline, flightrec
+from ketotpu import deadline, flightrec, profiler
 from ketotpu.api.types import (
     DeadlineExceededError,
     KetoAPIError,
@@ -135,6 +135,16 @@ class CoalescingEngine:
         self._stage: Optional[queue.Queue] = (
             queue.Queue(maxsize=1) if pipeline else None
         )
+        # each wave thread's wall time, partitioned into states (seconds,
+        # keto_coalescer_thread_seconds{thread,state}; host spans
+        # keto/coalesce/<state> during a profiler capture).  Collector:
+        # idle (nothing pending), window, prepare, stage_blocked (a wave
+        # staged and one in flight); dispatcher: stage_empty, serve, file
+        # (waking the callers + the ledger record).  Unpipelined, the
+        # collector serves and files too.
+        self.thread_seconds: Dict[Tuple[str, str], float] = {}
+        self._collector_states = self._thread_states("collector")
+        self._dispatcher_states = self._thread_states("dispatcher")
         self._worker = threading.Thread(
             target=self._run, name="keto-coalescer", daemon=True
         )
@@ -406,6 +416,27 @@ class CoalescingEngine:
     def __getattr__(self, name):
         return getattr(self.inner, name)
 
+    def _thread_states(self, thread: str) -> profiler.ThreadStates:
+        def sink(state: str, seconds: float) -> None:
+            key = (thread, state)
+            self.thread_seconds[key] = (
+                self.thread_seconds.get(key, 0.0) + seconds
+            )
+            if self.metrics is not None:
+                self.metrics.counter(
+                    "keto_coalescer_thread_seconds", seconds,
+                    help="wall seconds of each coalescer thread by state",
+                    thread=thread, state=state,
+                )
+
+        return profiler.ThreadStates("keto/coalesce/", sink)
+
+    def flush_thread_states(self) -> None:
+        """Hand the seconds of the states still open to the counter (a
+        scrape calls this, so a window's delta adds up to its length)."""
+        self._collector_states.flush()
+        self._dispatcher_states.flush()
+
     def close(self) -> None:
         with self._wake:
             self._closed = True
@@ -420,14 +451,18 @@ class CoalescingEngine:
     # -- worker --------------------------------------------------------------
 
     def _run(self) -> None:
+        states = self._collector_states
         while True:
+            states.enter("idle")
             with self._wake:
                 while not self._pending and not self._closed:
                     self._wake.wait()
                 if self._closed and not self._pending:
+                    states.close()
                     if self._stage is not None:
                         self._stage.put(None)  # retire the dispatcher
                     return
+                states.enter("window")
                 # wave window: let concurrent callers pile on for the FULL
                 # window (every enqueue notifies, so loop on the deadline
                 # rather than trusting a single wait)
@@ -440,28 +475,33 @@ class CoalescingEngine:
                     if remaining <= 0:
                         break
                     self._wake.wait(remaining)
+                states.enter("prepare", rows=len(self._pending))
                 wave, self._pending = self._pending, []
                 # the wave owns its slots now: identical checks arriving
                 # from here on start a fresh flight (the cache, refilled
                 # by this wave's dispatch, catches them instead)
                 self._inflight.clear()
             if self._stage is None:
-                self._serve(wave)
+                self._serve(wave, states=states)
             else:
                 # double-buffer handoff: prep (grouping + merged-block
                 # build + vocab pre-encode) runs here on the collector
                 # while the dispatcher drives the PREVIOUS wave; put()
                 # blocks only when a wave is staged AND one is in flight
                 prepared = self._prepare(wave)
+                states.enter("stage_blocked")
                 self._stage.put((wave, prepared))
 
     def _run_dispatch(self) -> None:
+        states = self._dispatcher_states
         while True:
+            states.enter("stage_empty")
             item = self._stage.get()
             if item is None:
+                states.close()
                 return
             wave, prepared = item
-            self._serve(wave, prepared)
+            self._serve(wave, prepared, states=states)
 
     def _prepare(self, wave) -> dict:
         """Host-side wave prep, off the dispatch critical path: group by
@@ -497,7 +537,8 @@ class CoalescingEngine:
             prepared[key] = (slots, cgroups, merged)
         return prepared
 
-    def _serve(self, wave, prepared: Optional[dict] = None) -> None:
+    def _serve(self, wave, prepared: Optional[dict] = None, *,
+               states: profiler.ThreadStates) -> None:
         self.waves += 1
         # the ledger is the wave-id authority when present so flight
         # recorder entries (wave=) and /debug/waves join on the same id
@@ -505,9 +546,11 @@ class CoalescingEngine:
             self.ledger.next_wave_id() if self.ledger is not None
             else self.waves
         )
-        self.coalesced += sum(
+        rows = sum(
             len(s.block) if isinstance(s, _ColumnGroup) else 1 for s in wave
         )
+        self.coalesced += rows
+        states.enter("serve", wave=wave_id, rows=rows)
         # engine counter/phase deltas around the dispatches: only one
         # thread dispatches waves (the collector, or the dispatcher when
         # pipelining), so the deltas attribute cleanly
@@ -536,7 +579,10 @@ class CoalescingEngine:
             prepared = self._prepare(wave)
         if any(cg for _, cg, _ in prepared.values()):
             self.block_waves += 1
-        for (depth, byp), (slots, cgroups, merged) in prepared.items():
+        for k, ((depth, byp), (slots, cgroups, merged)) in enumerate(
+                prepared.items()):
+            if k:  # the group before left this thread filing
+                states.enter("serve", wave=wave_id, rows=rows)
             t_dispatch = time.perf_counter()
             for s in slots:
                 s.t_dispatch = t_dispatch
@@ -597,6 +643,7 @@ class CoalescingEngine:
                     s.error = e
             finally:
                 device_s += time.perf_counter() - t_dispatch
+                states.enter("file", wave=wave_id)
                 for s in slots:
                     s.event.set()
                 for g in cgroups:

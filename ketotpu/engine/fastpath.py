@@ -86,14 +86,13 @@ oracle run (tests/test_fastpath.py).
 from __future__ import annotations
 
 import functools
-import time
 from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ketotpu import compilewatch
+from ketotpu import compilewatch, profiler
 from ketotpu.engine import hashtab
 from ketotpu.engine.delta import OV_ADDED, OV_DELETED
 from ketotpu.engine.xutil import arena_assign
@@ -118,16 +117,19 @@ def _node_lookup(g: Dict[str, jax.Array], ns, obj, rel):
     num_rels = g["f_direct_ok"].shape[1]
     hi = ns * num_rels + rel
     ok = (ns >= 0) & (obj >= 0) & (rel >= 0)
-    idx, found = hashtab.lookup(
-        hashtab.subtables(g, "nt_"), hi, obj, probe=hashtab.SNAPSHOT_PROBE
-    )
-    found = found & ok
-    res = jnp.where(found, idx, -1)
-    if "ovt_ptr" in g:
-        vid, vfound = hashtab.lookup(
-            hashtab.subtables(g, "ovt_"), hi, obj, probe=hashtab.PROBE_SHALLOW
+    with jax.named_scope("probe/node_table"):
+        idx, found = hashtab.lookup(
+            hashtab.subtables(g, "nt_"), hi, obj,
+            probe=hashtab.SNAPSHOT_PROBE,
         )
-        res = jnp.where(ok & vfound & ~found, vid, res)
+        found = found & ok
+        res = jnp.where(found, idx, -1)
+        if "ovt_ptr" in g:
+            vid, vfound = hashtab.lookup(
+                hashtab.subtables(g, "ovt_"), hi, obj,
+                probe=hashtab.PROBE_SHALLOW,
+            )
+            res = jnp.where(ok & vfound & ~found, vid, res)
     return res.astype(jnp.int32)
 
 
@@ -135,14 +137,19 @@ def _member(g: Dict[str, jax.Array], node, subj):
     """Does tuple (node, subject) exist?  ExistsRelationTuples equivalent.
     Overlay-exact: base OR added-since-base AND NOT deleted-since-base, so
     probe verdicts always reflect the latest write."""
-    _, found = hashtab.lookup(
-        hashtab.subtables(g, "mt_"), node, subj, probe=hashtab.SNAPSHOT_PROBE
-    )
-    if "om_ptr" in g:
-        v, vf = hashtab.lookup(
-            hashtab.subtables(g, "om_"), node, subj, probe=hashtab.PROBE_SHALLOW
+    with jax.named_scope("probe/mem_table"):
+        _, found = hashtab.lookup(
+            hashtab.subtables(g, "mt_"), node, subj,
+            probe=hashtab.SNAPSHOT_PROBE,
         )
-        found = (found | (vf & (v == OV_ADDED))) & ~(vf & (v == OV_DELETED))
+        if "om_ptr" in g:
+            v, vf = hashtab.lookup(
+                hashtab.subtables(g, "om_"), node, subj,
+                probe=hashtab.PROBE_SHALLOW,
+            )
+            found = (
+                (found | (vf & (v == OV_ADDED))) & ~(vf & (v == OV_DELETED))
+            )
     return found
 
 
@@ -675,19 +682,21 @@ def _fused_body(
     s["f_depth"] = jnp.minimum(s["f_depth"], len(schedule))
     occ = []  # live items ENTERING each level (occ[0] = roots)
     for i, (f, a) in enumerate(schedule):
-        occ.append(jnp.sum((s["f_qid"] >= 0).astype(jnp.int32)))
-        nxt_f = schedule[i + 1][0] if i + 1 < len(schedule) else 1
-        children, q_found, q_over, q_dirty = expand_phase(
-            g, s, arena=a, max_width=max_width,
-            probe_only=(i == len(schedule) - 1),
-        )
-        nxt, q_over = pack_phase(
-            children, q_found, q_over, frontier=nxt_f, ns_dim=NS, rel_dim=R
-        )
-        s = dict(
-            nxt, q_found=q_found, q_over=q_over, q_dirty=q_dirty,
-            q_subj=s["q_subj"],
-        )
+        with jax.named_scope(f"level{i}"):
+            occ.append(jnp.sum((s["f_qid"] >= 0).astype(jnp.int32)))
+            nxt_f = schedule[i + 1][0] if i + 1 < len(schedule) else 1
+            children, q_found, q_over, q_dirty = expand_phase(
+                g, s, arena=a, max_width=max_width,
+                probe_only=(i == len(schedule) - 1),
+            )
+            nxt, q_over = pack_phase(
+                children, q_found, q_over, frontier=nxt_f, ns_dim=NS,
+                rel_dim=R,
+            )
+            s = dict(
+                nxt, q_found=q_found, q_over=q_over, q_dirty=q_dirty,
+                q_subj=s["q_subj"],
+            )
     return FastResult(
         found=s["q_found"], over=s["q_over"], dirty=s["q_dirty"]
     ), jnp.stack(occ)
@@ -735,13 +744,14 @@ def run_fast_packed(
     max_width: int = 100,
     boost: int = 1,
     mults: Optional[Tuple[int, ...]] = None,
-    timer=None,
+    span=profiler.null_span,
 ):
     """run_fast over a pre-packed int32[6, Q] query block; returns the
     (device) uint8 verdict array and the int32[levels] occupancy vector —
-    the caller fetches them with np.asarray when it syncs.  ``timer`` (if
-    given) receives the dispatch's host wall seconds — trace/compile on a
-    fresh shape, async enqueue after.
+    the caller fetches them with np.asarray when it syncs.  The dispatch's
+    host wall time — trace/compile on a fresh shape, async enqueue after —
+    is the engine span ``check_fast_dispatch`` (``span``: the engine's
+    ``_span``).
 
     Row 5 of ``qpack`` is the active mask, and callers may clear bits for
     queries answered before dispatch — the engine's Leopard closure index
@@ -755,13 +765,10 @@ def run_fast_packed(
     if Q > frontier:
         raise ValueError(f"batch {Q} exceeds frontier capacity {frontier}")
     sched = level_schedule(Q, frontier, arena, max_depth, boost, mults)
-    t0 = time.perf_counter()
-    with compilewatch.scope(
+    with span("check_fast_dispatch", rows=Q), compilewatch.scope(
         "fast_packed", lambda: f"Q={Q} sched={sched} width={max_width}"
     ):
         out = _run_fused_packed(g, qpack, schedule=sched, max_width=max_width)
-    if timer is not None:
-        timer(time.perf_counter() - t0)
     return out
 
 

@@ -60,14 +60,13 @@ unfused dispatch-time increments).
 from __future__ import annotations
 
 import functools
-import time
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ketotpu import compilewatch
+from ketotpu import compilewatch, profiler
 from ketotpu.engine import algebra as alg
 from ketotpu.engine import fastpath as fp
 from ketotpu.engine.optable import R_ERR
@@ -100,6 +99,11 @@ def _wave_body(
     must miss (ineligible, unknown node/subject — consistent with the
     host path, where a -1 key can never match a non-negative pair).
 
+    Each tier's operations carry a ``jax.named_scope`` (``tier/leopard``,
+    ``tier/fast``, ``tier/general``; levels and hash probes nest inside),
+    which the profiler's trace keeps per operation: device time per tier
+    is read from it (PERF.md), and no computation depends on it.
+
     Absent tiers compile OUT of the program: ``fast_sched=None`` drops
     tier 1 (and its retry lanes), ``gen=None`` drops tier 2 (and its
     retry lane), and a ``g`` without the leopard columns drops tier 0.
@@ -121,26 +125,27 @@ def _wave_body(
     # every real row of a chunk shares one rest_depth and row 0 is always
     # real (padding is appended), so q_depth[0] is the scalar the host
     # formula uses
-    if "leo_sets" in g:
-        hit, hop = leodev.probe_in_program(
-            g["leo_sets"], g["leo_elts"], g["leo_hops"],
-            qpack[8], qpack[9],
+    with jax.named_scope("tier/leopard"):
+        if "leo_sets" in g:
+            hit, hop = leodev.probe_in_program(
+                g["leo_sets"], g["leo_elts"], g["leo_hops"],
+                qpack[8], qpack[9],
+            )
+            ok_depth = hop.astype(jnp.int32) + depth_slack <= q_depth[0]
+        else:
+            hit = zeros
+            ok_depth = zeros
+        leo_ans = jnp.select(
+            [lmode == LM_PROBE, lmode == LM_ALLOW, lmode == LM_DENY,
+             lmode == LM_HIT_ONLY],
+            [ok_depth | ~hit, ones, ones, hit & ok_depth],
+            zeros,
         )
-        ok_depth = hop.astype(jnp.int32) + depth_slack <= q_depth[0]
-    else:
-        hit = zeros
-        ok_depth = zeros
-    leo_ans = jnp.select(
-        [lmode == LM_PROBE, lmode == LM_ALLOW, lmode == LM_DENY,
-         lmode == LM_HIT_ONLY],
-        [ok_depth | ~hit, ones, ones, hit & ok_depth],
-        zeros,
-    )
-    leo_allow = jnp.select(
-        [lmode == LM_PROBE, lmode == LM_ALLOW, lmode == LM_HIT_ONLY],
-        [(ok_depth | ~hit) & hit, ones, hit & ok_depth],
-        zeros,
-    )
+        leo_allow = jnp.select(
+            [lmode == LM_PROBE, lmode == LM_ALLOW, lmode == LM_HIT_ONLY],
+            [(ok_depth | ~hit) & hit, ones, hit & ok_depth],
+            zeros,
+        )
 
     # -- tier 1: fast BFS, leopard answers done-masked ---------------------
     found = zeros
@@ -148,28 +153,30 @@ def _wave_body(
     retried = zeros
     occ_tail = []
     if fast_sched is not None:
-        fast_act = fast_elig & ~leo_ans
-        fres, focc = fp._fused_body(
-            g, q_ns, q_obj, q_rel, q_subj, q_depth, fast_act,
-            schedule=fast_sched, max_width=max_width,
-        )
-        found1, dirty1 = fres.found, fres.dirty
-        found = found1
-        # in-program width escalation: the overflow tail re-walks at
-        # retry capacity inside the same program (the unfused path pays
-        # a host round-trip to gather/re-pad it); found is monotone, so
-        # lanes only ever add verdicts
-        unres = fast_act & fres.over & ~found1 & ~dirty1
-        for _ in range(retry_lanes):
-            retried = retried | unres
-            rres, _rocc = fp._fused_body(
-                g, q_ns, q_obj, q_rel, q_subj, q_depth, unres,
-                schedule=retry_sched, max_width=max_width,
+        with jax.named_scope("tier/fast"):
+            fast_act = fast_elig & ~leo_ans
+            fres, focc = fp._fused_body(
+                g, q_ns, q_obj, q_rel, q_subj, q_depth, fast_act,
+                schedule=fast_sched, max_width=max_width,
             )
-            found = found | (unres & rres.found)
-            unres = unres & (rres.over | rres.dirty) & ~rres.found
-        fast_fb = (fast_act & dirty1 & ~found1) | unres
-        occ_tail.append(focc)
+            found1, dirty1 = fres.found, fres.dirty
+            found = found1
+            # in-program width escalation: the overflow tail re-walks at
+            # retry capacity inside the same program (the unfused path pays
+            # a host round-trip to gather/re-pad it); found is monotone, so
+            # lanes only ever add verdicts
+            unres = fast_act & fres.over & ~found1 & ~dirty1
+            for _ in range(retry_lanes):
+                retried = retried | unres
+                with jax.named_scope("retry"):
+                    rres, _rocc = fp._fused_body(
+                        g, q_ns, q_obj, q_rel, q_subj, q_depth, unres,
+                        schedule=retry_sched, max_width=max_width,
+                    )
+                found = found | (unres & rres.found)
+                unres = unres & (rres.over | rres.dirty) & ~rres.found
+            fast_fb = (fast_act & dirty1 & ~found1) | unres
+            occ_tail.append(focc)
 
     # -- tier 2: general algebra, done-masked ------------------------------
     izeros = jnp.zeros((Q,), jnp.int32)
@@ -178,36 +185,38 @@ def _wave_body(
     gdirty = zeros
     gen_retried = zeros
     if gen is not None:
-        gpack = jnp.stack(
-            [q_ns, q_obj, q_rel, q_subj, q_depth, gact.astype(jnp.int32)]
-        )
-        gcodes, gocc = alg._general_body(
-            g, gpack, sizes=gen[0], fast_b=gen[1], fast_sched=gen[2],
-            max_width=max_width, vcap=gen[3],
-        )
-        gcode = (gcodes & 3).astype(jnp.int32)
-        gover = ((gcodes >> 2) & 1).astype(bool)
-        gdirty = ((gcodes >> 3) & 1).astype(bool)
-        if gen_retry is not None:
-            gunres = gact & gover & ~gdirty & (gcode != R_ERR)
-            gen_retried = gunres
-            rpack = jnp.stack(
-                [q_ns, q_obj, q_rel, q_subj, q_depth,
-                 gunres.astype(jnp.int32)]
+        with jax.named_scope("tier/general"):
+            gpack = jnp.stack(
+                [q_ns, q_obj, q_rel, q_subj, q_depth, gact.astype(jnp.int32)]
             )
-            rcodes, _rgocc = alg._general_body(
-                g, rpack, sizes=gen_retry[0], fast_b=gen_retry[1],
-                fast_sched=gen_retry[2], max_width=max_width,
-                vcap=gen_retry[3],
+            gcodes, gocc = alg._general_body(
+                g, gpack, sizes=gen[0], fast_b=gen[1], fast_sched=gen[2],
+                max_width=max_width, vcap=gen[3],
             )
-            rcode = (rcodes & 3).astype(jnp.int32)
-            rover = ((rcodes >> 2) & 1).astype(bool)
-            rdirty = ((rcodes >> 3) & 1).astype(bool)
-            gcode = jnp.where(gunres, rcode, gcode)
-            gover = jnp.where(
-                gunres, rover | rdirty | (rcode == R_ERR), gover
-            )
-        occ_tail.append(gocc)
+            gcode = (gcodes & 3).astype(jnp.int32)
+            gover = ((gcodes >> 2) & 1).astype(bool)
+            gdirty = ((gcodes >> 3) & 1).astype(bool)
+            if gen_retry is not None:
+                gunres = gact & gover & ~gdirty & (gcode != R_ERR)
+                gen_retried = gunres
+                rpack = jnp.stack(
+                    [q_ns, q_obj, q_rel, q_subj, q_depth,
+                     gunres.astype(jnp.int32)]
+                )
+                with jax.named_scope("retry"):
+                    rcodes, _rgocc = alg._general_body(
+                        g, rpack, sizes=gen_retry[0], fast_b=gen_retry[1],
+                        fast_sched=gen_retry[2], max_width=max_width,
+                        vcap=gen_retry[3],
+                    )
+                rcode = (rcodes & 3).astype(jnp.int32)
+                rover = ((rcodes >> 2) & 1).astype(bool)
+                rdirty = ((rcodes >> 3) & 1).astype(bool)
+                gcode = jnp.where(gunres, rcode, gcode)
+                gover = jnp.where(
+                    gunres, rover | rdirty | (rcode == R_ERR), gover
+                )
+            occ_tail.append(gocc)
 
     rows = (
         gcode
@@ -243,15 +252,15 @@ def run_fused_wave(
     gen_retry: Optional[Tuple],
     max_width: int = 100,
     depth_slack: int = 2,
-    timer=None,
+    span=profiler.null_span,
 ):
     """Dispatch one fused wave; returns the UNCOLLECTED int32 device array
     (the caller's single ``np.asarray`` is the wave's one D2H fetch).
-    ``timer`` receives the dispatch's host wall seconds (trace/compile on
-    a fresh shape, async enqueue after)."""
+    The dispatch's host wall time (trace/compile on a fresh shape, async
+    enqueue after) is the engine span ``check_fused_dispatch``
+    (``span``: the engine's ``_span``)."""
     Q = qpack.shape[1]
-    t0 = time.perf_counter()
-    with compilewatch.scope(
+    with span("check_fused_dispatch", rows=Q), compilewatch.scope(
         "fused_wave",
         lambda: (
             f"Q={Q} fast={fast_sched} retry={retry_sched}x{retry_lanes} "
@@ -264,6 +273,4 @@ def run_fused_wave(
             retry_lanes=retry_lanes, gen=gen, gen_retry=gen_retry,
             max_width=max_width, depth_slack=depth_slack,
         )
-    if timer is not None:
-        timer(time.perf_counter() - t0)
     return out

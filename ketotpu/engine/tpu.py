@@ -50,7 +50,7 @@ from typing import List, Optional, Sequence
 import jax
 import numpy as np
 
-from ketotpu import compilewatch, deadline, faults, flightrec
+from ketotpu import compilewatch, deadline, faults, flightrec, profiler
 from ketotpu.api.types import (
     DeadlineExceededError,
     KetoAPIError,
@@ -339,6 +339,9 @@ class DeviceCheckEngine:
         self.last_build_phases: dict = {}  # per-phase seconds of last build
 
     def _phase(self, name: str, dt: float) -> None:
+        """File ``dt`` seconds under engine phase ``name``; what a
+        :meth:`_span` does when it ends, and what the phases that are timed
+        where they run (projection build, leopard build) call."""
         self.phase_seconds[name] = self.phase_seconds.get(name, 0.0) + dt
         self.phase_counts[name] = self.phase_counts.get(name, 0) + 1
         if self.metrics is not None:
@@ -347,14 +350,15 @@ class DeviceCheckEngine:
                 help="engine phase wall time", phase=name,
             )
 
-    def _fast_timer(self, dt: float) -> None:
-        self._phase("check_fast_dispatch", dt)
-
-    def _gen_timer(self, dt: float) -> None:
-        self._phase("check_gen_dispatch", dt)
-
-    def _fused_timer(self, dt: float) -> None:
-        self._phase("check_fused_dispatch", dt)
+    def _span(self, name: str, **fields) -> profiler.Span:
+        """``with self._span("check_encode", rows=n):`` one engine phase:
+        filed by :meth:`_phase` when it ends and, during a profiler
+        capture, a host span ``keto/engine/<name>`` on the device's clock
+        (``fields`` ride on it)."""
+        return profiler.Span(
+            "keto/engine/" + name,
+            lambda dt: self._phase(name, dt), **fields,
+        )
 
     def _device_failure(self, what: str) -> None:
         """Count and log a device fault the host is about to cover for;
@@ -1295,13 +1299,12 @@ class DeviceCheckEngine:
     ) -> List[bool]:
         t_fb = time.perf_counter()
         out: List[bool] = []
-        for q in queries:
-            deadline.check("oracle fallback")
-            self.fallbacks += 1
-            out.append(bool(self.oracle.check_is_member(q, rest_depth)))
-        dt = time.perf_counter() - t_fb
-        self._phase("check_oracle_fallback", dt)
-        self._rpc_fallback_stage("check", dt)
+        with self._span("check_oracle_fallback", rows=len(queries)):
+            for q in queries:
+                deadline.check("oracle fallback")
+                self.fallbacks += 1
+                out.append(bool(self.oracle.check_is_member(q, rest_depth)))
+        self._rpc_fallback_stage("check", time.perf_counter() - t_fb)
         return out
 
     def _pad(self, arrays, n: int, qpad: int):
@@ -1365,36 +1368,40 @@ class DeviceCheckEngine:
             return None
         faults.inject("device_dispatch")
         self.dispatches += 1
-        t_enc = time.perf_counter()
-        snap, dev_arrays, overlay_active, cursor = self._sync_view()
-        enc = self._encode(snap, queries, rest_depth)
-        err, general = self._classify(snap, enc[0], enc[2])
         use_fused = self.fused_dispatch if fused is None else fused
+        with self._span("check_encode", rows=n):
+            snap, dev_arrays, overlay_active, cursor = self._sync_view()
+            enc = self._encode(snap, queries, rest_depth)
+            err, general = self._classify(snap, enc[0], enc[2])
+            if use_fused:
+                wave = self._encode_fused(
+                    queries, rest_depth, dev_arrays, cursor, enc, err,
+                    general,
+                )
+            else:
+                # Leopard first: closure-eligible fast queries resolve as
+                # one sorted-pair binary search and leave the device walk
+                # entirely (their fast_active bit drops, so the BFS does
+                # no work for them)
+                leo_res = self._leopard_answers(enc, err, general)
+                active = ~(err | general)
+                if leo_res is not None:
+                    active &= ~leo_res[1]
+                # hot-spot shield after Leopard: cached verdicts drop
+                # their queries from the device walk AND the algebra
+                # dispatch
+                cache_res = self._cache_consult(
+                    queries, rest_depth, err, general, leo_res, cursor)
+                if cache_res is not None:
+                    active &= ~cache_res[0]
+                    general = general & ~cache_res[0]
+                # pad for compile-cache reuse, but never beyond the
+                # frontier cap (max_batch <= frontier guarantees n fits)
+                qpad = min(_bucket(n), self.frontier)
+                padded = self._pad(enc, n, qpad)
+                fast_active = np.pad(active, (0, qpad - n))
         if use_fused:
-            return self._dispatch_fused(
-                queries, rest_depth, dev_arrays, cursor, enc, err,
-                general, t_enc,
-            )
-        # Leopard first: closure-eligible fast queries resolve as one
-        # sorted-pair binary search and leave the device walk entirely
-        # (their fast_active bit drops, so the BFS does no work for them)
-        leo_res = self._leopard_answers(enc, err, general)
-        active = ~(err | general)
-        if leo_res is not None:
-            active &= ~leo_res[1]
-        # hot-spot shield after Leopard: cached verdicts drop their
-        # queries from the device walk AND the algebra dispatch
-        cache_res = self._cache_consult(queries, rest_depth, err, general,
-                                        leo_res, cursor)
-        if cache_res is not None:
-            active &= ~cache_res[0]
-            general = general & ~cache_res[0]
-        # pad for compile-cache reuse, but never beyond the frontier cap
-        # (max_batch <= frontier guarantees n fits)
-        qpad = min(_bucket(n), self.frontier)
-        padded = self._pad(enc, n, qpad)
-        fast_active = np.pad(active, (0, qpad - n))
-        self._phase("check_encode", time.perf_counter() - t_enc)
+            return self._dispatch_fused(wave)
         if fast_active.any():
             # ONE packed upload + ONE packed verdict download per chunk:
             # each separate transfer is a full host-link round-trip
@@ -1410,7 +1417,7 @@ class DeviceCheckEngine:
                 max_depth=self.max_depth,
                 max_width=self.max_width,
                 mults=self._adaptive_mults(),
-                timer=self._fast_timer,
+                span=self._span,
             )
         else:
             # the whole chunk resolved off-device (closure index and/or
@@ -1427,19 +1434,18 @@ class DeviceCheckEngine:
         return (enc, err, general, res, gi, gres, dev_arrays, occ, leo_res,
                 cache_res, cursor)
 
-    def _dispatch_fused(self, queries, rest_depth, dev_arrays, cursor,
-                        enc, err, general, t_enc):
-        """Fused branch of ``_dispatch``: the whole tier cascade (leopard
+    def _encode_fused(self, queries, rest_depth, dev_arrays, cursor,
+                      enc, err, general):
+        """Host half of the fused branch of ``_dispatch`` (inside its
+        ``check_encode`` span): everything :meth:`_dispatch_fused` hands
+        to the device.  The whole tier cascade (leopard
         probe -> fast BFS -> general algebra, with bounded in-program
         retry lanes) compiles into ONE device program (engine/fused.py)
         with ONE D2H fetch at collect.  The host keeps only the leopard
         work that needs dict state (closure.prep_fused_checks) and ships
         it as per-row probe modes; answered-masks gate the later tiers
         in-program, so resolved rows are dead weight instead of
-        host-filtered between dispatches.  Returns a MUTABLE list handle
-        (same slot layout as the unfused tuple): the collector writes
-        the decoded leopard/cache slots back so ``_note_tiers`` and
-        ``_cache_fill`` read them unchanged."""
+        host-filtered between dispatches."""
         n = len(queries)
         q_ns, q_obj, q_rel, q_subj, q_depth = enc
         lmode = np.zeros(n, np.int32)
@@ -1527,14 +1533,6 @@ class DeviceCheckEngine:
         if leo_dev is not None:
             g = dict(dev_arrays, leo_sets=leo_dev["sets"],
                      leo_elts=leo_dev["elts"], leo_hops=leo_dev["hops"])
-        self._phase("check_encode", time.perf_counter() - t_enc)
-        fres = fdx.run_fused_wave(
-            g, qpack,
-            fast_sched=fast_sched, retry_sched=retry_sched,
-            retry_lanes=lanes, gen=gen, gen_retry=gen_retry,
-            max_width=self.max_width, depth_slack=leo.DEPTH_SLACK,
-            timer=self._fused_timer,
-        )
         meta = {
             "n": n, "qpad": qpad, "has_leo": has_leo,
             "flen": len(fast_sched) if fast_sched is not None else 0,
@@ -1542,8 +1540,28 @@ class DeviceCheckEngine:
                     else 0,
             "gen_fast_b": gen[1] if gen is not None else 0,
         }
-        return [enc, err, general, fres, None, meta, dev_arrays, None,
-                None, cache_res, cursor]
+        scheds = dict(
+            fast_sched=fast_sched, retry_sched=retry_sched,
+            retry_lanes=lanes, gen=gen, gen_retry=gen_retry,
+        )
+        # a MUTABLE list handle (same slot layout as the unfused tuple):
+        # _dispatch_fused fills in the device result, and the collector
+        # writes the decoded leopard/cache slots back so ``_note_tiers``
+        # and ``_cache_fill`` read them unchanged
+        handle = [enc, err, general, None, None, meta, dev_arrays, None,
+                  None, cache_res, cursor]
+        return handle, g, qpack, scheds
+
+    def _dispatch_fused(self, wave):
+        """Enqueue what :meth:`_encode_fused` prepared; returns its handle
+        with the uncollected device result in it."""
+        handle, g, qpack, scheds = wave
+        handle[3] = fdx.run_fused_wave(
+            g, qpack, **scheds,
+            max_width=self.max_width, depth_slack=leo.DEPTH_SLACK,
+            span=self._span,
+        )
+        return handle
 
     def _cache_consult(self, queries, rest_depth, err, general, leo_res,
                        cursor):
@@ -1564,15 +1582,14 @@ class DeviceCheckEngine:
         idx = np.flatnonzero(eligible)
         if len(idx) == 0:
             return None
-        t0 = time.perf_counter()
-        hits = rc.lookup_many(self._qkeys(queries, idx, rest_depth))
-        cached = np.zeros(err.shape[0], bool)
-        vals = np.zeros(err.shape[0], bool)
-        for i, h in zip(idx, hits):
-            if h is not None:
-                cached[i] = True
-                vals[i] = bool(h.value)
-        self._phase("check_cache", time.perf_counter() - t0)
+        with self._span("check_cache", rows=len(idx)):
+            hits = rc.lookup_many(self._qkeys(queries, idx, rest_depth))
+            cached = np.zeros(err.shape[0], bool)
+            vals = np.zeros(err.shape[0], bool)
+            for i, h in zip(idx, hits):
+                if h is not None:
+                    cached[i] = True
+                    vals[i] = bool(h.value)
         if not cached.any():
             return None
         return cached, vals
@@ -1604,11 +1621,10 @@ class DeviceCheckEngine:
         idx = np.flatnonzero(fresh)
         if len(idx) == 0:
             return
-        t0 = time.perf_counter()
-        keys = self._qkeys(queries, idx, rest_depth)
-        for i, key in zip(idx, keys):
-            rc.insert(key, bool(allowed[i]), cursor)
-        self._phase("check_cache_fill", time.perf_counter() - t0)
+        with self._span("check_cache_fill", rows=len(idx)):
+            keys = self._qkeys(queries, idx, rest_depth)
+            for i, key in zip(idx, keys):
+                rc.insert(key, bool(allowed[i]), cursor)
 
     def _gen_schedule(self, q: int, boost: int):
         """Static shapes for one fused algebra dispatch (engine/algebra.py).
@@ -1748,7 +1764,7 @@ class DeviceCheckEngine:
             fast_sched=fast_sched,
             max_width=self.max_width,
             vcap=vcap,
-            timer=self._gen_timer,
+            span=self._span,
         )
         return codes, occ, n, fast_b
 
@@ -1767,10 +1783,9 @@ class DeviceCheckEngine:
         fallback = err.copy()
 
         if gres is not None:
-            t_sync = time.perf_counter()
-            packed = np.asarray(gres[0])[: gres[2]]  # one D2H fetch
-            self._update_gen_occ(np.asarray(gres[1]), gres[3])
-            self._phase("check_collect_sync", time.perf_counter() - t_sync)
+            with self._span("check_collect_sync"):
+                packed = np.asarray(gres[0])[: gres[2]]  # one D2H fetch
+                self._update_gen_occ(np.asarray(gres[1]), gres[3])
             codes = (packed & 3).astype(np.int8)
             gover = ((packed >> 2) & 1).astype(bool)
             # dirty: the skeleton touched overlay-stale state (a changed
@@ -1784,32 +1799,31 @@ class DeviceCheckEngine:
             # batch => ample per-root slots) before any oracle fallback
             gunres = gover & ~gdirty & (codes != R_ERR)
             if retry and gunres.any() and self.retry_scale > 1:
-                t_retry = time.perf_counter()
                 ri = gi[np.flatnonzero(gunres)]
-                self.retries += len(ri)
-                rh = self._run_general(
-                    dev_arrays, enc, ri, boost=self.retry_scale
-                )
-                rpacked = np.asarray(rh[0])[: rh[2]]
-                rcodes = (rpacked & 3).astype(np.int8)
-                rover = ((rpacked >> 2) & 1).astype(bool)
-                rdirty = ((rpacked >> 3) & 1).astype(bool)
-                allowed[ri] = rcodes == R_IS
-                gover[gunres] = rover | rdirty | (rcodes == R_ERR)
-                codes = codes.copy()
-                codes[np.flatnonzero(gunres)] = rcodes
-                self._phase("check_retry", time.perf_counter() - t_retry)
+                with self._span("check_retry", rows=len(ri)):
+                    self.retries += len(ri)
+                    rh = self._run_general(
+                        dev_arrays, enc, ri, boost=self.retry_scale
+                    )
+                    rpacked = np.asarray(rh[0])[: rh[2]]
+                    rcodes = (rpacked & 3).astype(np.int8)
+                    rover = ((rpacked >> 2) & 1).astype(bool)
+                    rdirty = ((rpacked >> 3) & 1).astype(bool)
+                    allowed[ri] = rcodes == R_IS
+                    gover[gunres] = rover | rdirty | (rcodes == R_ERR)
+                    codes = codes.copy()
+                    codes[np.flatnonzero(gunres)] = rcodes
             fallback[gi] |= gover | gdirty | (codes == R_ERR)
 
-        t_sync = time.perf_counter()
-        if res is None:
-            # nothing was dispatched on the fast path (closure index
-            # answered everything eligible): all-zero codes, no occupancy
-            codes = np.zeros(n, np.uint8)
-        else:
-            codes = np.asarray(res)[:n]  # one D2H fetch for all 3 masks
-            self._update_occ(np.asarray(occ))
-        self._phase("check_collect_sync", time.perf_counter() - t_sync)
+        with self._span("check_collect_sync"):
+            if res is None:
+                # nothing was dispatched on the fast path (closure index
+                # answered everything eligible): all-zero codes, no
+                # occupancy
+                codes = np.zeros(n, np.uint8)
+            else:
+                codes = np.asarray(res)[:n]  # one D2H fetch, all 3 masks
+                self._update_occ(np.asarray(occ))
         found = (codes & 1).astype(bool)
         over = ((codes >> 1) & 1).astype(bool)
         dirty = ((codes >> 2) & 1).astype(bool)
@@ -1834,35 +1848,36 @@ class DeviceCheckEngine:
         # found is monotone: an overflow only voids not-yet-found queries
         unres = fmask & over & ~found & ~dirty
         if retry and unres.any() and self.retry_scale > 1:
-            t_retry = time.perf_counter()
             ri = np.flatnonzero(unres)
-            rpad = min(_bucket(len(ri), 256), self.retry_scale * self.frontier)
-            renc = self._pad(tuple(a[ri] for a in enc), len(ri), rpad)
-            self.retries += len(ri)
-            rpack = np.stack(
-                [*renc, (np.arange(rpad) < len(ri)).astype(np.int32)]
-            ).astype(np.int32)
-            rres, _roc = fp.run_fast_packed(
-                dev_arrays,
-                rpack,
-                frontier=self.retry_scale * self.frontier,
-                arena=self.retry_scale * self.arena,
-                max_depth=self.max_depth,
-                max_width=self.max_width,
-                # scale the per-query schedule too: the tail queries need
-                # retry_scale x the capacity their tier-1 share gave them,
-                # and with a small retry batch the caps alone don't bind.
-                # No adaptive mults here: the retry exists precisely because
-                # the demand-sized tier missed.
-                boost=self.retry_scale,
-            )
-            rcodes = np.asarray(rres)[: len(ri)]
-            rfound = (rcodes & 1).astype(bool)
-            rover = ((rcodes >> 1) & 1).astype(bool)
-            rdirty = ((rcodes >> 2) & 1).astype(bool)
-            allowed[ri] = rfound
-            unres[ri] = (rover | rdirty) & ~rfound
-            self._phase("check_retry", time.perf_counter() - t_retry)
+            with self._span("check_retry", rows=len(ri)):
+                rpad = min(
+                    _bucket(len(ri), 256), self.retry_scale * self.frontier
+                )
+                renc = self._pad(tuple(a[ri] for a in enc), len(ri), rpad)
+                self.retries += len(ri)
+                rpack = np.stack(
+                    [*renc, (np.arange(rpad) < len(ri)).astype(np.int32)]
+                ).astype(np.int32)
+                rres, _roc = fp.run_fast_packed(
+                    dev_arrays,
+                    rpack,
+                    frontier=self.retry_scale * self.frontier,
+                    arena=self.retry_scale * self.arena,
+                    max_depth=self.max_depth,
+                    max_width=self.max_width,
+                    # scale the per-query schedule too: the tail queries
+                    # need retry_scale x the capacity their tier-1 share
+                    # gave them, and with a small retry batch the caps
+                    # alone don't bind.  No adaptive mults here: the retry
+                    # exists precisely because the demand-sized tier missed.
+                    boost=self.retry_scale,
+                )
+                rcodes = np.asarray(rres)[: len(ri)]
+                rfound = (rcodes & 1).astype(bool)
+                rover = ((rcodes >> 1) & 1).astype(bool)
+                rdirty = ((rcodes >> 2) & 1).astype(bool)
+                allowed[ri] = rfound
+                unres[ri] = (rover | rdirty) & ~rfound
         fallback |= unres
         return allowed, fallback
 
@@ -1878,9 +1893,8 @@ class DeviceCheckEngine:
          cache_res, _cursor) = handle
         n = meta["n"]
         qpad = meta["qpad"]
-        t_sync = time.perf_counter()
-        packed = np.asarray(fres)  # the wave's single D2H fetch
-        self._phase("check_collect_sync", time.perf_counter() - t_sync)
+        with self._span("check_collect_sync", rows=n):
+            packed = np.asarray(fres)  # the wave's single D2H fetch
         self.fused_waves += 1
         self.fused_d2h_fetches += 1
         rows = packed[:n]
@@ -1996,30 +2010,31 @@ class DeviceCheckEngine:
         skip = None
         if fallback.any():
             t_fb = time.perf_counter()
-            for i in np.flatnonzero(fallback):
-                # oracle reproduces the exact verdict or typed error; a
-                # long fallback tail must not outlive the request's budget
-                deadline.check("oracle fallback")
-                self.fallbacks += 1
-                if errs is None:
-                    allowed[i] = self.oracle.check_is_member(
-                        queries[i], rest_depth
-                    )
-                    continue
-                try:
-                    allowed[i] = self.oracle.check_is_member(
-                        queries[i], rest_depth
-                    )
-                except DeadlineExceededError:
-                    raise
-                except KetoAPIError as e:
-                    errs[base + int(i)] = e
-                    if skip is None:
-                        skip = np.zeros(allowed.shape[0], bool)
-                    skip[i] = True
-            dt = time.perf_counter() - t_fb
-            self._phase("check_oracle_fallback", dt)
-            self._rpc_fallback_stage("check", dt)
+            fell = np.flatnonzero(fallback)
+            with self._span("check_oracle_fallback", rows=len(fell)):
+                for i in fell:
+                    # oracle reproduces the exact verdict or typed error;
+                    # a long fallback tail must not outlive the request's
+                    # budget
+                    deadline.check("oracle fallback")
+                    self.fallbacks += 1
+                    if errs is None:
+                        allowed[i] = self.oracle.check_is_member(
+                            queries[i], rest_depth
+                        )
+                        continue
+                    try:
+                        allowed[i] = self.oracle.check_is_member(
+                            queries[i], rest_depth
+                        )
+                    except DeadlineExceededError:
+                        raise
+                    except KetoAPIError as e:
+                        errs[base + int(i)] = e
+                        if skip is None:
+                            skip = np.zeros(allowed.shape[0], bool)
+                        skip[i] = True
+            self._rpc_fallback_stage("check", time.perf_counter() - t_fb)
         self._cache_fill(queries, handle, rest_depth, allowed, skip=skip)
         return allowed
 
@@ -2056,8 +2071,7 @@ class DeviceCheckEngine:
             # mesh engine's lazy replicated-graph device transfer (and don't
             # stall concurrent checks on the lock) for leaves
             return out
-        t_snap = time.perf_counter()
-        with self._sync_lock:
+        with self._span("expand_snapshot"), self._sync_lock:
             snap = self._snapshot_locked()
             overlay_active = self._overlay_active
             xarrays = self._expand_arrays()
@@ -2065,7 +2079,6 @@ class DeviceCheckEngine:
                 xd.OverlayMembers(self._overlay, snap, self._vocab)
                 if overlay_active else None
             )
-        self._phase("expand_snapshot", time.perf_counter() - t_snap)
         roots = [subjects[i] for i in set_idx]
         if xarrays is None:
             # mesh replica over budget: the oracle expands from the live
@@ -2075,7 +2088,6 @@ class DeviceCheckEngine:
                 self.fallbacks += 1
                 out[i] = oracle.build_tree(subjects[i], rest_depth)
             return out
-        timings: dict = {}
         try:
             faults.inject("device_dispatch")
             trees, over = xd.run_expand(
@@ -2083,7 +2095,7 @@ class DeviceCheckEngine:
                 max_depth=self.max_depth, fanout=fanout, cap=cap,
                 ov=ov,
                 sub_expand=oracle._build,
-                timings=timings,
+                span=self._span,
             )
         except KetoAPIError:
             raise
@@ -2092,30 +2104,27 @@ class DeviceCheckEngine:
             # sequential oracle (same degraded-health contract as check)
             self._device_failure("expand dispatch")
             t_fb = time.perf_counter()
-            for i in set_idx:
-                deadline.check("oracle fallback")
-                self.fallbacks += 1
-                out[i] = oracle.build_tree(subjects[i], rest_depth)
-            dt = time.perf_counter() - t_fb
-            self._phase("expand_oracle_fallback", dt)
-            self._rpc_fallback_stage("expand", dt)
+            with self._span("expand_oracle_fallback", roots=len(set_idx)):
+                for i in set_idx:
+                    deadline.check("oracle fallback")
+                    self.fallbacks += 1
+                    out[i] = oracle.build_tree(subjects[i], rest_depth)
+            self._rpc_fallback_stage("expand", time.perf_counter() - t_fb)
             return out
-        for name, dt in timings.items():
-            self._phase("expand_" + name, dt)
-        t_fb = time.perf_counter()
-        fell = False
-        for k, i in enumerate(set_idx):
-            if over[k]:
-                fell = True
-                deadline.check("oracle fallback")
-                self.fallbacks += 1
-                out[i] = oracle.build_tree(subjects[i], rest_depth)
-            else:
+        if not over.any():
+            for k, i in enumerate(set_idx):
                 out[i] = trees[k]
-        if fell:
-            dt = time.perf_counter() - t_fb
-            self._phase("expand_oracle_fallback", dt)
-            self._rpc_fallback_stage("expand", dt)
+            return out
+        t_fb = time.perf_counter()
+        with self._span("expand_oracle_fallback", roots=int(over.sum())):
+            for k, i in enumerate(set_idx):
+                if over[k]:
+                    deadline.check("oracle fallback")
+                    self.fallbacks += 1
+                    out[i] = oracle.build_tree(subjects[i], rest_depth)
+                else:
+                    out[i] = trees[k]
+        self._rpc_fallback_stage("expand", time.perf_counter() - t_fb)
         return out
 
     def batch_check_device_only(
@@ -2177,18 +2186,19 @@ class DeviceCheckEngine:
         columnar path's per-item error capture."""
         t_fb = time.perf_counter()
         out = np.zeros(len(block), bool)
-        for i in range(len(block)):
-            deadline.check("oracle fallback")
-            self.fallbacks += 1
-            try:
-                out[i] = bool(self.oracle.check_is_member(block[i], rest_depth))
-            except DeadlineExceededError:
-                raise
-            except KetoAPIError as e:
-                errs[i] = e
-        dt = time.perf_counter() - t_fb
-        self._phase("check_oracle_fallback", dt)
-        self._rpc_fallback_stage("check", dt)
+        with self._span("check_oracle_fallback", rows=len(block)):
+            for i in range(len(block)):
+                deadline.check("oracle fallback")
+                self.fallbacks += 1
+                try:
+                    out[i] = bool(
+                        self.oracle.check_is_member(block[i], rest_depth)
+                    )
+                except DeadlineExceededError:
+                    raise
+                except KetoAPIError as e:
+                    errs[i] = e
+        self._rpc_fallback_stage("check", time.perf_counter() - t_fb)
         return out
 
     # -- Leopard listing APIs ------------------------------------------------
@@ -2225,31 +2235,30 @@ class DeviceCheckEngine:
     ):
         """Objects o with ``namespace:o#relation`` reaching ``subject``
         through the set-containment closure; (objects, next_page_token)."""
-        t0 = time.perf_counter()
-        sets = None
-        with self._sync_lock:
-            self._snapshot_locked()
-            idx = self._leopard
-            if idx is not None:
-                v = self._vocab
-                lo, hi = idx.node_range(
-                    v.namespaces.lookup(namespace),
-                    v.relations.lookup(relation),
+        with self._span("list_objects"):
+            sets = None
+            with self._sync_lock:
+                self._snapshot_locked()
+                idx = self._leopard
+                if idx is not None:
+                    v = self._vocab
+                    lo, hi = idx.node_range(
+                        v.namespaces.lookup(namespace),
+                        v.relations.lookup(relation),
+                    )
+                    sets = idx.list_sets_of(v.subject_key(subject), lo, hi)
+                if sets is not None:
+                    obj_tab = self._vocab.objects.strings()
+                    objs = sorted(obj_tab[idx.node_obj(s)] for s in sets)
+            if sets is None:
+                self.leopard_list_fallbacks += 1
+                t_fb = time.perf_counter()
+                objs = leolist.host_list_objects(
+                    self.store, namespace, relation, subject
                 )
-                sets = idx.list_sets_of(v.subject_key(subject), lo, hi)
-            if sets is not None:
-                obj_tab = self._vocab.objects.strings()
-                objs = sorted(obj_tab[idx.node_obj(s)] for s in sets)
-        if sets is None:
-            self.leopard_list_fallbacks += 1
-            t_fb = time.perf_counter()
-            objs = leolist.host_list_objects(
-                self.store, namespace, relation, subject
-            )
-            self._rpc_fallback_stage(
-                "list_objects", time.perf_counter() - t_fb
-            )
-        self._phase("list_objects", time.perf_counter() - t0)
+                self._rpc_fallback_stage(
+                    "list_objects", time.perf_counter() - t_fb
+                )
         return leolist.paginate(objs, page_token, page_size)
 
     def list_subjects(
@@ -2263,35 +2272,34 @@ class DeviceCheckEngine:
     ):
         """Subjects reaching ``namespace:object#relation`` through the
         set-containment closure; (subjects, next_page_token)."""
-        t0 = time.perf_counter()
-        elems = None
-        with self._sync_lock:
-            self._snapshot_locked()
-            idx = self._leopard
-            if idx is not None:
-                v = self._vocab
-                elems = idx.list_elements(idx.node_id(
-                    v.namespaces.lookup(namespace),
-                    v.objects.lookup(object),
-                    v.relations.lookup(relation),
-                ))
-            if elems is not None:
-                subj_tab = self._vocab.subjects.strings()
-                by_uid = {
-                    subj_tab[e]: leolist.subject_from_uid(subj_tab[e])
-                    for e in elems
-                }
-        if elems is None:
-            self.leopard_list_fallbacks += 1
-            t_fb = time.perf_counter()
-            by_uid = leolist.host_list_subjects(
-                self.store, namespace, object, relation
+        with self._span("list_subjects"):
+            elems = None
+            with self._sync_lock:
+                self._snapshot_locked()
+                idx = self._leopard
+                if idx is not None:
+                    v = self._vocab
+                    elems = idx.list_elements(idx.node_id(
+                        v.namespaces.lookup(namespace),
+                        v.objects.lookup(object),
+                        v.relations.lookup(relation),
+                    ))
+                if elems is not None:
+                    subj_tab = self._vocab.subjects.strings()
+                    by_uid = {
+                        subj_tab[e]: leolist.subject_from_uid(subj_tab[e])
+                        for e in elems
+                    }
+            if elems is None:
+                self.leopard_list_fallbacks += 1
+                t_fb = time.perf_counter()
+                by_uid = leolist.host_list_subjects(
+                    self.store, namespace, object, relation
+                )
+                self._rpc_fallback_stage(
+                    "list_subjects", time.perf_counter() - t_fb
+                )
+            keys, next_token = leolist.paginate(
+                sorted(by_uid.keys()), page_token, page_size
             )
-            self._rpc_fallback_stage(
-                "list_subjects", time.perf_counter() - t_fb
-            )
-        keys, next_token = leolist.paginate(
-            sorted(by_uid.keys()), page_token, page_size
-        )
-        self._phase("list_subjects", time.perf_counter() - t0)
         return [by_uid[k] for k in keys], next_token

@@ -31,6 +31,7 @@ import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
+from ketotpu import hostwaits
 from ketotpu.observability import format_traceparent, parse_traceparent
 
 _local = threading.local()
@@ -246,11 +247,17 @@ def rpc_recording(registry, op: str, *, traceparent: Optional[str] = None,
     from every layer underneath, and files the request with the flight
     recorder on exit.  Re-entrant: a context already open on this thread
     (e.g. worker host inside a serving thread) wins and this call is a
-    pass-through.
+    pass-through.  Where a front door's pool (hostwaits.StampedPool) ran
+    this thread's call, the request starts when the call was submitted:
+    its wait for a thread is stage ``pool_wait`` and part of the total, as
+    it is of the client's.
     """
     if getattr(_local, "ctx", None) is not None:
         yield
         return
+    stamp = hostwaits.take_pool_stamp()
+    if stamp is not None:
+        t0 = stamp[0]
     metrics = registry.metrics()
     recorder = registry.flight_recorder()
     tracer = registry.tracer()
@@ -259,6 +266,8 @@ def rpc_recording(registry, op: str, *, traceparent: Optional[str] = None,
     ctx = _ReqCtx(op, detail, t0 if t0 is not None else time.perf_counter(),
                   metrics, recorder, tracer, trace)
     _local.ctx = ctx
+    if stamp is not None:
+        note_stage("pool_wait", stamp[1] - stamp[0])
     try:
         with tracer.span(f"rpc.{op}", _parent=traceparent, detail=detail):
             # capture the trace id while the span is OPEN (the recorder

@@ -96,17 +96,18 @@ def probe_in_program(sets, elts, hops, q_set, q_elt):
     compiled search is exact for any occupancy."""
     cap = sets.shape[0]
     steps = max(int(cap).bit_length(), 1)
-    lo = jnp.zeros(q_set.shape, jnp.int32)
-    hi = jnp.full(q_set.shape, cap, jnp.int32)
-    for _ in range(steps):
-        mid = (lo + hi) >> 1
-        ms, me = sets[mid], elts[mid]
-        less = (ms < q_set) | ((ms == q_set) & (me < q_elt))
-        lo = jnp.where(less, mid + 1, lo)
-        hi = jnp.where(less, hi, mid)
-    idx = jnp.clip(lo, 0, cap - 1)
-    hit = (sets[idx] == q_set) & (elts[idx] == q_elt)
-    return hit, jnp.where(hit, hops[idx], 0)
+    with jax.named_scope("probe/pairs"):
+        lo = jnp.zeros(q_set.shape, jnp.int32)
+        hi = jnp.full(q_set.shape, cap, jnp.int32)
+        for _ in range(steps):
+            mid = (lo + hi) >> 1
+            ms, me = sets[mid], elts[mid]
+            less = (ms < q_set) | ((ms == q_set) & (me < q_elt))
+            lo = jnp.where(less, mid + 1, lo)
+            hi = jnp.where(less, hi, mid)
+        idx = jnp.clip(lo, 0, cap - 1)
+        hit = (sets[idx] == q_set) & (elts[idx] == q_elt)
+        return hit, jnp.where(hit, hops[idx], 0)
 
 
 _probe = jax.jit(probe_in_program)

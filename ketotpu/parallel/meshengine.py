@@ -39,7 +39,6 @@ the fast-path dispatch.  Sharded differences:
 from __future__ import annotations
 
 import threading
-import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -726,78 +725,76 @@ class MeshCheckEngine(DeviceCheckEngine):
             return None
         faults.inject("device_dispatch")
         self.dispatches += 1
-        t0 = time.perf_counter()
-        with self._sync_lock:
-            snap = self._snapshot_locked()
-            stacked = self._stacked
-            # cache-entry freshness stamp: captured under the same lock as
-            # the snapshot the verdicts will be computed against
-            cursor = self._log_cursor
-        enc = self._encode(snap, queries, rest_depth)
-        err, general = self._classify(snap, enc[0], enc[2])
-        # Leopard first: checks the closure index answers drop out of the
-        # sharded BFS entirely (same interception as the single-chip path)
-        leo_res = self._leopard_answers(enc, err, general)
-        act = ~(err | general)
-        if leo_res is not None:
-            act &= ~leo_res[1]
-        # hot-spot shield after Leopard (shared _cache_consult): cached
-        # queries leave both the sharded BFS and the algebra dispatch
-        cache_res = self._cache_consult(queries, rest_depth, err, general,
-                                        leo_res, cursor)
-        if cache_res is not None:
-            act &= ~cache_res[0]
-            general = general & ~cache_res[0]
-        # cross-host routing BEFORE the shard-level machinery: rows whose
-        # serving host is a peer leave the local wave entirely (one framed
-        # round trip per peer, launched now so the DCN exchange overlaps
-        # the local device run; joined last in _collect).  Rows with no
-        # live serving host degrade to the oracle via the err-mask.
-        peerh = None
-        if (self.hostlink is not None and self.n_hosts > 1
-                and not getattr(_LOCAL_SERVE, "serving", False)):
-            peerh = self._route_hosts(queries, act | general, rest_depth)
-            if peerh is not None:
-                gone = peerh["sent"] | peerh["lost"]
-                act = act & ~gone
-                general = general & ~gone
-                err = err | gone
-        self._poll_shard_faults()
-        assign, owner = self._route_assign(enc[0], enc[1])
-        if self._shard_down.any():
-            # roots whose serving shard is down and that no live replica
-            # can absorb degrade to the host oracle; the wave itself keeps
-            # serving (general roots activate by hash owner on-device, so
-            # a down owner sends them to the oracle too)
-            down_fast = act & self._shard_down[assign]
-            down_gen = general & self._shard_down[owner]
-            act = act & ~down_fast
-            general = general & ~down_gen
-            err = err | down_fast | down_gen
-        if self.replicate_hot and act.any():
-            live = np.flatnonzero(act)
-            self._hot.observe_many(list(zip(
-                np.clip(np.asarray(enc[0])[live], 0, None).tolist(),
-                np.clip(np.asarray(enc[1])[live], 0, None).tolist(),
-            )))
-        # per-shard routed-root accounting: the skew/rebalance signal and
-        # the wave ledger's per-shard deltas
-        with self._mesh_run_lock:
-            np.add.at(self._shard_batches, assign[act], 1)
+        with self._span("check_encode", rows=n):
+            with self._sync_lock:
+                snap = self._snapshot_locked()
+                stacked = self._stacked
+                # cache-entry freshness stamp: captured under the same lock as
+                # the snapshot the verdicts will be computed against
+                cursor = self._log_cursor
+            enc = self._encode(snap, queries, rest_depth)
+            err, general = self._classify(snap, enc[0], enc[2])
+            # Leopard first: checks the closure index answers drop out of the
+            # sharded BFS entirely (same interception as the single-chip path)
+            leo_res = self._leopard_answers(enc, err, general)
+            act = ~(err | general)
+            if leo_res is not None:
+                act &= ~leo_res[1]
+            # hot-spot shield after Leopard (shared _cache_consult): cached
+            # queries leave both the sharded BFS and the algebra dispatch
+            cache_res = self._cache_consult(queries, rest_depth, err, general,
+                                            leo_res, cursor)
+            if cache_res is not None:
+                act &= ~cache_res[0]
+                general = general & ~cache_res[0]
+            # cross-host routing BEFORE the shard-level machinery: rows whose
+            # serving host is a peer leave the local wave entirely (one framed
+            # round trip per peer, launched now so the DCN exchange overlaps
+            # the local device run; joined last in _collect).  Rows with no
+            # live serving host degrade to the oracle via the err-mask.
+            peerh = None
+            if (self.hostlink is not None and self.n_hosts > 1
+                    and not getattr(_LOCAL_SERVE, "serving", False)):
+                peerh = self._route_hosts(queries, act | general, rest_depth)
+                if peerh is not None:
+                    gone = peerh["sent"] | peerh["lost"]
+                    act = act & ~gone
+                    general = general & ~gone
+                    err = err | gone
+            self._poll_shard_faults()
+            assign, owner = self._route_assign(enc[0], enc[1])
+            if self._shard_down.any():
+                # roots whose serving shard is down and that no live replica
+                # can absorb degrade to the host oracle; the wave itself keeps
+                # serving (general roots activate by hash owner on-device, so
+                # a down owner sends them to the oracle too)
+                down_fast = act & self._shard_down[assign]
+                down_gen = general & self._shard_down[owner]
+                act = act & ~down_fast
+                general = general & ~down_gen
+                err = err | down_fast | down_gen
+            if self.replicate_hot and act.any():
+                live = np.flatnonzero(act)
+                self._hot.observe_many(list(zip(
+                    np.clip(np.asarray(enc[0])[live], 0, None).tolist(),
+                    np.clip(np.asarray(enc[1])[live], 0, None).tolist(),
+                )))
+            # per-shard routed-root accounting: the skew/rebalance signal and
+            # the wave ledger's per-shard deltas
+            with self._mesh_run_lock:
+                np.add.at(self._shard_batches, assign[act], 1)
+                if general.any():
+                    np.add.at(self._shard_batches, owner[general], 1)
+            qpad = min(_bucket(n), self.frontier)
+            padded = self._pad(enc, n, qpad)
+            active = np.pad(act, (0, qpad - n))
+            passign = np.pad(assign, (0, qpad - n))
+        with self._span("check_mesh_dispatch", rows=n):
+            res = self._sharded_run(stacked, padded, active, assign=passign)
+            gres = gi = None
             if general.any():
-                np.add.at(self._shard_batches, owner[general], 1)
-        qpad = min(_bucket(n), self.frontier)
-        padded = self._pad(enc, n, qpad)
-        active = np.pad(act, (0, qpad - n))
-        passign = np.pad(assign, (0, qpad - n))
-        self._phase("check_encode", time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        res = self._sharded_run(stacked, padded, active, assign=passign)
-        gres = gi = None
-        if general.any():
-            gi = np.flatnonzero(general)
-            gres = self._run_general_mesh(stacked, enc, gi)
-        self._phase("check_mesh_dispatch", time.perf_counter() - t0)
+                gi = np.flatnonzero(general)
+                gres = self._run_general_mesh(stacked, enc, gi)
         return (enc, err, general, res, gi, gres, stacked, assign, leo_res,
                 cache_res, cursor, peerh)
 

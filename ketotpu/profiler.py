@@ -17,15 +17,113 @@ Safety properties the REST handler relies on:
   non-blocking: a busy profiler answers 409 immediately.
 * **Bounded** — ``seconds`` is clamped to ``max_seconds``; a typo'd
   ``seconds=3600`` cannot pin the capture thread for an hour.
+
+The capture is read against the program's own host spans, which this
+module also defines: :class:`Span` (one timed block: an engine phase) and
+:class:`ThreadStates` (a partition of one thread's wall time: the
+coalescer's two threads).  Both open a ``jax.profiler.TraceAnnotation``,
+which costs a flag read outside a capture and, during one, lands in the
+same ``.xplane.pb`` on the same clock as the device's ``XLA Ops``.  So a
+capture runs with the Python tracer off (it slowed the host it measured)
+unless the operator asks for stacks with ``?python=1``.
 """
 
 from __future__ import annotations
 
+import contextlib
 import os
 import tempfile
 import threading
 import time
-from typing import Optional
+from typing import Callable, Optional
+
+from jax.profiler import (
+    ProfileOptions,
+    TraceAnnotation,
+    start_trace,
+    stop_trace,
+)
+
+from ketotpu import hostwaits
+
+# thread ident -> names of the spans and states open on that thread: kept
+# where the host-pause log line that quotes them is written, in a module
+# that does not import jax (the front doors' workers import it)
+_open = hostwaits.open_by_thread
+
+
+def null_span(name: str, **fields):
+    """Stands in for an engine's ``_span`` where a dispatcher is called
+    without one (tests, bench, the retry tier inside ``check_retry``)."""
+    return contextlib.nullcontext()
+
+
+class Span:
+    """``with Span(name, done, **fields):`` one timed block on this
+    thread, as a trace annotation ``name`` carrying ``fields``; its wall
+    seconds go to ``done(seconds)`` when it ends."""
+
+    __slots__ = ("name", "_done", "_ann", "_t0")
+
+    def __init__(self, name: str, done: Callable[[float], None], **fields):
+        self.name = name
+        self._done = done
+        self._ann = TraceAnnotation(name, **fields)
+
+    def __enter__(self) -> "Span":
+        _open.setdefault(threading.get_ident(), []).append(self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        _open[threading.get_ident()].pop()
+        self._done(dt)
+
+
+class ThreadStates:
+    """One thread's wall time as a sequence of named states, each an
+    annotation ``<prefix><state>``: from the first :meth:`enter` to
+    :meth:`close` the thread is in exactly one, so the seconds handed to
+    ``sink(state, seconds)`` add up to its wall time.  ``enter`` and
+    ``close`` belong to the owning thread; :meth:`flush` may come from any
+    (a scrape hands over the seconds of the state still open)."""
+
+    def __init__(self, prefix: str, sink: Callable[[str, float], None]):
+        self._prefix = prefix
+        self._sink = sink
+        self._lock = threading.Lock()
+        self._state: Optional[str] = None
+        self._ann: Optional[TraceAnnotation] = None
+        self._t0 = 0.0
+
+    def enter(self, state: Optional[str], **fields) -> None:
+        """Leave the state the thread is in (its seconds go to the sink)
+        for ``state``; None leaves the last one."""
+        names = _open.setdefault(threading.get_ident(), [])
+        with self._lock:
+            now = time.perf_counter()
+            if self._state is not None:
+                self._ann.__exit__(None, None, None)
+                names.pop()
+                self._sink(self._state, now - self._t0)
+            self._state, self._t0 = state, now
+        if state is not None:
+            names.append(self._prefix + state)
+            self._ann = TraceAnnotation(self._prefix + state, **fields)
+            self._ann.__enter__()
+
+    def close(self) -> None:
+        self.enter(None)
+
+    def flush(self) -> None:
+        with self._lock:
+            if self._state is not None:
+                now = time.perf_counter()
+                self._sink(self._state, now - self._t0)
+                self._t0 = now
 
 
 class ProfilerDisabled(RuntimeError):
@@ -48,9 +146,11 @@ class DeviceProfiler:
         self.captures = 0
         self.last_artifact: Optional[str] = None
 
-    def capture(self, seconds: float) -> dict:
+    def capture(self, seconds: float, python: bool = False) -> dict:
         """Block for ``seconds`` (clamped) of trace capture; returns the
-        artifact metadata ``{path, seconds, started_ts}``."""
+        artifact metadata ``{path, seconds, started_ts, python}``.  The
+        host tracer (annotations, runtime calls) is always on; ``python``
+        adds the Python tracer's call stacks, which slow the host."""
         if not self.enabled:
             raise ProfilerDisabled(
                 "device profiling is disabled; set "
@@ -60,25 +160,26 @@ class DeviceProfiler:
         if not self._lock.acquire(blocking=False):
             raise ProfilerBusy("a profile capture is already in progress")
         try:
-            import jax
-
             base = self.out_dir or os.path.join(
                 tempfile.gettempdir(), "keto-tpu-profiles"
             )
             os.makedirs(base, exist_ok=True)
             started = time.time()
             path = os.path.join(base, f"profile-{int(started)}")
-            jax.profiler.start_trace(path)
+            options = ProfileOptions()
+            options.python_tracer_level = 1 if python else 0
+            start_trace(path, profiler_options=options)
             try:
                 time.sleep(seconds)
             finally:
-                jax.profiler.stop_trace()
+                stop_trace()
             self.captures += 1
             self.last_artifact = path
             return {
                 "path": path,
                 "seconds": seconds,
                 "started_ts": round(started, 3),
+                "python": bool(python),
             }
         finally:
             self._lock.release()

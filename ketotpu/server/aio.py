@@ -36,12 +36,11 @@ import json
 import socket
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
 from contextlib import nullcontext
 from typing import Optional, Tuple
 from urllib.parse import parse_qs, urlparse
 
-from ketotpu import deadline, flightrec
+from ketotpu import deadline, flightrec, hostwaits
 from ketotpu.api.types import KetoAPIError
 from ketotpu.server import overload
 
@@ -86,7 +85,7 @@ class AsyncHTTPServer:
         self.server_address = self._sock.getsockname()
         self._backlog = backlog
         self._ssl_ctx = ssl_ctx
-        self._pool = ThreadPoolExecutor(
+        self._pool = hostwaits.StampedPool(
             max_workers=workers, thread_name_prefix="http-worker",
         )
         self._loop: Optional[asyncio.AbstractEventLoop] = None

@@ -28,11 +28,11 @@ import socket
 import ssl
 import threading
 import time
-from concurrent import futures
 from typing import Dict, List, Optional, Tuple
 
 import grpc
 
+from ketotpu import hostwaits
 from ketotpu.proto import health_pb2
 from ketotpu.proto.services import (
     CHECK_SERVICE,
@@ -262,7 +262,9 @@ class Server:
         )
 
         server = grpc.server(
-            futures.ThreadPoolExecutor(max_workers=16),
+            # stamped: a request's wait for one of these threads is its
+            # pool_wait stage (hostwaits.py)
+            hostwaits.StampedPool(max_workers=16),
             options=[("grpc.so_reuseport", 0)],
             # access-log/metrics interceptor first so its duration covers
             # the embedder-supplied chain (ketoctx

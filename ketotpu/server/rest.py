@@ -891,7 +891,8 @@ def metrics_router(registry) -> Router:
 
     def post_profile(req):
         # on-demand jax.profiler capture: config-gated (403 unarmed),
-        # single-flight (409 while a capture runs), seconds clamped
+        # single-flight (409 while a capture runs), seconds clamped;
+        # ?python=1 adds the Python tracer's stacks to the host spans
         from ketotpu.profiler import ProfilerBusy, ProfilerDisabled
 
         try:
@@ -899,7 +900,9 @@ def metrics_router(registry) -> Router:
         except ValueError:
             raise BadRequestError("seconds must be a number")
         try:
-            artifact = registry.profiler().capture(seconds)
+            artifact = registry.profiler().capture(
+                seconds, python=req.query.get("python", "") in ("1", "true")
+            )
         except ProfilerDisabled as e:
             return 403, {"error": {"code": 403, "message": str(e)}}
         except ProfilerBusy as e:

@@ -14,9 +14,9 @@ keys off that version.
 
 from __future__ import annotations
 
-import threading
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
+from ketotpu import hostwaits
 from ketotpu.api.types import (
     BadRequestError,
     RelationQuery,
@@ -34,7 +34,10 @@ class InMemoryTupleStore:
     """Ordered tuple store with by-userset and by-subject indexes."""
 
     def __init__(self):
-        self._lock = threading.RLock()
+        # every Check mints its snaptoken under this lock, so a long hold
+        # (a lazy index build) stalls all of them: waits for it are counted
+        # (keto_host_pause_seconds{cause="store_lock"})
+        self._lock = hostwaits.TimedRLock()
         self._rows: Dict[int, RelationTuple] = {}  # seq -> tuple, insertion order
         self._next_seq = 0
         # (namespace, object, relation) -> [seq]; the forward index backing

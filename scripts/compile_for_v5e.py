@@ -104,7 +104,7 @@ def main() -> None:
             (g, qpack), static = arguments_of(
                 fdx, "run_fused_wave", lambda: eng._dispatch(queries, 0)
             )
-            static.pop("timer")
+            static.pop("span")
             one_chip = on(SingleDeviceSharding(topo.devices[0]))
             report(f"fused wave Q={qpack.shape[1]} lanes={args.lanes}",
                    fdx._run_wave, (one_chip(g), one_chip(qpack)), static)

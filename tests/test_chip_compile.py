@@ -138,7 +138,7 @@ def test_fused_wave_compiles_for_v5e(topo, no_cache, engine, graph,
         monkeypatch, fdx, "run_fused_wave",
         lambda: engine._dispatch(queries, 0),
     )
-    static.pop("timer")
+    static.pop("span")
     assert static["fast_sched"] is not None and static["gen"] is not None
     assert "leo_sets" in g  # tier 0 is in the program
     one_chip = _on(SingleDeviceSharding(topo.devices[0]))

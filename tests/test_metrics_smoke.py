@@ -247,6 +247,28 @@ def test_projection_metric_vocabulary(scrape):
     assert "build_phases" in proj and proj["build_phases"]
 
 
+def test_wave_ring_gauges_gone_and_window_series_present(scrape):
+    """ISSUE 27: the four wave-ledger ring quantiles are off the scrape
+    (a caller's window reads the same from counters and stages that are);
+    the waits nobody timed are on it: the pool-wait stage of both front
+    doors and the wave threads' states."""
+    text = scrape["metrics_text"]
+    for gone in ("keto_wave_size_mean", "keto_wave_size_p95",
+                 "keto_wave_window_wait_ms_p50", "keto_wave_device_ms_p50"):
+        assert gone not in text, gone
+    assert "keto_engine_coalesced_checks" in text
+    assert "keto_engine_coalesced_waves" in text
+    stages = set(re.findall(
+        r'keto_rpc_stage_seconds_count\{op="check",stage="([^"]+)"', text))
+    assert {"pool_wait", "coalesce_wait", "device_compute"} <= stages, stages
+    states = set(re.findall(
+        r'keto_coalescer_thread_seconds\{state="([^"]+)",thread="([^"]+)"',
+        text))
+    assert {("idle", "collector"), ("window", "collector"),
+            ("stage_empty", "dispatcher"), ("serve", "dispatcher"),
+            ("file", "dispatcher")} <= states, states
+
+
 def test_metric_vocabulary_documented_in_readme(scrape):
     """Vocabulary drift gate: every ``keto_*`` metric name a live daemon
     exposes must appear in README.md's metric table (wildcard rows like

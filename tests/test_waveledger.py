@@ -9,6 +9,7 @@ check/expand waves.
 """
 
 import json
+import re
 import threading
 import time
 import urllib.error
@@ -123,8 +124,11 @@ def cache_dir_restored():
     import jax
 
     was = jax.config.jax_compilation_cache_dir
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
     yield
     jax.config.update("jax_compilation_cache_dir", was)
+    jax.config.update(
+        "jax_compilation_cache_include_metadata_in_key", keyed)
 
 
 @pytest.mark.parametrize("placed", ["by_environment", "by_default"])
@@ -144,8 +148,10 @@ def test_compile_cache_placement(placed, monkeypatch, tmp_path,
     if placed == "by_environment":
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert compilewatch.place_cache() == str(tmp_path)
-        # nothing set in code: JAX reads the variable itself
+        # no directory set in code: JAX reads the variable itself
         assert jax.config.jax_compilation_cache_dir == before
+        # named scopes are part of a cached program's identity
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
         return
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     first = compilewatch.place_cache()
@@ -156,6 +162,7 @@ def test_compile_cache_placement(placed, monkeypatch, tmp_path,
     assert compilewatch.place_cache() == first
     assert first == os.path.join(repo, ".jax_cache")
     assert jax.config.jax_compilation_cache_dir == first
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
 
 
 def test_compilewatch_counts_cache_hits():
@@ -486,10 +493,21 @@ def test_profile_endpoint_gated(debug_scrape):
 
 
 @pytest.mark.slow
-def test_wave_gauges_in_metrics(server, debug_scrape):
-    # sample_engine_metrics publishes the ledger aggregates as gauges on
-    # the scrape path; value must match the ledger's own stats
+def test_wave_series_in_metrics(server, debug_scrape):
+    # what a caller's window reads wave size and waits from, now that the
+    # ledger's ring quantiles are off the scrape: checks and waves ridden,
+    # the coalesce_wait / device_compute stages, the wave threads' states
     metrics = debug_scrape["metrics"]
     text = _get(f"{metrics}/metrics/prometheus")
-    assert "keto_wave_size_mean" in text
-    assert "keto_wave_window_wait_ms_p50" in text
+    assert "keto_wave_size_mean" not in text
+    assert "keto_wave_window_wait_ms_p50" not in text
+    waves = float(re.search(
+        r"^keto_engine_coalesced_waves (\S+)", text, re.M).group(1))
+    checks = float(re.search(
+        r"^keto_engine_coalesced_checks (\S+)", text, re.M).group(1))
+    assert checks >= waves >= 1
+    for stage in ("coalesce_wait", "device_compute"):
+        assert re.search(
+            r'keto_rpc_stage_seconds_count\{op="check",stage="%s"\} [1-9]'
+            % stage, text), stage
+    assert 'keto_coalescer_thread_seconds{state="serve",thread=' in text
