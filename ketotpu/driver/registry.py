@@ -25,6 +25,7 @@ import os
 import threading
 import time
 import uuid
+import weakref
 from typing import Any, Callable, Dict, Optional
 
 import numpy as np
@@ -95,6 +96,7 @@ class Registry:
         self._profiler = None
         self._compile_watch = None
         self._admission = None
+        self._door_pools: "weakref.WeakSet" = weakref.WeakSet()
         self._overload = None
         self._overload_built = False
         self._session_broker = None
@@ -1096,6 +1098,18 @@ class Registry:
                 )
             return self._admission
 
+    def front_door_pool(self, door: str, max_workers: int,
+                        thread_name_prefix: str):
+        """The thread pool of one port's ``door`` (``grpc`` or ``rest``)
+        front door: stamped, so a request's wait for a thread is its
+        ``pool_wait`` stage, and counted on the scrape for as long as its
+        server lives (``keto_frontdoor_pool_busy|max{door}``)."""
+        pool = hostwaits.StampedPool(
+            max_workers, door=door, thread_name_prefix=thread_name_prefix,
+        )
+        self._door_pools.add(pool)
+        return pool
+
     def overload(self):
         """The adaptive overload-control plane (server/overload.py):
         AIMD admission limit, brownout ladder, Retry-After hints.  None
@@ -1437,6 +1451,19 @@ class Registry:
                 slo.publish()
             except Exception:  # noqa: BLE001 - scrape must not fail
                 pass
+        # front doors: threads inside a call and the most there can be,
+        # added up over the ports' pools of each door
+        doors: Dict[str, list] = {}
+        for pool in list(self._door_pools):
+            tally = doors.setdefault(pool.door, [0, 0])
+            tally[0] += pool.busy
+            tally[1] += pool.ceiling
+        m = self.metrics()
+        for door, (busy, ceiling) in doors.items():
+            m.gauge("keto_frontdoor_pool_busy", busy, door=door,
+                    help="front-door pool threads inside a call")
+            m.gauge("keto_frontdoor_pool_max", ceiling, door=door,
+                    help="most threads the front door's pools will start")
         # overload plane: adaptive limit + ladder stage gauges stay live
         # even between ticks; breaker lanes publish their state codes
         with self._lock:

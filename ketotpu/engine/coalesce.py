@@ -836,3 +836,11 @@ class CoalescingEngine:
             ],
             "ts": round(time.time(), 3),
         })
+
+
+#: The places a single check waits between its enqueue and its answer
+#: (``_run``, ``_run_dispatch``): pending, the wave the collector holds at
+#: ``_stage.put``, the staged wave, the wave in flight.  Callers that keep
+#: coming fill each with a wave, so a front door has to let in this many
+#: waves' worth of them before a wave can be full (server/daemon.py).
+PLACES = 4

@@ -70,16 +70,36 @@ def open_spans() -> Dict[int, List[str]]:
 class StampedPool(ThreadPoolExecutor):
     """A thread pool that tells the work how long it waited for a thread:
     while a submitted call runs, :func:`take_pool_stamp` on its thread
-    gives ``(submitted, started)`` in ``time.perf_counter`` seconds."""
+    gives ``(submitted, started)`` in ``time.perf_counter`` seconds.
+
+    It also counts itself: ``busy`` is the number of its threads inside a
+    call right now and ``ceiling`` the most it will ever start (a
+    ``ThreadPoolExecutor`` starts a thread only when a submit finds none
+    idle, so an idle pool holds as many as its busiest moment needed).
+    ``door`` names the front door it serves (``grpc`` or ``rest``); the
+    registry adds the pools of a door up into
+    ``keto_frontdoor_pool_busy|max{door}`` at scrape time."""
+
+    def __init__(self, max_workers: int, *, door: str = "",
+                 thread_name_prefix: str = ""):
+        super().__init__(max_workers, thread_name_prefix=thread_name_prefix)
+        self.door = door
+        self.ceiling = max_workers
+        self.busy = 0
+        self._busy_lock = threading.Lock()
 
     def submit(self, fn, /, *args, **kwargs):
         submitted = time.perf_counter()
 
         def stamped():
             _local.stamp = (submitted, time.perf_counter())
+            with self._busy_lock:
+                self.busy += 1
             try:
                 return fn(*args, **kwargs)
             finally:
+                with self._busy_lock:
+                    self.busy -= 1
                 _local.stamp = None
 
         return super().submit(stamped)

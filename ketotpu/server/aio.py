@@ -40,7 +40,7 @@ from contextlib import nullcontext
 from typing import Optional, Tuple
 from urllib.parse import parse_qs, urlparse
 
-from ketotpu import deadline, flightrec, hostwaits
+from ketotpu import deadline, flightrec
 from ketotpu.api.types import KetoAPIError
 from ketotpu.server import overload
 
@@ -85,8 +85,8 @@ class AsyncHTTPServer:
         self.server_address = self._sock.getsockname()
         self._backlog = backlog
         self._ssl_ctx = ssl_ctx
-        self._pool = hostwaits.StampedPool(
-            max_workers=workers, thread_name_prefix="http-worker",
+        self._pool = self.registry.front_door_pool(
+            "rest", workers, "http-worker",
         )
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._stop_ev: Optional[asyncio.Event] = None

@@ -32,7 +32,6 @@ from typing import Dict, List, Optional, Tuple
 
 import grpc
 
-from ketotpu import hostwaits
 from ketotpu.proto import health_pb2
 from ketotpu.proto.services import (
     CHECK_SERVICE,
@@ -256,15 +255,32 @@ class Server:
     # -- construction -------------------------------------------------------
 
     def _grpc_backend(self, services: Dict[str, object]) -> Tuple[str, int]:
+        from ketotpu.engine import coalesce, tpu
         from ketotpu.server.interceptors import (
             AccessLogInterceptor,
             AdmissionInterceptor,
         )
 
         server = grpc.server(
-            # stamped: a request's wait for one of these threads is its
-            # pool_wait stage (hostwaits.py)
-            hostwaits.StampedPool(max_workers=16),
+            # A synchronous handler parks its thread until the wave that
+            # carries its check has answered, so this ceiling is how many
+            # checks may wait inside the server, and one under what the
+            # coalescer can hold cuts its waves (16 threads over its four
+            # places made waves of four rows).  So: a full wave of the
+            # smallest check program (the rows engine/tpu.py:_bucket pads
+            # a wave of one to) in each place a check waits for its answer
+            # (engine/coalesce.py:PLACES).  What bounds the calls that
+            # stay inside is admission (limit.max_inflight, the interceptor
+            # below, on the pool's thread); the ceiling is not cut down to
+            # that limit, because a call over it needs a thread for the
+            # instant it takes to answer RESOURCE_EXHAUSTED.  A thread
+            # starts only when a submit finds none idle: 64 callers cost
+            # 64 threads, an idle daemon none, and a parked handler blocks
+            # on an Event and holds no GIL.  Stamped: a request's wait for
+            # a thread is its pool_wait stage (hostwaits.py).
+            self.registry.front_door_pool(
+                "grpc", coalesce.PLACES * tpu._bucket(1), "grpc-worker",
+            ),
             options=[("grpc.so_reuseport", 0)],
             # access-log/metrics interceptor first so its duration covers
             # the embedder-supplied chain (ketoctx

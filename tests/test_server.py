@@ -493,6 +493,61 @@ class TestSDKTransport:
             sdk.list_relation_tuples(page_token="not-a-token")
 
 
+def test_concurrent_grpc_checks_fill_shared_waves(
+        server, read_channel, monkeypatch):
+    """48 clients' single Checks all wait inside the server at once, so a
+    wave carries what the coalescer's four places leave it of 48, not of
+    the 16 threads the gRPC pool used to stop at (waves of four rows)."""
+    stub = CheckServiceStub(read_channel)
+    reg = server.registry
+    # a wave on the CPU takes a few ms, less than this process's threads
+    # need to send 48 checks: a window as long as a wave on the chip
+    monkeypatch.setattr(reg.check_engine(), "window", 0.03)
+
+    def check(subject):
+        return stub.Check(cs.CheckRequest(tuple=rts.RelationTuple(
+            namespace="File", object="keto/README.md", relation="view",
+            subject=rts.Subject(id=subject),
+        )), timeout=120).allowed
+
+    assert check("bob") is True  # the wave program is compiled
+    ledger = reg.wave_ledger()
+    waves_before = max(w["wave"] for w in ledger.snapshot())
+
+    def gauges():
+        reg.sample_engine_metrics()
+        m = reg.metrics()
+        return (m.get_gauge("keto_engine_coalesced_checks"),
+                m.get_gauge("keto_engine_coalesced_waves"))
+
+    checks0, waves0 = gauges()
+    clients, rounds = 48, 6
+    denied = []
+
+    def client(k):
+        # every check another key: no result-cache hit, no singleflight
+        denied.extend(check(f"nobody-{k}-{i}") for i in range(rounds))
+
+    threads = [threading.Thread(target=client, args=(k,))
+               for k in range(clients)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+        assert not t.is_alive()
+    assert denied == [False] * (clients * rounds)
+    checks1, waves1 = gauges()
+    assert checks1 - checks0 == clients * rounds
+    assert (checks1 - checks0) / (waves1 - waves0) > 4.0
+    # and some wave held more checks than sixteen threads could bring
+    sizes = [w["size"] for w in ledger.snapshot()
+             if w["wave"] > waves_before]
+    assert max(sizes) > 16, sizes
+    reg.sample_engine_metrics()
+    assert reg.metrics().get_gauge(
+        "keto_frontdoor_pool_max", door="grpc") == 3 * 1024  # three ports
+
+
 def test_engine_gauges_on_metrics(server, read_addr):
     status, body = _http("GET", f"{read_addr}/metrics/prometheus")
     assert status == 200
