@@ -630,7 +630,7 @@ def _fast_subrun(g, fb, *, sched, max_width: int, shard=None):
                 # merge found bits across shards before packing so arrived
                 # children of already-found leaves die immediately
                 q_found = (
-                    jax.lax.psum(q_found.astype(jnp.int32), axis_name) > 0
+                    _psum(q_found.astype(jnp.int32), axis_name) > 0
                 )
             nxt, q_over = fp.pack_phase(
                 children, q_found, q_over, frontier=nxt_f, ns_dim=NS,
@@ -642,9 +642,9 @@ def _fast_subrun(g, fb, *, sched, max_width: int, shard=None):
             )
     q_found, q_over, q_dirty = s["q_found"], s["q_over"], s["q_dirty"]
     if shard is not None:
-        q_found = jax.lax.psum(q_found.astype(jnp.int32), axis_name) > 0
-        q_over = jax.lax.psum(q_over.astype(jnp.int32), axis_name) > 0
-        q_dirty = jax.lax.psum(q_dirty.astype(jnp.int32), axis_name) > 0
+        q_found = _psum(q_found.astype(jnp.int32), axis_name) > 0
+        q_over = _psum(q_over.astype(jnp.int32), axis_name) > 0
+        q_dirty = _psum(q_dirty.astype(jnp.int32), axis_name) > 0
     # found is monotone and overlay-exact (probes consult om_), so a
     # found leaf is trustworthy even when exploration brushed a dirty
     # row; an UNFOUND dirty leaf must be answered by the host oracle
@@ -721,10 +721,10 @@ def _general_body(
         me = jax.lax.axis_index(axis_name)
 
         def _mi(x, mine):  # owner-masked int merge (exactly one owner)
-            return jax.lax.psum(jnp.where(mine, x, 0), axis_name)
+            return _psum(jnp.where(mine, x, 0), axis_name)
 
         def _mb(x, mine):
-            return jax.lax.psum(
+            return _psum(
                 jnp.where(mine, x.astype(jnp.int32), 0), axis_name
             ) > 0
 
@@ -768,7 +768,7 @@ def _general_body(
             return out
 
         def _pmax_bool(x):
-            return jax.lax.psum(x.astype(jnp.int32), axis_name) > 0
+            return _psum(x.astype(jnp.int32), axis_name) > 0
     else:
         _merge_classified = None
 
@@ -923,3 +923,13 @@ run_general_packed = functools.partial(
         "sizes", "fast_b", "fast_sched", "max_width", "vcap", "shard",
     ),
 )(_general_body)
+
+
+def _psum(x, axis_name):
+    """``lax.psum`` of the sharded mode's merges, under the scope
+    ``mesh/merge`` in a capture.  Defined after the module's last line, and
+    every call of it stands on a line only the ``shard=`` mode traces: no
+    line of a one-chip program moved, so the persistent cache still knows
+    them (compilewatch.place_cache keys by source locations too)."""
+    with jax.named_scope("mesh/merge"):
+        return jax.lax.psum(x, axis_name)

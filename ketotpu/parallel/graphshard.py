@@ -160,58 +160,74 @@ def _route(children: Dict, n: int, cap: int, q_over, axis: str):
     """Bucket children by owner shard and all-to-all them to owners.
 
     ``cap`` slots per destination peer; overflow marks q_over (monotone).
+    The whole of it (sort, bucketize, ``all_to_all``, unpack) stands under
+    the scope ``mesh/route`` in a capture, in both sharded programs.
     """
-    Q = q_over.shape[0]
-    dest = shard_of_device(children["ns"], children["obj"], n)
-    alive = children["qid"] >= 0
-    dest = jnp.where(alive, dest, n)  # dead rows sort last
+    with jax.named_scope("mesh/route"):
+        Q = q_over.shape[0]
+        dest = shard_of_device(children["ns"], children["obj"], n)
+        alive = children["qid"] >= 0
+        dest = jnp.where(alive, dest, n)  # dead rows sort last
 
-    # stable sort by destination, then slot within each dest bucket
-    A = dest.shape[0]
-    order = jnp.argsort(dest * (A + 1) + jnp.arange(A, dtype=jnp.int32))
-    dsorted = dest[order]
-    # position within the destination run
-    pos_in_run = jnp.arange(A, dtype=jnp.int32) - jnp.searchsorted(
-        dsorted, dsorted, side="left"
-    )
-    over_b = (dsorted < n) & (pos_in_run >= cap)
-    srt = {k: v[order] for k, v in children.items()}
-    q_over = q_over.at[jnp.clip(srt["qid"], 0, Q - 1)].max(over_b & (srt["qid"] >= 0))
-
-    slot = jnp.where(dsorted < n, dsorted * cap + jnp.clip(pos_in_run, 0, cap - 1), n * cap)
-    slot = jnp.where(over_b, n * cap, slot)
-
-    def bucketize(col, fill):
-        return (
-            jnp.full((n * cap,), fill, col.dtype)
-            .at[slot]
-            .set(jnp.where(over_b | (dsorted >= n), fill, col), mode="drop")
+        # stable sort by destination, then slot within each dest bucket
+        A = dest.shape[0]
+        order = jnp.argsort(dest * (A + 1) + jnp.arange(A, dtype=jnp.int32))
+        dsorted = dest[order]
+        # position within the destination run
+        pos_in_run = jnp.arange(A, dtype=jnp.int32) - jnp.searchsorted(
+            dsorted, dsorted, side="left"
+        )
+        over_b = (dsorted < n) & (pos_in_run >= cap)
+        srt = {k: v[order] for k, v in children.items()}
+        q_over = q_over.at[jnp.clip(srt["qid"], 0, Q - 1)].max(
+            over_b & (srt["qid"] >= 0)
         )
 
-    send = jnp.stack(
-        [
-            bucketize(srt["qid"], -1),
-            bucketize(srt["ns"], -1),
-            bucketize(srt["obj"], -1),
-            bucketize(srt["rel"], -1),
-            bucketize(srt["d"], 0),
-            bucketize(srt["skip"].astype(jnp.int32), 1),
-            bucketize(srt["force"].astype(jnp.int32), 0),
-        ],
-        axis=1,
-    ).reshape(n, cap, 7)
-    recv = jax.lax.all_to_all(send, axis, 0, 0, tiled=False)
-    recv = recv.reshape(n * cap, 7)
-    out = dict(
-        qid=recv[:, 0],
-        ns=recv[:, 1],
-        obj=recv[:, 2],
-        rel=recv[:, 3],
-        d=recv[:, 4],
-        skip=recv[:, 5].astype(bool),
-        force=recv[:, 6].astype(bool),
-    )
-    return out, q_over
+        slot = jnp.where(
+            dsorted < n,
+            dsorted * cap + jnp.clip(pos_in_run, 0, cap - 1),
+            n * cap,
+        )
+        slot = jnp.where(over_b, n * cap, slot)
+
+        def bucketize(col, fill):
+            return (
+                jnp.full((n * cap,), fill, col.dtype)
+                .at[slot]
+                .set(jnp.where(over_b | (dsorted >= n), fill, col), mode="drop")
+            )
+
+        send = jnp.stack(
+            [
+                bucketize(srt["qid"], -1),
+                bucketize(srt["ns"], -1),
+                bucketize(srt["obj"], -1),
+                bucketize(srt["rel"], -1),
+                bucketize(srt["d"], 0),
+                bucketize(srt["skip"].astype(jnp.int32), 1),
+                bucketize(srt["force"].astype(jnp.int32), 0),
+            ],
+            axis=1,
+        ).reshape(n, cap, 7)
+        recv = jax.lax.all_to_all(send, axis, 0, 0, tiled=False)
+        recv = recv.reshape(n * cap, 7)
+        out = dict(
+            qid=recv[:, 0],
+            ns=recv[:, 1],
+            obj=recv[:, 2],
+            rel=recv[:, 3],
+            d=recv[:, 4],
+            skip=recv[:, 5].astype(bool),
+            force=recv[:, 6].astype(bool),
+        )
+        return out, q_over
+
+
+def _merge_any(bits, axis: str):
+    """OR a per-shard verdict bit over the mesh (a ``psum`` of the bits),
+    under the scope ``mesh/merge`` in a capture."""
+    with jax.named_scope("mesh/merge"):
+        return jax.lax.psum(bits.astype(jnp.int32), axis) > 0
 
 
 def sharded_general_check(
@@ -270,11 +286,14 @@ def _sharded_general_run(
 
     def local(g, qp):
         g = jax.tree_util.tree_map(lambda a: a[0], g)
-        codes, occ = alg.run_general_packed(
-            g, qp, sizes=sizes, fast_b=fast_b, fast_sched=fast_sched,
-            max_width=max_width, vcap=vcap,
-            shard=(axis, mesh.devices.size),
-        )
+        # the body itself, as the fused wave calls it: under a nested jit
+        # its operations would drop this scope from their names
+        with jax.named_scope("tier/general"):
+            codes, occ = alg._general_body(
+                g, qp, sizes=sizes, fast_b=fast_b, fast_sched=fast_sched,
+                max_width=max_width, vcap=vcap,
+                shard=(axis, mesh.devices.size),
+            )
         return codes, occ[None, :]
 
     return jax.shard_map(
@@ -309,35 +328,38 @@ def _sharded_fast_run(
         # root activation follows the host-provided assignment column —
         # the hash owner by default, a least-loaded replica for hot keys
         mine = assign == me
-        s = fp._init_state(
-            q_ns, q_obj, q_rel, q_subj, q_depth, act & mine,
-            frontier=frontier,
-        )
-        for _ in range(max_depth):
-            children, q_found, q_over, q_dirty = fp.expand_phase(
-                g, s, arena=arena, max_width=max_width
+        with jax.named_scope("tier/fast"):
+            s = fp._init_state(
+                q_ns, q_obj, q_rel, q_subj, q_depth, act & mine,
+                frontier=frontier,
             )
-            # children always route to their HASH owner (replication
-            # copies rows, never moves them, so the owner has them)
-            children, q_over = _route(children, n, cap, q_over, axis)
-            # merge found bits across shards before packing so arrived
-            # children of already-found queries die immediately
-            q_found = (
-                jax.lax.psum(q_found.astype(jnp.int32), axis) > 0
-            )
-            # ns_dim/rel_dim unlock the linear hash-scatter dedup — the
-            # sort fallback was the dominant per-level cost on shards
-            nxt, q_over = fp.pack_phase(
-                children, q_found, q_over, frontier=frontier,
-                ns_dim=NS, rel_dim=R,
-            )
-            s = dict(nxt, q_found=q_found, q_over=q_over,
-                     q_dirty=q_dirty, q_subj=s["q_subj"])
-        q_found = jax.lax.psum(s["q_found"].astype(jnp.int32), axis) > 0
-        q_over = jax.lax.psum(s["q_over"].astype(jnp.int32), axis) > 0
-        # a dirty hit on ANY shard voids that query's device verdict
-        # (unless found: found-bits are overlay-exact and monotone)
-        q_dirty = jax.lax.psum(s["q_dirty"].astype(jnp.int32), axis) > 0
+            for i in range(max_depth):
+                with jax.named_scope(f"level{i}"):
+                    children, q_found, q_over, q_dirty = fp.expand_phase(
+                        g, s, arena=arena, max_width=max_width
+                    )
+                    # children always route to their HASH owner
+                    # (replication copies rows, never moves them, so the
+                    # owner has them)
+                    children, q_over = _route(children, n, cap, q_over, axis)
+                    # merge found bits across shards before packing so
+                    # arrived children of already-found queries die
+                    # immediately
+                    q_found = _merge_any(q_found, axis)
+                    # ns_dim/rel_dim unlock the linear hash-scatter dedup
+                    # — the sort fallback was the dominant per-level cost
+                    # on shards
+                    nxt, q_over = fp.pack_phase(
+                        children, q_found, q_over, frontier=frontier,
+                        ns_dim=NS, rel_dim=R,
+                    )
+                    s = dict(nxt, q_found=q_found, q_over=q_over,
+                             q_dirty=q_dirty, q_subj=s["q_subj"])
+            q_found = _merge_any(s["q_found"], axis)
+            q_over = _merge_any(s["q_over"], axis)
+            # a dirty hit on ANY shard voids that query's device verdict
+            # (unless found: found-bits are overlay-exact and monotone)
+            q_dirty = _merge_any(s["q_dirty"], axis)
         return q_found, q_over, q_dirty
 
     return jax.shard_map(

@@ -393,15 +393,31 @@ class MeshCheckEngine(DeviceCheckEngine):
         # exactly like the single-chip engine
         return super()._expand_arrays()
 
-    def _sharded_run(self, stacked, padded, active, boost: int = 1,
-                     assign=None):
+    def _run_locked(self, phase: str, run, **fields):
+        """One sharded program, launched AND finished under the run lock:
+        collectives over one mesh must not overlap (two in-flight sharded
+        programs interleave their all_to_all rendezvous on the host
+        backend and starve).  The wait for the lock is the engine phase
+        ``check_mesh_lock_wait``, the program ``phase``: ``check_mesh_fast``
+        or ``check_mesh_general`` on a wave's first pass, ``check_mesh_retry``
+        (``boost=``) for the rows retried at ``retry_scale``."""
         import jax
 
-        # collectives over one mesh must not overlap: launch AND finish
-        # under the run lock (two in-flight sharded programs interleave
-        # their all_to_all rendezvous on the host backend and starve)
-        with self._mesh_run_lock:
-            res = graphshard.sharded_check(
+        with self._span("check_mesh_lock_wait"):
+            self._mesh_run_lock.acquire()
+        try:
+            with self._span(phase, **fields):
+                out = run()
+                jax.block_until_ready(out)
+        finally:
+            self._mesh_run_lock.release()
+        return out
+
+    def _sharded_run(self, stacked, padded, active, boost: int = 1,
+                     assign=None):
+        return self._run_locked(
+            "check_mesh_fast" if boost == 1 else "check_mesh_retry",
+            lambda: graphshard.sharded_check(
                 stacked,
                 padded,
                 self.mesh,
@@ -412,9 +428,9 @@ class MeshCheckEngine(DeviceCheckEngine):
                 max_width=self.max_width,
                 active=active,
                 assign=assign,
-            )
-            jax.block_until_ready(res)
-        return res
+            ),
+            rows=int(np.count_nonzero(active)), boost=boost,
+        )
 
     def _run_general_mesh(self, stacked, enc, gi, boost: int = 1):
         """One fused algebra dispatch over the SHARDED graph stacks for
@@ -432,15 +448,15 @@ class MeshCheckEngine(DeviceCheckEngine):
         qpack = np.stack([*genc, active.astype(np.int32)]).astype(np.int32)
         # GLOBAL shapes: the whole batch's skeleton lives on every shard
         sizes, fast_b, fast_sched, vcap = self._gen_schedule(qpad, boost)
-        import jax
-
-        with self._mesh_run_lock:  # see _sharded_run: collectives serialize
-            codes, occ = graphshard.sharded_general_check(
+        codes, occ = self._run_locked(
+            "check_mesh_general" if boost == 1 else "check_mesh_retry",
+            lambda: graphshard.sharded_general_check(
                 stacked, qpack, self.mesh, axis=self.mesh_axis,
                 sizes=sizes, fast_b=fast_b, fast_sched=fast_sched,
                 max_width=self.max_width, vcap=vcap,
-            )
-            jax.block_until_ready((codes, occ))
+            ),
+            rows=n, boost=boost,
+        )
         return codes, occ, n, fast_b
 
     # -- routing / failover -------------------------------------------------

@@ -798,3 +798,168 @@ def test_mesh_hosts_config_validation():
             "host_id": 0, "peers": ["nope", "10.0.0.2:7701"],
             "secret": "s3",
         }}}})
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 29: the object-sharded deployment as the benchmark runs it
+# (benchmark/configs/drive-10m-mesh4.json): four devices, the Drive graph at
+# the rehearsal's counts, batch1k's mix, held to the benchmark's own plain
+# reference.  One engine for the three tests, so the two sharded programs
+# compile once.
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def drive_mesh():
+    """The tiny Drive graph (``world``) behind a four-shard MeshCheckEngine
+    (``eng``) that has answered one seeded batch of batch1k's mix (``mix``,
+    ``rows``) on the device: ``allowed`` and ``fallback``, ``want`` the
+    plain reference's verdicts, ``counts`` the engine phases of that one
+    batch, ``texts`` the lowered text of the two sharded programs.  The
+    batch runs what ``.lower().compile()`` gives, so the programs are
+    traced once for the answers and for their text."""
+    import json
+    import pathlib
+    import sys
+    import types
+
+    from ketotpu.parallel import MeshCheckEngine, graphshard
+
+    bench = pathlib.Path(__file__).resolve().parent.parent / "benchmark"
+    sys.path.insert(0, str(bench))
+    try:
+        import checkmix
+        from graphs import drive
+        from reference.zanzibar import Reference
+    finally:
+        sys.path.remove(str(bench))
+    config = json.loads((bench / "configs/drive-10m-mesh4.json").read_text())
+    mix = json.loads((bench / "traffic/batch1k.json").read_text())
+    world = drive.build(config["rehearsal_graph"], 7)
+    store, manager = world.server_store()
+    eng = MeshCheckEngine(
+        store, manager, mesh_devices=config["engine"]["mesh_devices"],
+        frontier=1024, arena=4096, max_batch=512,
+        # compile time follows the levels: the Drive schema's only AND/NOT
+        # (edit = !banned && view) fits two skeleton levels below its root
+        gen_levels=2, gen_arena=2048, vcap=1024,
+    )
+    rows = checkmix.rows(world, mix, np.random.default_rng(29), 512)
+    queries = [
+        RelationTuple.from_json(world.tuple_json(
+            drive.NS_D, rows["obj"][i], rows["rel"][i],
+            checkmix.subject(rows, i)))
+        for i in range(512)
+    ]
+    texts = {}
+
+    def lowered_once(name):
+        real = getattr(graphshard, name)
+
+        def call(*args, **static):
+            lowered = real.lower(*args, **static)
+            texts[name] = lowered.as_text(debug_info=True)
+            return lowered.compile()(*args)
+        return call
+
+    before = dict(eng.phase_counts)
+    with pytest.MonkeyPatch.context() as patch:
+        for name in ("_sharded_fast_run", "_sharded_general_run"):
+            patch.setattr(graphshard, name, lowered_once(name))
+        allowed, fallback = eng.batch_check_device_only(queries)
+    counts = {k: v - before.get(k, 0) for k, v in eng.phase_counts.items()}
+    want = checkmix.reference_verdicts(
+        Reference(world.cols, drive.SCHEMA), rows)
+    yield types.SimpleNamespace(
+        world=world, mix=mix, rows=rows, want=want, eng=eng, allowed=allowed,
+        fallback=fallback, counts=counts, texts=texts)
+    eng.close()
+
+
+def test_mesh_answers_batch1k_mix_as_the_plain_reference(drive_mesh):
+    """Sharding may not change an answer: the device path alone (no row
+    handed to the host oracle) against benchmark/reference/zanzibar.py,
+    which takes the tuples and knows nothing of their layout."""
+    from graphs import drive  # the fixture has imported the benchmark's
+
+    m, eng, rows = drive_mesh, drive_mesh.eng, drive_mesh.rows
+    assert not np.asarray(m.fallback).any()
+    assert eng.fallbacks == 0 and eng.device_failures == 0
+    assert list(m.allowed) == m.want
+    # the mix is the cell's: the granted eighth is granted, through both
+    # permits and with subject-set subjects among the rest
+    assert sum(m.want) >= round(m.mix["granted_share"] * 512) - 4
+    assert (rows["rel"] == drive.R_EDIT).sum() > 100
+    assert (rows["group"] >= 0).sum() > 40
+    # a check-only engine never builds the replicated copy that only
+    # batch_expand reads (it would land whole on device 0)
+    assert eng._device_arrays is None and eng._base_device is None
+
+
+def test_mesh_shards_add_up_to_the_whole_graph(drive_mesh):
+    """Every tuple lies on exactly one shard, the one shard_of_np names,
+    and a direct-membership probe answered shard by shard and OR-ed is
+    the unsharded snapshot's answer."""
+    world, eng = drive_mesh.world, drive_mesh.eng
+    n = eng.n_shards
+    whole = eng._snap
+
+    def tuples_of(snap):
+        """(ns, obj, rel, subject) of every tuple a snapshot holds."""
+        node = snap.mem_node[: snap.n_tuples].astype(np.int64)
+        hi = snap.node_hi[node].astype(np.int64)
+        return np.stack([hi // snap.num_rels, snap.node_lo[node],
+                         hi % snap.num_rels,
+                         snap.mem_subj[: snap.n_tuples]], axis=1)
+
+    everything = tuples_of(whole)
+    assert len(everything) == len(world)
+    seen = []
+    for s, snap in enumerate(eng._shard_snaps):
+        mine = tuples_of(snap)
+        assert (shard_of_np(mine[:, 0], mine[:, 1], n) == s).all(), s
+        seen.append(mine)
+    seen = np.concatenate(seen)
+    assert len(seen) == len(everything)  # none twice, none missing
+    order = lambda a: a[np.lexsort(a.T[::-1])]  # noqa: E731
+    assert (order(seen) == order(everything)).all()
+
+    def member(snap, probe):
+        """Whether ``probe`` rows (ns, obj, rel, subject) are tuples of
+        ``snap``: the direct-membership probe, on the host."""
+        have = {tuple(t) for t in tuples_of(snap).tolist()}
+        return np.array([tuple(p) in have for p in probe.tolist()])
+
+    rng = np.random.default_rng(29)
+    present = everything[rng.integers(len(everything), size=256)]
+    absent = present.copy()
+    absent[:, 3] = rng.integers(world.U, size=256)  # mostly no such tuple
+    probe = np.concatenate([present, absent])
+    by_shard = np.stack([member(s, probe) for s in eng._shard_snaps])
+    assert (by_shard.sum(axis=0) <= 1).all()
+    want = member(whole, probe)
+    assert (by_shard.any(axis=0) == want).all()
+    assert want[:256].all() and not want[256:].all()
+
+
+def test_mesh_programs_carry_their_scopes_and_spans(drive_mesh):
+    """What the benchmark's mesh_* metrics read: the scopes in both
+    sharded programs' lowered text and the engine phases of a dispatch."""
+    eng, counts, texts = drive_mesh.eng, drive_mesh.counts, drive_mesh.texts
+    assert counts["check_mesh_fast"] == 1
+    assert counts["check_mesh_general"] == 1
+    assert counts["check_mesh_lock_wait"] == 2  # once a program
+    assert counts["check_mesh_dispatch"] == 1
+    assert "check_mesh_retry" not in counts and eng.retries == 0
+
+    fast, general = texts["_sharded_fast_run"], texts["_sharded_general_run"]
+    for scope in ["probe/node_table", "probe/mem_table", "mesh/merge",
+                  *(f"tier/fast/level{i}/mesh/route" for i in range(5))]:
+        assert f"{scope}/" in fast, scope
+    for scope in ["tier/general/level0/mesh/merge", "tier/general/up",
+                  *(f"tier/general/leaves/level{i}/mesh/route"
+                    for i in range(5))]:
+        assert f"{scope}/" in general, scope
+    for text in (fast, general):
+        assert "mesh/route/all_to_all" in text
+        assert "mesh/merge/psum" in text
