@@ -1551,6 +1551,10 @@ class Registry:
                 help="waves dispatched as one fused device program")
         m.gauge("keto_fused_d2h_fetches_total", eng.fused_d2h_fetches,
                 help="device-to-host fetches for fused waves (1 per wave)")
+        m.gauge("keto_fused_general_rows_total", eng.fused_general_rows,
+                help="fused-wave rows that needed the general tier")
+        m.gauge("keto_fused_general_lanes_total", eng.fused_general_lanes,
+                help="lanes the fused waves ran the general tier at")
         for tier, rows in eng.fused_tier_rows.items():
             m.gauge("keto_fused_tier_rows_total", rows,
                     help="fused-wave rows attributed per answering tier",

@@ -38,6 +38,25 @@ from ketotpu.cache import check_key as cache_check_key
 from ketotpu.cache import context as cache_context
 from ketotpu.engine import columns as colmod
 
+#: the engine's fused-wave counters -> their per-wave delta's field in a
+#: wave-ledger entry's ``fused`` group
+_FUSED_COUNTERS = {
+    "fused_waves": "waves",
+    "fused_d2h_fetches": "d2h_fetches",
+    "fused_general_rows": "general_rows",
+    "fused_general_lanes": "general_lanes",
+}
+
+
+def _fused_counts(inner) -> Tuple[Dict[str, int], Dict[str, int]]:
+    """(ledger field -> counter, tier -> rows) of ``inner`` right now;
+    zeros for an engine without the fused dispatch."""
+    counts = {
+        field: int(getattr(inner, attr, 0) or 0)
+        for attr, field in _FUSED_COUNTERS.items()
+    }
+    return counts, dict(getattr(inner, "fused_tier_rows", None) or {})
+
 
 class _Slot:
     __slots__ = ("tuple", "depth", "bypass", "event", "result", "error",
@@ -560,12 +579,9 @@ class CoalescingEngine:
         phase_before = dict(getattr(inner, "phase_seconds", None) or {})
         # fused tiered dispatch (engine/fused.py): per-wave deltas of the
         # fused-wave count, its D2H fetches (the single-fetch invariant is
-        # checked as waves == fetches) and the per-tier row attribution
-        fused_before = (
-            int(getattr(inner, "fused_waves", 0) or 0),
-            int(getattr(inner, "fused_d2h_fetches", 0) or 0),
-            dict(getattr(inner, "fused_tier_rows", None) or {}),
-        )
+        # checked as waves == fetches), the general tier's rows and lanes
+        # (how full the tier ran) and the per-tier row attribution
+        fused_before = _fused_counts(inner)
         # per-shard wave accounting (mesh serving): routed-root deltas
         # across this wave's dispatches land in the ledger entry
         routes_fn = getattr(inner, "shard_route_counts", None)
@@ -782,16 +798,13 @@ class CoalescingEngine:
              if s.t_dispatch is not None and s.traceparent is not None),
             key=lambda s: s.t_dispatch - s.t_enq, reverse=True,
         )[:3]
-        fused = {"waves": 0, "d2h_fetches": 0, "tiers": {}}
+        fused = dict.fromkeys(_FUSED_COUNTERS.values(), 0)
+        fused["tiers"] = {}
         if fused_before is not None:
-            fw, fd, ftiers = fused_before
-            fused["waves"] = max(
-                0, int(getattr(inner, "fused_waves", 0) or 0) - fw
-            )
-            fused["d2h_fetches"] = max(
-                0, int(getattr(inner, "fused_d2h_fetches", 0) or 0) - fd
-            )
-            now = dict(getattr(inner, "fused_tier_rows", None) or {})
+            before, ftiers = fused_before
+            after, now = _fused_counts(inner)
+            for field in after:
+                fused[field] = max(0, after[field] - before[field])
             fused["tiers"] = {
                 t: d for t, d in (
                     (t, int(now[t]) - int(ftiers.get(t, 0))) for t in now

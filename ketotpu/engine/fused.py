@@ -25,14 +25,19 @@ This module compiles the whole cascade into ONE program:
   re-walks at retry capacity without a host round-trip, found bits
   accumulate monotonically (a tier-1 IS can never be revoked).
 * **tier 2 — general algebra** (``alg._general_body``): the AND/NOT
-  rows run done-masked in the same program, plus one boosted retry
-  lane mirroring the unfused general overflow re-run.
+  rows are compacted in-program into ``gen_lanes`` lanes (the host
+  sizes them by the rows that need the tier, with the unfused
+  ``_run_general``'s half-octave rule) and run there, plus one boosted
+  retry lane on the same lanes mirroring the unfused general overflow
+  re-run; the lanes' bits scatter back to their wave rows.
 
 Exactly ONE D2H fetch returns everything the collector needs: per-row
 verdict codes AND per-tier attribution masks packed into one int32 bit
 field, concatenated with the two occupancy vectors the adaptive
 scheduler feeds on.  Layout of the returned int32[Q + F + G] array
-(Q = padded wave rows, F = len(fast_sched), G = general occ length):
+(Q = padded wave rows, F = len(fast_sched), G = general occ length;
+the general lanes are inside the program only: their bits come back
+on the wave rows they were gathered from):
 
 =====  ==========================================================
 bits   per-row meaning (first Q entries)
@@ -88,6 +93,7 @@ def _wave_body(
     retry_lanes: int,
     gen: Tuple,
     gen_retry: Optional[Tuple],
+    gen_lanes: int,
     max_width: int,
     depth_slack: int,
 ):
@@ -98,6 +104,8 @@ def _wave_body(
     leopard probe element id.  The probe ids are -1 on rows the probe
     must miss (ineligible, unknown node/subject — consistent with the
     host path, where a -1 key can never match a non-negative pair).
+    ``gen_lanes``: the root count ``gen`` and ``gen_retry`` were sized
+    for, at least the wave's general rows and at most Q.
 
     Each tier's operations carry a ``jax.named_scope`` (``tier/leopard``,
     ``tier/fast``, ``tier/general``; levels and hash probes nest inside),
@@ -178,17 +186,17 @@ def _wave_body(
             fast_fb = (fast_act & dirty1 & ~found1) | unres
             occ_tail.append(focc)
 
-    # -- tier 2: general algebra, done-masked ------------------------------
-    izeros = jnp.zeros((Q,), jnp.int32)
-    gcode = izeros
-    gover = zeros
-    gdirty = zeros
-    gen_retried = zeros
+    # -- tier 2: general algebra on the rows that need it --------------------
+    # every skeleton level, leaf buffer and hash probe of the tier scales
+    # with its root count, so the general rows are gathered into gen_lanes
+    # lanes (order kept, padding inactive) and their bits scattered back
+    gen_bits = jnp.zeros((Q,), jnp.int32)
     if gen is not None:
         with jax.named_scope("tier/general"):
-            gpack = jnp.stack(
-                [q_ns, q_obj, q_rel, q_subj, q_depth, gact.astype(jnp.int32)]
-            )
+            (gidx,) = jnp.nonzero(gact, size=gen_lanes, fill_value=Q)
+            lact = gidx < Q
+            lq = jnp.take(qpack[:5], gidx, axis=1, mode="clip")
+            gpack = jnp.concatenate([lq, lact.astype(jnp.int32)[None]])
             gcodes, gocc = alg._general_body(
                 g, gpack, sizes=gen[0], fast_b=gen[1], fast_sched=gen[2],
                 max_width=max_width, vcap=gen[3],
@@ -196,13 +204,11 @@ def _wave_body(
             gcode = (gcodes & 3).astype(jnp.int32)
             gover = ((gcodes >> 2) & 1).astype(bool)
             gdirty = ((gcodes >> 3) & 1).astype(bool)
+            gen_retried = jnp.zeros((gen_lanes,), bool)
             if gen_retry is not None:
-                gunres = gact & gover & ~gdirty & (gcode != R_ERR)
+                gunres = lact & gover & ~gdirty & (gcode != R_ERR)
                 gen_retried = gunres
-                rpack = jnp.stack(
-                    [q_ns, q_obj, q_rel, q_subj, q_depth,
-                     gunres.astype(jnp.int32)]
-                )
+                rpack = jnp.concatenate([lq, gunres.astype(jnp.int32)[None]])
                 with jax.named_scope("retry"):
                     rcodes, _rgocc = alg._general_body(
                         g, rpack, sizes=gen_retry[0], fast_b=gen_retry[1],
@@ -216,18 +222,26 @@ def _wave_body(
                 gover = jnp.where(
                     gunres, rover | rdirty | (rcode == R_ERR), gover
                 )
+            lane_bits = (
+                gcode
+                | (gover.astype(jnp.int32) << 2)
+                | (gdirty.astype(jnp.int32) << 3)
+                | (gen_retried.astype(jnp.int32) << 9)
+            )
+            # a general row that found no lane reads over (the oracle
+            # answers it); padding lanes carry index Q and drop
+            gen_bits = jnp.where(gact, 1 << 2, 0).at[gidx].set(
+                lane_bits, mode="drop"
+            )
             occ_tail.append(gocc)
 
     rows = (
-        gcode
-        | (gover.astype(jnp.int32) << 2)
-        | (gdirty.astype(jnp.int32) << 3)
+        gen_bits
         | (found.astype(jnp.int32) << 4)
         | (fast_fb.astype(jnp.int32) << 5)
         | (leo_ans.astype(jnp.int32) << 6)
         | (leo_allow.astype(jnp.int32) << 7)
         | (retried.astype(jnp.int32) << 8)
-        | (gen_retried.astype(jnp.int32) << 9)
     )
     return jnp.concatenate([rows, *occ_tail])
 
@@ -236,7 +250,7 @@ _run_wave = functools.partial(
     jax.jit,
     static_argnames=(
         "fast_sched", "retry_sched", "retry_lanes", "gen", "gen_retry",
-        "max_width", "depth_slack",
+        "gen_lanes", "max_width", "depth_slack",
     ),
 )(_wave_body)
 
@@ -250,6 +264,7 @@ def run_fused_wave(
     retry_lanes: int,
     gen: Tuple,
     gen_retry: Optional[Tuple],
+    gen_lanes: int = 0,
     max_width: int = 100,
     depth_slack: int = 2,
     span=profiler.null_span,
@@ -263,7 +278,8 @@ def run_fused_wave(
     with span("check_fused_dispatch", rows=Q), compilewatch.scope(
         "fused_wave",
         lambda: (
-            f"Q={Q} fast={fast_sched} retry={retry_sched}x{retry_lanes} "
+            f"Q={Q} GQ={gen_lanes} fast={fast_sched} "
+            f"retry={retry_sched}x{retry_lanes} "
             f"gen={gen} genr={gen_retry} width={max_width}"
         ),
     ):
@@ -271,6 +287,7 @@ def run_fused_wave(
             g, qpack,
             fast_sched=fast_sched, retry_sched=retry_sched,
             retry_lanes=retry_lanes, gen=gen, gen_retry=gen_retry,
-            max_width=max_width, depth_slack=depth_slack,
+            gen_lanes=gen_lanes, max_width=max_width,
+            depth_slack=depth_slack,
         )
     return out

@@ -240,6 +240,10 @@ class DeviceCheckEngine:
         self.fused_retry_lanes = max(int(fused_retry_lanes), 0)
         self.fused_waves = 0  # observability: fused waves collected
         self.fused_d2h_fetches = 0  # observability: D2H fetches (1/wave)
+        # rows of fused waves that needed the general tier, and the lanes
+        # the program ran it at: their quotient is how full the tier ran
+        self.fused_general_rows = 0
+        self.fused_general_lanes = 0
         # per-tier row attribution for fused waves, from the returned
         # masks (keto_fused_tier_rows_total; wave-ledger tier deltas)
         self.fused_tier_rows = {
@@ -1443,9 +1447,12 @@ class DeviceCheckEngine:
         retry lanes) compiles into ONE device program (engine/fused.py)
         with ONE D2H fetch at collect.  The host keeps only the leopard
         work that needs dict state (closure.prep_fused_checks) and ships
-        it as per-row probe modes; answered-masks gate the later tiers
+        it as per-row probe modes; answered-masks gate the fast tier
         in-program, so resolved rows are dead weight instead of
-        host-filtered between dispatches."""
+        host-filtered between dispatches.  The general tier is sized
+        here by the rows that need it (``gen_lanes``, the padding
+        :meth:`_run_general` gives them) and the program compacts them
+        into that many lanes itself: still one upload a wave."""
         n = len(queries)
         q_ns, q_obj, q_rel, q_subj, q_depth = enc
         lmode = np.zeros(n, np.int32)
@@ -1524,11 +1531,18 @@ class DeviceCheckEngine:
                     self.retry_scale * self.arena, self.max_depth,
                     self.retry_scale,
                 )
+        # the general tier's every buffer scales with its root count:
+        # it is compiled for the general rows at the unfused cascade's
+        # half-octave padding, not for the wave (333 of 1024 rows run in
+        # 384 lanes), one program a bucket the count falls into
         gen = gen_retry = None
-        if general.any():
-            gen = self._gen_schedule(qpad, 1)
+        n_general = int(general.sum())
+        gen_lanes = 0
+        if n_general:
+            gen_lanes = min(_bucket15(n_general, 256), qpad)
+            gen = self._gen_schedule(gen_lanes, 1)
             if self.retry_scale > 1 and self.fused_retry_lanes > 0:
-                gen_retry = self._gen_schedule(qpad, self.retry_scale)
+                gen_retry = self._gen_schedule(gen_lanes, self.retry_scale)
         g = dev_arrays
         if leo_dev is not None:
             g = dict(dev_arrays, leo_sets=leo_dev["sets"],
@@ -1539,10 +1553,12 @@ class DeviceCheckEngine:
             "glen": (len(gen[0]) + 2 + len(gen[2])) if gen is not None
                     else 0,
             "gen_fast_b": gen[1] if gen is not None else 0,
+            "gen_rows": n_general, "gen_lanes": gen_lanes,
         }
         scheds = dict(
             fast_sched=fast_sched, retry_sched=retry_sched,
             retry_lanes=lanes, gen=gen, gen_retry=gen_retry,
+            gen_lanes=gen_lanes,
         )
         # a MUTABLE list handle (same slot layout as the unfused tuple):
         # _dispatch_fused fills in the device result, and the collector
@@ -1897,6 +1913,8 @@ class DeviceCheckEngine:
             packed = np.asarray(fres)  # the wave's single D2H fetch
         self.fused_waves += 1
         self.fused_d2h_fetches += 1
+        self.fused_general_rows += meta["gen_rows"]
+        self.fused_general_lanes += meta["gen_lanes"]
         rows = packed[:n]
         focc = packed[qpad:qpad + meta["flen"]]
         gocc = packed[qpad + meta["flen"]:

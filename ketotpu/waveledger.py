@@ -83,7 +83,7 @@ class WaveLedger:
         # fused tiered dispatch (engine/fused.py): ring-wide sums of the
         # per-wave deltas; `fused_waves == fused_d2h_fetches` IS the
         # single-fetch-per-wave invariant the serving bench asserts
-        fused_waves = fused_d2h = 0
+        fused_waves = fused_d2h = fused_gen_rows = fused_gen_lanes = 0
         fused_tiers: Dict[str, int] = {}
         # multi-host mesh: ring-wide sums of each wave's per-peer
         # shipped-row deltas — how much of the recent window crossed DCN
@@ -92,6 +92,8 @@ class WaveLedger:
             f = e.get("fused") or {}
             fused_waves += int(f.get("waves", 0))
             fused_d2h += int(f.get("d2h_fetches", 0))
+            fused_gen_rows += int(f.get("general_rows", 0))
+            fused_gen_lanes += int(f.get("general_lanes", 0))
             for t, d in (f.get("tiers") or {}).items():
                 fused_tiers[t] = fused_tiers.get(t, 0) + int(d)
             for h, d in (e.get("peers") or {}).items():
@@ -108,6 +110,8 @@ class WaveLedger:
             "device_ms_p95": round(_percentile(devs, 0.95), 3),
             "fused_waves": fused_waves,
             "fused_d2h_fetches": fused_d2h,
+            "fused_general_rows": fused_gen_rows,
+            "fused_general_lanes": fused_gen_lanes,
             "fused_tier_rows": fused_tiers,
             "peer_rows": peer_rows,
         }

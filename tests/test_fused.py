@@ -8,6 +8,10 @@ Breadth runs with the wave body EAGER (``_run_wave`` monkeypatched to
 each fresh fused shape costs XLA:CPU tens of seconds — one small jitted
 leg (marked slow; the CI serve-northstar job runs it) covers the real
 compiled path and the steady-state no-recompile gate.
+
+How the wave sizes its general tier (cases by the general share of a
+wave) is held in tests/test_fused_lanes.py: xdist hands out whole files,
+and this one is the longest of the run.
 """
 
 import numpy as np

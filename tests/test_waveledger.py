@@ -9,6 +9,7 @@ check/expand waves.
 """
 
 import json
+import random
 import re
 import threading
 import time
@@ -23,7 +24,7 @@ from ketotpu.compilewatch import _COMPILE_EVENT, CompileWatch
 from ketotpu.driver import Provider, Registry
 from ketotpu.driver.config import ConfigError
 from ketotpu.engine.coalesce import CoalescingEngine
-from ketotpu.engine.tpu import DeviceCheckEngine
+from ketotpu.engine.tpu import DeviceCheckEngine, _bucket15
 from ketotpu.flightrec import FlightRecorder
 from ketotpu.observability import Metrics, Tracer, make_logger
 from ketotpu.profiler import DeviceProfiler, ProfilerDisabled
@@ -243,6 +244,79 @@ def test_wave_records_singleflight_followers():
     assert results == [True] * 6
     total = sum(w["singleflight_collapsed"] for w in led.snapshot())
     assert total == co.singleflight_collapsed > 0
+
+
+class _SizingInner(_FakeInner):
+    """Counts like the engine's fused collect does: every wave holds a
+    seeded count of general rows of up to 1024 (a column group's rows ride
+    one slot) and runs them at the engine's half-octave lanes."""
+
+    fused_waves = fused_d2h_fetches = 0
+    fused_general_rows = fused_general_lanes = 0
+
+    def __init__(self, seed):
+        self._rng = random.Random(seed)
+        self.sized = []
+
+    def batch_check(self, queries, rest_depth=0):
+        rows = self._rng.choice((0, 1, 256, 257, 333, 1024))
+        lanes = min(_bucket15(rows, 256), 1024) if rows else 0
+        self.fused_waves += 1
+        self.fused_d2h_fetches += 1
+        self.fused_general_rows += rows
+        self.fused_general_lanes += lanes
+        self.sized.append((rows, lanes))
+        return [True] * len(queries)
+
+
+@pytest.mark.parametrize("seed", [30, 31])
+def test_wave_records_general_rows_and_lanes(seed):
+    """The general tier's rows and lanes of every wave, as the engine
+    counted them at collect: per wave in the entry's ``fused`` group, over
+    a window as the two sums whose quotient ``wave_general_fill`` reads."""
+    inner = _SizingInner(seed)
+    led = WaveLedger()
+    co = CoalescingEngine(inner, window=0.001, ledger=led)
+    before = led.stats()
+    try:
+        for i in range(8):
+            assert co.check_is_member(T(f"Doc:d{i}#edit@u{i}")) is True
+    finally:
+        co.close()
+    waves = list(reversed(led.snapshot()))
+    assert [(w["fused"]["general_rows"], w["fused"]["general_lanes"])
+            for w in waves] == inner.sized
+    assert all(w["fused"]["waves"] == w["fused"]["d2h_fetches"] == 1
+               for w in waves)
+    after = led.stats()
+    moved = tuple(after[k] - before[k]
+                  for k in ("fused_general_rows", "fused_general_lanes"))
+    assert moved == tuple(map(sum, zip(*inner.sized)))
+    assert moved == (inner.fused_general_rows, inner.fused_general_lanes)
+
+
+def test_general_rows_and_lanes_on_the_scrape():
+    """The registry publishes the engine's two counters as they stand:
+    a window's delta of each is the engine's own."""
+    reg = Registry(Provider({
+        "namespaces": [{"name": "Doc"}],
+        "engine": {"kind": "tpu", "coalesce_ms": 0},
+    })).init()
+    try:
+        eng = reg.check_engine()
+        eng = getattr(eng, "inner", eng)
+        names = ("keto_fused_general_rows_total",
+                 "keto_fused_general_lanes_total")
+        reg.sample_engine_metrics()
+        before = [reg.metrics().get_gauge(n) for n in names]
+        assert before == [0, 0]
+        # what two collected waves of 300 and 333 general rows leave
+        eng.fused_general_rows += 300 + 333
+        eng.fused_general_lanes += 384 + 384
+        reg.sample_engine_metrics()
+        assert [reg.metrics().get_gauge(n) for n in names] == [633, 768]
+    finally:
+        reg.close_engines()
 
 
 # -- config + registry plumbing ----------------------------------------------
