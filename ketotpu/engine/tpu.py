@@ -61,7 +61,7 @@ from ketotpu.engine import algebra as alg
 from ketotpu.engine import delta as dl
 from ketotpu.engine import fastpath as fp
 from ketotpu.engine import fused as fdx
-from ketotpu.engine.optable import R_ERR, R_IS
+from ketotpu.engine import wave as wv
 from ketotpu.engine.oracle import (
     DEFAULT_MAX_DEPTH,
     DEFAULT_MAX_WIDTH,
@@ -69,6 +69,7 @@ from ketotpu.engine.oracle import (
 )
 from ketotpu.engine.snapshot import Snapshot
 from ketotpu.engine.vocab import Vocab
+from ketotpu.engine.wave import Wave, _bucket, _bucket15
 from ketotpu.leopard import closure as leo
 from ketotpu.leopard import device as leodev
 from ketotpu.leopard import hostlist as leolist
@@ -76,26 +77,6 @@ from ketotpu.storage.memory import InMemoryTupleStore
 from ketotpu.storage.namespaces import NamespaceManager
 
 _log = logging.getLogger("ketotpu.engine")
-
-
-def _bucket(n: int, floor: int = 256) -> int:
-    b = floor
-    while b < n:
-        b *= 2
-    return b
-
-
-def _bucket15(n: int, floor: int = 64) -> int:
-    """Smallest of {2^k, 1.5*2^k} >= n: pow2 rounding wastes up to ~50%
-    of every buffer (and per-level device cost scales with buffer size);
-    the half-octave step bounds waste at ~33% while adding at most one
-    extra compile variant per octave."""
-    b = floor
-    while b < n:
-        if b * 3 // 2 >= n:
-            return b * 3 // 2
-        b *= 2
-    return b
 
 
 #: per-level task multipliers (units of general roots) for the algebra
@@ -135,10 +116,11 @@ def config_fingerprint(manager: Optional[NamespaceManager]) -> int:
 class DeviceCheckEngine:
     """Batched permission checks on the device, oracle fallback on the host."""
 
-    # the mesh engine opts out of both: its device state is per-shard
-    # stacks with their own publish discipline
+    # the mesh engine opts out of all three: its device state is per-shard
+    # stacks with their own publish discipline and their own launchers
     supports_fold = True
     supports_background_compaction = True
+    supports_fused = True
 
     def __init__(
         self,
@@ -950,7 +932,7 @@ class DeviceCheckEngine:
             }
 
     def _sync_view(self):
-        """Atomic (snapshot, device_arrays, overlay_active, cursor) view.
+        """Atomic (snapshot, device_arrays, cursor) view.
         Writers mutate all of these together under ``_sync_lock``, so a
         dispatching thread must capture them together — reading
         ``_device_arrays`` after releasing the lock could pair a new
@@ -964,8 +946,7 @@ class DeviceCheckEngine:
             # compaction the drain can run ahead of what the device view
             # covers, and cache entries must be stamped with what the
             # verdicts actually describe
-            return (snap, self._device_arrays, self._overlay_active,
-                    self._served_cursor)
+            return snap, self._device_arrays, self._served_cursor
 
     def refresh(self) -> None:
         """Force a full rebuild (the CheckRequest.latest consistency knob —
@@ -1271,10 +1252,10 @@ class DeviceCheckEngine:
             # dispatch everything before syncing on anything: device
             # executions queue back-to-back while the host reads earlier
             # chunks' results
-            handles = [self._dispatch(c, rest_depth) for c in chunks]
+            waves = [self._dispatch(c, rest_depth) for c in chunks]
             out: List[bool] = []
-            for c, h in zip(chunks, handles):
-                out.extend(self._finish_chunk(c, h, rest_depth).tolist())
+            for c, w in zip(chunks, waves):
+                out.extend(self._finish_chunk(c, w, rest_depth).tolist())
         except KetoAPIError:
             raise  # typed client errors (and deadline/shed) pass through
         except Exception:  # noqa: BLE001
@@ -1363,98 +1344,119 @@ class DeviceCheckEngine:
 
     def _dispatch(self, queries: Sequence[RelationTuple], rest_depth: int,
                   fused: Optional[bool] = None):
-        """Enqueue one chunk's device work; returns an uncollected handle.
-        ``fused`` overrides the engine flag per call (diagnostic surfaces
-        pin the unfused cascade: its host-side tiers are individually
-        observable)."""
+        """Enqueue one chunk's device work; returns the uncollected
+        :class:`Wave`.  ``fused`` overrides the engine flag per call
+        (diagnostic surfaces pin the unfused cascade: its host-side tiers
+        are individually observable).  Every launcher shares the prefix
+        view -> encode -> classify -> Leopard -> cache -> route -> pad."""
         n = len(queries)
         if n == 0:
             return None
         faults.inject("device_dispatch")
         self.dispatches += 1
-        use_fused = self.fused_dispatch if fused is None else fused
+        use_fused = self.supports_fused and (
+            self.fused_dispatch if fused is None else fused)
         with self._span("check_encode", rows=n):
-            snap, dev_arrays, overlay_active, cursor = self._sync_view()
+            snap, arrays, cursor = self._sync_view()
             enc = self._encode(snap, queries, rest_depth)
             err, general = self._classify(snap, enc[0], enc[2])
+            active = ~(err | general)
+            leo_res = probe = None
             if use_fused:
-                wave = self._encode_fused(
-                    queries, rest_depth, dev_arrays, cursor, enc, err,
-                    general,
-                )
+                # the program finishes the closure probe itself and masks
+                # the rows it answers; the cache is told which rows the
+                # host already KNOWS are answered
+                known, probe = self._leopard_modes(
+                    enc, err, general, rest_depth)
             else:
                 # Leopard first: closure-eligible fast queries resolve as
                 # one sorted-pair binary search and leave the device walk
-                # entirely (their fast_active bit drops, so the BFS does
-                # no work for them)
-                leo_res = self._leopard_answers(enc, err, general)
-                active = ~(err | general)
+                # entirely (their active bit drops, so the BFS does no
+                # work for them)
+                known = leo_res = self._leopard_answers(enc, err, general)
                 if leo_res is not None:
                     active &= ~leo_res[1]
-                # hot-spot shield after Leopard: cached verdicts drop
-                # their queries from the device walk AND the algebra
-                # dispatch
-                cache_res = self._cache_consult(
-                    queries, rest_depth, err, general, leo_res, cursor)
-                if cache_res is not None:
-                    active &= ~cache_res[0]
-                    general = general & ~cache_res[0]
-                # pad for compile-cache reuse, but never beyond the
-                # frontier cap (max_batch <= frontier guarantees n fits)
-                qpad = min(_bucket(n), self.frontier)
-                padded = self._pad(enc, n, qpad)
-                fast_active = np.pad(active, (0, qpad - n))
-        if use_fused:
-            return self._dispatch_fused(wave)
-        if fast_active.any():
-            # ONE packed upload + ONE packed verdict download per chunk:
-            # each separate transfer is a full host-link round-trip
-            # (fastpath _run_fused_packed)
-            qpack = np.stack(
-                [*padded, fast_active.astype(np.int32)]
-            ).astype(np.int32)
-            res, occ = fp.run_fast_packed(
-                dev_arrays,
-                qpack,
-                frontier=self.frontier,
-                arena=self.arena,
-                max_depth=self.max_depth,
-                max_width=self.max_width,
-                mults=self._adaptive_mults(),
-                span=self._span,
+            # hot-spot shield after Leopard: cached verdicts drop their
+            # queries from the device walk AND the algebra dispatch
+            cache_res = self._cache_consult(queries, rest_depth, err, known)
+            if cache_res is not None:
+                active &= ~cache_res[0]
+                general = general & ~cache_res[0]
+            wave = Wave(
+                n=n, qpad=wv.wave_rows(n, self.frontier), enc=enc, err=err,
+                general=general, cursor=cursor, arrays=arrays,
+                leo_res=leo_res, cache_res=cache_res,
             )
+            active = self._route(queries, rest_depth, wave, active)
+            padded = self._pad(enc, n, wave.qpad)
+            if use_fused:
+                launch = self._encode_fused(
+                    wave, padded, active, known is not None, probe)
+            else:
+                active = np.pad(active, (0, wave.qpad - n))
+        if use_fused:
+            self._dispatch_fused(wave, *launch)
         else:
-            # the whole chunk resolved off-device (closure index and/or
-            # err/general routing): skip the dispatch, not just the work
-            res = occ = None
+            self._launch(wave, padded, active)
+        return wave
+
+    def _route(self, queries, rest_depth, wave, active):
+        """Hook inside ``check_encode``: rows may leave the wave here (into
+        ``wave.err``, out of ``wave.general`` and of the returned
+        fast-active mask).  One chip serves every row itself."""
+        return active
+
+    def _launch(self, wave, padded, active) -> None:
+        """Enqueue the cascade's tiers; their uncollected results go into
+        ``wave``."""
+        wave.fast, wave.occ = self._run_fast(wave, padded, active)
         # the algebra program is overlay-aware (probes consult the om_
         # delta tables, stale edge rows raise the per-query dirty bit that
         # routes just those queries to the oracle), so general queries
         # dispatch on-device even with pending writes
-        gres = gi = None
-        if general.any():
-            gi = np.flatnonzero(general)
-            gres = self._run_general(dev_arrays, enc, gi)
-        return (enc, err, general, res, gi, gres, dev_arrays, occ, leo_res,
-                cache_res, cursor)
+        if wave.general.any():
+            wave.gi = np.flatnonzero(wave.general)
+            wave.gen = self._run_general(wave.arrays, wave.enc, wave.gi)
 
-    def _encode_fused(self, queries, rest_depth, dev_arrays, cursor,
-                      enc, err, general):
-        """Host half of the fused branch of ``_dispatch`` (inside its
-        ``check_encode`` span): everything :meth:`_dispatch_fused` hands
-        to the device.  The whole tier cascade (leopard
-        probe -> fast BFS -> general algebra, with bounded in-program
-        retry lanes) compiles into ONE device program (engine/fused.py)
-        with ONE D2H fetch at collect.  The host keeps only the leopard
-        work that needs dict state (closure.prep_fused_checks) and ships
-        it as per-row probe modes; answered-masks gate the fast tier
-        in-program, so resolved rows are dead weight instead of
-        host-filtered between dispatches.  The general tier is sized
-        here by the rows that need it (``gen_lanes``, the padding
-        :meth:`_run_general` gives them) and the program compacts them
-        into that many lanes itself: still one upload a wave."""
-        n = len(queries)
+    def _run_fast(self, wave, padded, active, boost: int = 1, rows=None):
+        """Enqueue the fast tier for padded rows; returns the uncollected
+        (verdict words, occupancy), or (None, None) when no row is active:
+        the whole chunk resolved off-device, so skip the dispatch, not
+        just the work.  ONE packed upload + ONE packed verdict download
+        per launch: each separate transfer is a full host-link round-trip.
+        ``boost`` > 1 is the retry: caps and the per-query schedule both
+        scale (with a small retry batch the caps alone don't bind), and
+        no adaptive mults — the retry exists because the demand-sized
+        tier missed.  ``rows``: which of the wave's rows ``padded`` holds
+        (None: all of them), for a launcher with per-row state in the wave."""
+        if not active.any():
+            return None, None
+        first = boost == 1
+        qpack = np.stack([*padded, active.astype(np.int32)]).astype(np.int32)
+        return fp.run_fast_packed(
+            wave.arrays,
+            qpack,
+            frontier=boost * self.frontier,
+            arena=boost * self.arena,
+            max_depth=self.max_depth,
+            max_width=self.max_width,
+            boost=boost,
+            mults=self._adaptive_mults() if first else None,
+            # a retry is timed whole, as check_retry
+            span=self._span if first else profiler.null_span,
+        )
+
+    def _leopard_modes(self, enc, err, general, rest_depth):
+        """The closure index's half of a fused wave: the leopard work that
+        needs dict state (closure.prep_fused_checks), shipped as per-row
+        probe modes; answered-masks gate the fast tier in-program, so
+        resolved rows are dead weight instead of host-filtered between
+        dispatches.  Returns ``(known, (lmode, leo_set, leo_elt,
+        leo_dev))``: ``known`` is what the result cache is told — ``(None,
+        rows the host already KNOWS are answered)``, or None with the
+        index off."""
         q_ns, q_obj, q_rel, q_subj, q_depth = enc
+        n = len(q_ns)
         lmode = np.zeros(n, np.int32)
         leo_set = np.full(n, -1, np.int32)
         leo_elt = np.full(n, -1, np.int32)
@@ -1491,17 +1493,23 @@ class DeviceCheckEngine:
         lmode[err | general] = leo.LM_NONE
         # the cache sees every row the host KNOWS is unanswered; rows the
         # device probe may yet answer keep leopard precedence at collect
-        pre_ans = (lmode == leo.LM_ALLOW) | (lmode == leo.LM_DENY)
-        cache_res = self._cache_consult(
-            queries, rest_depth, err, general,
-            (None, pre_ans) if has_leo else None, cursor,
-        )
-        fast_elig = ~(err | general)
-        if cache_res is not None:
-            fast_elig &= ~cache_res[0]
-            general = general & ~cache_res[0]
-        qpad = min(_bucket(n), self.frontier)
-        padded = self._pad(enc, n, qpad)
+        known = None
+        if has_leo:
+            known = (None, (lmode == leo.LM_ALLOW) | (lmode == leo.LM_DENY))
+        return known, (lmode, leo_set, leo_elt, leo_dev)
+
+    def _encode_fused(self, wave, padded, fast_elig, has_leo, probe):
+        """Host half of the fused launch (inside ``_dispatch``'s
+        ``check_encode`` span): everything :meth:`_dispatch_fused` hands
+        to the device.  The whole tier cascade (leopard probe -> fast
+        BFS -> general algebra, with bounded in-program retry lanes)
+        compiles into ONE device program (engine/fused.py) with ONE D2H
+        fetch at collect.  The general tier is sized here by the rows
+        that need it (``gen_lanes``, the padding :meth:`_run_general`
+        gives them) and the program compacts them into that many lanes
+        itself: still one upload a wave."""
+        lmode, leo_set, leo_elt, leo_dev = probe
+        n, qpad, general = wave.n, wave.qpad, wave.general
         pad = qpad - n
         qpack = np.stack([
             *padded,
@@ -1531,24 +1539,20 @@ class DeviceCheckEngine:
                     self.retry_scale * self.arena, self.max_depth,
                     self.retry_scale,
                 )
-        # the general tier's every buffer scales with its root count:
-        # it is compiled for the general rows at the unfused cascade's
-        # half-octave padding, not for the wave (333 of 1024 rows run in
-        # 384 lanes), one program a bucket the count falls into
+        # one program a bucket the general count falls into
         gen = gen_retry = None
         n_general = int(general.sum())
-        gen_lanes = 0
-        if n_general:
-            gen_lanes = min(_bucket15(n_general, 256), qpad)
+        gen_lanes = wv.general_lanes(n_general, qpad)
+        if gen_lanes:
             gen = self._gen_schedule(gen_lanes, 1)
             if self.retry_scale > 1 and self.fused_retry_lanes > 0:
                 gen_retry = self._gen_schedule(gen_lanes, self.retry_scale)
-        g = dev_arrays
+        g = wave.arrays
         if leo_dev is not None:
-            g = dict(dev_arrays, leo_sets=leo_dev["sets"],
+            g = dict(g, leo_sets=leo_dev["sets"],
                      leo_elts=leo_dev["elts"], leo_hops=leo_dev["hops"])
-        meta = {
-            "n": n, "qpad": qpad, "has_leo": has_leo,
+        wave.meta = {
+            "has_leo": has_leo,
             "flen": len(fast_sched) if fast_sched is not None else 0,
             "glen": (len(gen[0]) + 2 + len(gen[2])) if gen is not None
                     else 0,
@@ -1560,27 +1564,18 @@ class DeviceCheckEngine:
             retry_lanes=lanes, gen=gen, gen_retry=gen_retry,
             gen_lanes=gen_lanes,
         )
-        # a MUTABLE list handle (same slot layout as the unfused tuple):
-        # _dispatch_fused fills in the device result, and the collector
-        # writes the decoded leopard/cache slots back so ``_note_tiers``
-        # and ``_cache_fill`` read them unchanged
-        handle = [enc, err, general, None, None, meta, dev_arrays, None,
-                  None, cache_res, cursor]
-        return handle, g, qpack, scheds
+        return g, qpack, scheds
 
-    def _dispatch_fused(self, wave):
-        """Enqueue what :meth:`_encode_fused` prepared; returns its handle
-        with the uncollected device result in it."""
-        handle, g, qpack, scheds = wave
-        handle[3] = fdx.run_fused_wave(
+    def _dispatch_fused(self, wave, g, qpack, scheds) -> None:
+        """Enqueue what :meth:`_encode_fused` prepared; the uncollected
+        device result goes into ``wave``."""
+        wave.fused = fdx.run_fused_wave(
             g, qpack, **scheds,
             max_width=self.max_width, depth_slack=leo.DEPTH_SLACK,
             span=self._span,
         )
-        return handle
 
-    def _cache_consult(self, queries, rest_depth, err, general, leo_res,
-                       cursor):
+    def _cache_consult(self, queries, rest_depth, err, leo_res):
         """Probe the hot-spot shield for every query not already answered
         (encode errors fall to the oracle for their typed error; Leopard
         answers are cheaper than a probe would be).  Returns
@@ -1610,7 +1605,7 @@ class DeviceCheckEngine:
             return None
         return cached, vals
 
-    def _cache_fill(self, queries, handle, rest_depth, allowed,
+    def _cache_fill(self, queries, wave, rest_depth, allowed,
                     skip=None) -> None:
         """Insert this chunk's freshly computed verdicts, stamped with the
         drain cursor captured with the dispatch's sync view.  Oracle-
@@ -1624,14 +1619,11 @@ class DeviceCheckEngine:
         rc = self.result_cache
         if rc is None:
             return
-        err, leo_res, cache_res, cursor = (
-            handle[1], handle[8], handle[9], handle[10]
-        )
-        fresh = ~err
-        if leo_res is not None:
-            fresh &= ~leo_res[1]
-        if cache_res is not None:
-            fresh &= ~cache_res[0]
+        fresh = ~wave.err
+        if wave.leo_res is not None:
+            fresh &= ~wave.leo_res[1]
+        if wave.cache_res is not None:
+            fresh &= ~wave.cache_res[0]
         if skip is not None:
             fresh &= ~skip
         idx = np.flatnonzero(fresh)
@@ -1640,7 +1632,7 @@ class DeviceCheckEngine:
         with self._span("check_cache_fill", rows=len(idx)):
             keys = self._qkeys(queries, idx, rest_depth)
             for i, key in zip(idx, keys):
-                rc.insert(key, bool(allowed[i]), cursor)
+                rc.insert(key, bool(allowed[i]), wave.cursor)
 
     def _gen_schedule(self, q: int, boost: int):
         """Static shapes for one fused algebra dispatch (engine/algebra.py).
@@ -1756,275 +1748,195 @@ class DeviceCheckEngine:
                 else:
                     self._gen_fast_occ_ema = focc
 
-    def _run_general(self, dev_arrays, enc, gi, boost: int = 1):
+    def _run_general(self, arrays, enc, gi, boost: int = 1):
         """Enqueue ONE fused algebra dispatch for the general (AND/NOT)
         roots — whole-chunk batches, no host round-trips (the round-3
         host-stepped interpreter paid a flags sync per 6 levels and
         ~128-task-slots-per-root sub-batching; VERDICT r3 #1).  Returns an
-        uncollected (codes, occ, n) device handle; ``boost`` widens every
-        capacity for the retry tier."""
+        uncollected (codes, occ, n, fast_b); ``boost`` widens
+        every capacity for the retry tier."""
         n = len(gi)
-        # half-octave padding: every buffer in the fused program scales
-        # with qpad, so pow2 rounding (e.g. 3046 -> 4096) taxed the whole
-        # dispatch ~33%
-        qpad = min(_bucket15(n, 256), self.max_batch)
+        qpad = wv.general_lanes(n, self.max_batch)
         genc = self._pad(tuple(a[gi] for a in enc), n, qpad)
         active = np.arange(qpad) < n
         qpack = np.stack([*genc, active.astype(np.int32)]).astype(np.int32)
         sizes, fast_b, fast_sched, vcap = self._gen_schedule(qpad, boost)
-        codes, occ = alg.run_general_packed_timed(
-            dev_arrays,
-            qpack,
-            sizes=sizes,
-            fast_b=fast_b,
-            fast_sched=fast_sched,
-            max_width=self.max_width,
-            vcap=vcap,
-            span=self._span,
+        codes, occ = self._general_program(
+            arrays, qpack, n, boost, sizes=sizes, fast_b=fast_b,
+            fast_sched=fast_sched, max_width=self.max_width, vcap=vcap,
         )
         return codes, occ, n, fast_b
 
-    def _collect(self, handle, retry: bool = True):
-        """Sync one chunk's results; device-retry the fast-path overflow
-        tail at ``retry_scale``x caps.  Returns (allowed, fallback).
-        The retry runs against the handle's own device arrays — a write
-        landing between dispatch and retry must not pair these encodings
-        with a newer projection."""
-        if isinstance(handle, list):  # fused wave (mutable list handle)
-            return self._collect_fused(handle)
-        (enc, err, general, res, gi, gres, dev_arrays, occ, leo_res,
-         cache_res, _cursor) = handle
-        n = err.shape[0]
-        allowed = np.zeros(n, bool)
-        fallback = err.copy()
+    def _general_program(self, arrays, qpack, rows, boost, **shapes):
+        """Launch the algebra program at ``_run_general``'s shapes (the
+        mesh launches its sharded one)."""
+        return alg.run_general_packed_timed(
+            arrays, qpack, span=self._span, **shapes)
 
-        if gres is not None:
-            with self._span("check_collect_sync"):
-                packed = np.asarray(gres[0])[: gres[2]]  # one D2H fetch
-                self._update_gen_occ(np.asarray(gres[1]), gres[3])
-            codes = (packed & 3).astype(np.int8)
-            gover = ((packed >> 2) & 1).astype(bool)
-            # dirty: the skeleton touched overlay-stale state (a changed
-            # edge row) — under AND/NOT even an IS verdict can be wrong
-            # (a missed child IS inverts through NOT), so the oracle
-            # answers; a device retry would read the same stale base
-            gdirty = ((packed >> 3) & 1).astype(bool)
-            allowed[gi] = codes == R_IS
-            # overflow retry tier for the general path, mirroring the fast
-            # path: re-run just the overflowed roots at boosted caps (small
-            # batch => ample per-root slots) before any oracle fallback
-            gunres = gover & ~gdirty & (codes != R_ERR)
-            if retry and gunres.any() and self.retry_scale > 1:
-                ri = gi[np.flatnonzero(gunres)]
-                with self._span("check_retry", rows=len(ri)):
+    def _general_occ(self, occ) -> np.ndarray:
+        """The occupancy vector ``_update_gen_occ`` is fed, from what the
+        general launch returned beside its codes."""
+        return np.asarray(occ)
+
+    def _fast_bits(self, res, k: int) -> wv.FastBits:
+        """(found, over, dirty) of the first ``k`` rows of a fast launch:
+        one D2H fetch, all three masks.  Nothing dispatched (the closure
+        index answered everything eligible): all-zero bits."""
+        if res is None:
+            return wv.decode_fast(np.zeros(k, np.uint8))
+        return wv.decode_fast(np.asarray(res)[:k])
+
+    def _fetch_span(self, phase: str, **fields):
+        """``check_collect_sync`` around the cascade's fetches and
+        ``check_retry`` around its retries.  (The mesh's launches are
+        finished and timed where they are made: it opens neither.)"""
+        return self._span(phase, **fields)
+
+    def _fast_retry_cap(self) -> int:
+        """Most rows a fast retry is padded to: the boosted program's own
+        frontier."""
+        return self.retry_scale * self.frontier
+
+    def _after_collect(self, wave, allowed, fallback) -> None:
+        """Hook on the cascade's merged verdicts (in place); one chip has
+        nothing to add."""
+
+    def _collect(self, wave, retry: bool = True):
+        """Sync one chunk's results; device-retry the overflow tail of
+        either tier at ``retry_scale``x caps (small batch => ample
+        per-row slots) before any oracle fallback.  Returns (allowed,
+        fallback).  The retry runs against the wave's own device arrays —
+        a write landing between dispatch and retry must not pair these
+        encodings with a newer projection."""
+        if wave.meta is not None:
+            return self._collect_fused(wave)
+        n = wave.n
+        boosted = retry and self.retry_scale > 1
+        g_is = np.zeros(n, bool)
+        g_fb = np.zeros(n, bool)
+        if wave.gen is not None:
+            codes, occ, rows, fast_b = wave.gen
+            with self._fetch_span("check_collect_sync"):
+                g = wv.decode_general(np.asarray(codes)[:rows])  # one fetch
+                self._update_gen_occ(self._general_occ(occ), fast_b)
+            again = wv.general_retry_rows(g)
+            if boosted and again.any():
+                ri = wave.gi[again]
+                with self._fetch_span("check_retry", rows=len(ri)):
                     self.retries += len(ri)
-                    rh = self._run_general(
-                        dev_arrays, enc, ri, boost=self.retry_scale
-                    )
-                    rpacked = np.asarray(rh[0])[: rh[2]]
-                    rcodes = (rpacked & 3).astype(np.int8)
-                    rover = ((rpacked >> 2) & 1).astype(bool)
-                    rdirty = ((rpacked >> 3) & 1).astype(bool)
-                    allowed[ri] = rcodes == R_IS
-                    gover[gunres] = rover | rdirty | (rcodes == R_ERR)
-                    codes = codes.copy()
-                    codes[np.flatnonzero(gunres)] = rcodes
-            fallback[gi] |= gover | gdirty | (codes == R_ERR)
+                    rcodes, _, k, _ = self._run_general(
+                        wave.arrays, wave.enc, ri, boost=self.retry_scale)
+                    wv.take_retry(
+                        g, again, wv.decode_general(np.asarray(rcodes)[:k]))
+            g_is[wave.gi] = wv.general_allowed(g)
+            g_fb[wave.gi] = wv.general_fallback(g)
 
-        with self._span("check_collect_sync"):
-            if res is None:
-                # nothing was dispatched on the fast path (closure index
-                # answered everything eligible): all-zero codes, no
-                # occupancy
-                codes = np.zeros(n, np.uint8)
-            else:
-                codes = np.asarray(res)[:n]  # one D2H fetch, all 3 masks
-                self._update_occ(np.asarray(occ))
-        found = (codes & 1).astype(bool)
-        over = ((codes >> 1) & 1).astype(bool)
-        dirty = ((codes >> 2) & 1).astype(bool)
-        fmask = ~(err | general)
-        allowed[fmask] = found[fmask]
-        if leo_res is not None:
-            # closure verdicts override the (inactive, all-zero) device
-            # slots for the answered queries; their over/dirty bits are
-            # zero by construction, so no fallback/retry can claim them
-            allowed[leo_res[1]] = leo_res[0][leo_res[1]]
-        if cache_res is not None:
-            # cached verdicts likewise ride inactive all-zero slots
-            allowed[cache_res[0]] = cache_res[1][cache_res[0]]
-            fallback &= ~cache_res[0]
-        # dirty queries touched a CSR row with pending writes: the oracle
-        # (live store) must answer *unless* membership was already
-        # established — found-bits are overlay-exact and monotone, so a
-        # found verdict stands even when the exploration brushed a dirty
-        # row.  A device retry would see the same stale base, so dirty
-        # queries are excluded from the retry tier.
-        fallback |= fmask & dirty & ~found
-        # found is monotone: an overflow only voids not-yet-found queries
-        unres = fmask & over & ~found & ~dirty
-        if retry and unres.any() and self.retry_scale > 1:
-            ri = np.flatnonzero(unres)
-            with self._span("check_retry", rows=len(ri)):
-                rpad = min(
-                    _bucket(len(ri), 256), self.retry_scale * self.frontier
+        with self._fetch_span("check_collect_sync"):
+            f = self._fast_bits(wave.fast, n)
+            if wave.occ is not None:
+                self._update_occ(np.asarray(wave.occ))
+        again = ~(wave.err | wave.general) & wv.fast_retry_rows(f)
+        if boosted and again.any():
+            ri = np.flatnonzero(again)
+            k = len(ri)
+            with self._fetch_span("check_retry", rows=k):
+                rpad = wv.retry_rows(k, self._fast_retry_cap())
+                renc = self._pad(tuple(a[ri] for a in wave.enc), k, rpad)
+                self.retries += k
+                rres, _ = self._run_fast(
+                    wave, renc, np.arange(rpad) < k,
+                    boost=self.retry_scale, rows=ri,
                 )
-                renc = self._pad(tuple(a[ri] for a in enc), len(ri), rpad)
-                self.retries += len(ri)
-                rpack = np.stack(
-                    [*renc, (np.arange(rpad) < len(ri)).astype(np.int32)]
-                ).astype(np.int32)
-                rres, _roc = fp.run_fast_packed(
-                    dev_arrays,
-                    rpack,
-                    frontier=self.retry_scale * self.frontier,
-                    arena=self.retry_scale * self.arena,
-                    max_depth=self.max_depth,
-                    max_width=self.max_width,
-                    # scale the per-query schedule too: the tail queries
-                    # need retry_scale x the capacity their tier-1 share
-                    # gave them, and with a small retry batch the caps
-                    # alone don't bind.  No adaptive mults here: the retry
-                    # exists precisely because the demand-sized tier missed.
-                    boost=self.retry_scale,
-                )
-                rcodes = np.asarray(rres)[: len(ri)]
-                rfound = (rcodes & 1).astype(bool)
-                rover = ((rcodes >> 1) & 1).astype(bool)
-                rdirty = ((rcodes >> 2) & 1).astype(bool)
-                allowed[ri] = rfound
-                unres[ri] = (rover | rdirty) & ~rfound
-        fallback |= unres
+                wv.take_retry(f, ri, self._fast_bits(rres, k))
+        allowed, fallback = wv.merge(
+            wave.err, wave.general, g_is, g_fb, f.found,
+            wv.fast_fallback(f), wave.leo_res, wave.cache_res,
+        )
+        self._after_collect(wave, allowed, fallback)
         return allowed, fallback
 
-    def _collect_fused(self, handle):
+    def _collect_fused(self, wave):
         """Sync one fused wave: ONE D2H fetch returns the verdict codes
         AND the per-tier attribution masks (engine/fused.py bit layout).
         Decode, feed the occupancy EMAs, update the leopard/retry
         counters from the returned masks (totals match the unfused
         dispatch-time increments exactly), and write the decoded
-        leopard/cache slots back into the mutable handle so
-        ``_note_tiers`` and ``_cache_fill`` work unchanged."""
-        (enc, err, general, fres, _gi, meta, _dev, _occ, _leo,
-         cache_res, _cursor) = handle
-        n = meta["n"]
-        qpad = meta["qpad"]
+        leopard answers into the wave so ``_note_tiers`` and
+        ``_cache_fill`` read them like a cascade's."""
+        meta, n = wave.meta, wave.n
         with self._span("check_collect_sync", rows=n):
-            packed = np.asarray(fres)  # the wave's single D2H fetch
+            packed = np.asarray(wave.fused)  # the wave's single D2H fetch
         self.fused_waves += 1
         self.fused_d2h_fetches += 1
         self.fused_general_rows += meta["gen_rows"]
         self.fused_general_lanes += meta["gen_lanes"]
-        rows = packed[:n]
-        focc = packed[qpad:qpad + meta["flen"]]
-        gocc = packed[qpad + meta["flen"]:
-                      qpad + meta["flen"] + meta["glen"]]
-        gcode = (rows & 3).astype(np.int8)
-        gover = ((rows >> 2) & 1).astype(bool)
-        gdirty = ((rows >> 3) & 1).astype(bool)
-        found = ((rows >> 4) & 1).astype(bool)
-        fast_fb = ((rows >> 5) & 1).astype(bool)
-        leo_ans = ((rows >> 6) & 1).astype(bool)
-        leo_allow = ((rows >> 7) & 1).astype(bool)
-        retried = ((rows >> 8) & 1).astype(bool)
-        gen_retried = ((rows >> 9) & 1).astype(bool)
+        bits = wv.decode_fused(packed[:n])
         # occupancy EMA feeds (absent tiers ship no occupancy at all)
+        f_end = wave.qpad + meta["flen"]
         if meta["flen"]:
-            self._update_occ(focc)
+            self._update_occ(packed[wave.qpad:f_end])
         if meta["glen"]:
-            self._update_gen_occ(gocc, meta["gen_fast_b"])
-        self.retries += int(retried.sum()) + int(gen_retried.sum())
-        leo_res = None
+            self._update_gen_occ(
+                packed[f_end:f_end + meta["glen"]], meta["gen_fast_b"])
+        self.retries += int(bits.retried.sum()) + int(bits.gen_retried.sum())
         if meta["has_leo"]:
-            leo_res = (leo_allow, leo_ans)
-            self.leopard_answered += int(leo_ans.sum())
-            self.leopard_hits += int(leo_allow.sum())
-            handle[8] = leo_res
-            if cache_res is not None:
-                # leopard precedence: the unfused cascade never consults
-                # the cache for closure-answered rows, so a fused cache
-                # hit on one must not claim its verdict or attribution
-                cache_res = (cache_res[0] & ~leo_ans, cache_res[1])
-                handle[9] = cache_res
-        allowed = np.zeros(n, bool)
-        fallback = err.copy()
-        allowed[general] = (gcode == R_IS)[general]
-        fallback[general] |= (gover | gdirty | (gcode == R_ERR))[general]
-        fmask = ~(err | general)
-        allowed[fmask] = found[fmask]
-        if leo_res is not None:
-            allowed[leo_ans] = leo_allow[leo_ans]
-        if cache_res is not None:
-            allowed[cache_res[0]] = cache_res[1][cache_res[0]]
-            fallback &= ~cache_res[0]
+            wave.leo_res = (bits.leo_allow, bits.leo_ans)
+            self.leopard_answered += int(bits.leo_ans.sum())
+            self.leopard_hits += int(bits.leo_allow.sum())
         # fast_fb is masked to the fast-active rows in-program, which
         # already exclude leopard/cache-answered rows
-        fallback |= fast_fb
-        # per-tier row attribution from the returned masks — same
-        # precedence as _note_tiers (cache -> leopard -> oracle -> device)
+        allowed, fallback = wv.merge(
+            wave.err, wave.general, wv.general_allowed(bits.general),
+            wv.general_fallback(bits.general), bits.found, bits.fast_fb,
+            wave.leo_res, wave.cache_res,
+        )
+        tiers = wv.attribute(wave.err, fallback, wave.leo_res, wave.cache_res)
         tr = self.fused_tier_rows
-        seen = np.zeros(n, bool)
-        if cache_res is not None:
-            tr["cache"] += int(cache_res[0].sum())
-            seen |= cache_res[0]
-        if leo_res is not None:
-            tr["leopard"] += int(leo_ans.sum())
-            seen |= leo_ans
-        orc = (fallback | err) & ~seen
-        tr["oracle"] += int(orc.sum())
-        seen |= orc
-        rest = ~seen
-        tr["general"] += int((rest & general).sum())
-        tr["fastpath"] += int((rest & ~general).sum())
+        tr["cache"] += int(tiers.cache.sum())
+        tr["leopard"] += int(tiers.leopard.sum())
+        tr["oracle"] += int(tiers.oracle.sum())
+        tr["general"] += int((tiers.device & wave.general).sum())
+        tr["fastpath"] += int((tiers.device & ~wave.general).sum())
         return allowed, fallback
 
-    def _note_tiers(self, handle, fallback) -> np.ndarray:
+    def _note_tiers(self, wave, fallback) -> None:
         """Attribute this chunk's verdicts to the tier that answered them
         (request-anatomy tracing + shadow-plane provenance): cache hits,
         Leopard closure answers, oracle fallbacks, and whatever remains on
-        the device fast path.  Best-effort — only a request context open
-        on the collecting thread receives the notes (the coalescer's
-        dispatch thread has none and skips the work entirely)."""
-        err, leo_res, cache_res = handle[1], handle[8], handle[9]
-        seen = np.zeros(err.shape[0], bool)
+        the device.  Best-effort — only a request context open on the
+        collecting thread receives the notes (the coalescer's dispatch
+        thread has none and skips the work entirely)."""
         if flightrec.current() is None:
-            return seen
-        if isinstance(handle, list):
-            # fused-wave handle: stamp the request's shadow provenance so
-            # a divergence localizes to the fused program vs the cascade
+            return
+        if wave.meta is not None:
+            # stamp the request's shadow provenance so a divergence
+            # localizes to the fused program vs the cascade
             flightrec.note_fused()
-        if cache_res is not None and cache_res[0].any():
-            flightrec.note_tier("cache", int(cache_res[0].sum()))
-            seen |= cache_res[0]
-        if leo_res is not None and leo_res[1].any():
-            flightrec.note_tier("leopard", int(leo_res[1].sum()))
-            seen |= leo_res[1]
-        orc = (fallback | err) & ~seen
-        if orc.any():
-            flightrec.note_tier("oracle", int(orc.sum()))
-            seen |= orc
-        rest = ~seen
-        if rest.any():
-            self._note_fast_tiers(rest, handle)
-        return seen
+        tiers = wv.attribute(wave.err, fallback, wave.leo_res, wave.cache_res)
+        for tier in ("cache", "leopard", "oracle"):
+            rows = int(getattr(tiers, tier).sum())
+            if rows:
+                flightrec.note_tier(tier, rows)
+        if tiers.device.any():
+            self._note_fast_tiers(tiers.device, wave)
 
-    def _note_fast_tiers(self, mask, handle) -> None:
+    def _note_fast_tiers(self, mask, wave) -> None:
         """Fast-path attribution hook; the mesh engine overrides this to
         split the count by serving shard."""
         flightrec.note_tier("fastpath", int(mask.sum()))
 
     def _finish_chunk(
-        self, queries, handle, rest_depth: int, errs=None, base: int = 0
+        self, queries, wave, rest_depth: int, errs=None, base: int = 0
     ) -> np.ndarray:
         """Collect one chunk's verdicts as a bool array.  With ``errs``
         (the columnar path's per-item contract) a typed oracle error is
         captured into ``errs[base + i]`` instead of aborting the chunk;
         deadline expiry still propagates — it is batch-wide by design and
         the handler fans it out as per-item 504s."""
-        if handle is None:
+        if wave is None:
             return np.zeros(0, bool)
-        allowed, fallback = self._collect(handle)
-        self._note_tiers(handle, fallback)
+        allowed, fallback = self._collect(wave)
+        self._note_tiers(wave, fallback)
         skip = None
         if fallback.any():
             t_fb = time.perf_counter()
@@ -2036,24 +1948,19 @@ class DeviceCheckEngine:
                     # budget
                     deadline.check("oracle fallback")
                     self.fallbacks += 1
-                    if errs is None:
-                        allowed[i] = self.oracle.check_is_member(
-                            queries[i], rest_depth
-                        )
-                        continue
                     try:
                         allowed[i] = self.oracle.check_is_member(
                             queries[i], rest_depth
                         )
-                    except DeadlineExceededError:
-                        raise
                     except KetoAPIError as e:
+                        if errs is None or isinstance(e, DeadlineExceededError):
+                            raise
                         errs[base + int(i)] = e
                         if skip is None:
                             skip = np.zeros(allowed.shape[0], bool)
                         skip[i] = True
             self._rpc_fallback_stage("check", time.perf_counter() - t_fb)
-        self._cache_fill(queries, handle, rest_depth, allowed, skip=skip)
+        self._cache_fill(queries, wave, rest_depth, allowed, skip=skip)
         return allowed
 
     def batch_expand(
@@ -2152,10 +2059,10 @@ class DeviceCheckEngine:
         Test/diagnostic surface — pinned to the unfused cascade, whose
         host-side tiers honor ``retry=False`` individually (the fused
         program's retry lanes are compiled in)."""
-        handle = self._dispatch(list(queries), rest_depth, fused=False)
-        if handle is None:
+        wave = self._dispatch(list(queries), rest_depth, fused=False)
+        if wave is None:
             return [], []
-        allowed, fallback = self._collect(handle, retry=retry)
+        allowed, fallback = self._collect(wave, retry=retry)
         return allowed.tolist(), fallback.tolist()
 
     def batch_check_block(self, block, rest_depth: int = 0):
@@ -2179,10 +2086,10 @@ class DeviceCheckEngine:
         allowed = np.zeros(n, bool)
         try:
             # same dispatch-all-then-sync pipelining as batch_check
-            handles = [self._dispatch(c, rest_depth) for _, c in chunks]
-            for (lo, c), h in zip(chunks, handles):
+            waves = [self._dispatch(c, rest_depth) for _, c in chunks]
+            for (lo, c), w in zip(chunks, waves):
                 allowed[lo:lo + len(c)] = self._finish_chunk(
-                    c, h, rest_depth, errs=errs, base=lo
+                    c, w, rest_depth, errs=errs, base=lo
                 )
         except KetoAPIError:
             raise  # typed client errors (and deadline/shed) pass through

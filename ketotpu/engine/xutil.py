@@ -1,87 +1,19 @@
 """Array utilities for the device check engine.
 
-Small, jittable building blocks: vectorized lexicographic binary search over
-multi-key sorted arrays (the device-side replacement for the reference's SQL
-index probes, `internal/persistence/sql/traverser.go:53-191`), and the
-prefix-sum "arena" expansion that turns per-task child counts into flat child
-slots (the batched replacement for goroutine fan-out in
+A small, jittable building block: the prefix-sum "arena" expansion that
+turns per-task child counts into flat child slots (the batched replacement
+for goroutine fan-out in
 `internal/check/checkgroup/concurrent_checkgroup.go:66-138`).
 
-Everything works on int32 arrays and static shapes so XLA can tile it; no
-int64 needed (keys stay as tuples of int32 columns compared lexicographically).
+Everything works on int32 arrays and static shapes so XLA can tile it.
 """
 
 from __future__ import annotations
 
-from typing import Sequence, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-
-
-def _lex_less(a: Sequence[jax.Array], b: Sequence[jax.Array]) -> jax.Array:
-    """Elementwise a < b under lexicographic order over key columns."""
-    lt = jnp.zeros(jnp.broadcast_shapes(a[0].shape, b[0].shape), dtype=bool)
-    eq = jnp.ones_like(lt)
-    for ka, kb in zip(a, b):
-        lt = lt | (eq & (ka < kb))
-        eq = eq & (ka == kb)
-    return lt
-
-
-def _lex_eq(a: Sequence[jax.Array], b: Sequence[jax.Array]) -> jax.Array:
-    eq = jnp.ones(jnp.broadcast_shapes(a[0].shape, b[0].shape), dtype=bool)
-    for ka, kb in zip(a, b):
-        eq = eq & (ka == kb)
-    return eq
-
-
-# NOTE: lex_searchsorted / lex_sort have no production callers since the
-# general-path visited log moved to a hash set (device.py phase F); they
-# remain as tested utilities for host-side tooling and as the documented
-# alternative where sorted semantics (ordered output) are required.
-def lex_searchsorted(
-    keys: Sequence[jax.Array], queries: Sequence[jax.Array]
-) -> Tuple[jax.Array, jax.Array]:
-    """Vectorized lexicographic binary search.
-
-    ``keys``: tuple of K sorted-together int32 columns, each of length N
-    (sorted by ``jax.lax.sort(..., num_keys=K)`` order).
-    ``queries``: tuple of K columns of query keys, each of length Q.
-
-    Returns ``(idx, found)``: the insertion point (first index with
-    key >= query) and whether the key at that index equals the query.
-    Works for N == 0 (idx = 0, found = False).
-    """
-    n = keys[0].shape[0]
-    q = queries[0].shape[0]
-    if n == 0:
-        return jnp.zeros((q,), jnp.int32), jnp.zeros((q,), bool)
-    lo = jnp.zeros((q,), jnp.int32)
-    hi = jnp.full((q,), n, jnp.int32)
-    # Unrolled binary search (static log2(n)+1 steps).  Deliberately NOT a
-    # fori_loop: when this search sits inside an outer lax.while_loop (the
-    # check interpreter), XLA:TPU demotes the nested loop's gathers to the
-    # scalar core (~500x slower); straight-line gathers stay vectorized.
-    for _ in range(max(1, int(n).bit_length() + 1)):
-        mid = (lo + hi) // 2
-        mid_keys = [k[jnp.clip(mid, 0, max(n - 1, 0))] for k in keys]
-        live = lo < hi
-        go_right = live & _lex_less(mid_keys, queries)  # key[mid] < query
-        lo = jnp.where(go_right, mid + 1, lo)
-        hi = jnp.where(go_right | ~live, hi, mid)
-    idx = lo
-    if n == 0:
-        return idx, jnp.zeros((q,), bool)
-    at = jnp.clip(idx, 0, n - 1)
-    found = (idx < n) & _lex_eq([k[at] for k in keys], queries)
-    return idx, found
-
-
-def lex_sort(keys: Sequence[jax.Array], *payload: jax.Array):
-    """Sort key columns lexicographically, carrying payload columns along."""
-    out = jax.lax.sort(tuple(keys) + tuple(payload), num_keys=len(keys))
-    return out[: len(keys)], out[len(keys):]
 
 
 def arena_assign(counts: jax.Array, arena_size: int) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:

@@ -43,11 +43,12 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ketotpu import compilewatch, deadline, faults, flightrec
+from ketotpu import compilewatch, deadline, faults, flightrec, profiler
 from ketotpu.cache.hotspot import HotSpotSketch
 from ketotpu.engine import delta as dl
-from ketotpu.engine.optable import R_ERR, R_IS
-from ketotpu.engine.tpu import DeviceCheckEngine, _bucket, _bucket15
+from ketotpu.engine import wave as wv
+from ketotpu.engine.tpu import DeviceCheckEngine
+from ketotpu.engine.wave import _bucket
 from ketotpu.parallel import graphshard, peerlink
 from ketotpu.parallel.mesh import make_mesh
 
@@ -83,6 +84,9 @@ class MeshCheckEngine(DeviceCheckEngine):
     # no base-engine fold or background generation swap
     supports_fold = False
     supports_background_compaction = False
+    # the mesh runs the base's cascade with its own launchers; the fused
+    # wave has never run under shard_map, whatever the shared config says
+    supports_fused = False
 
     def __init__(
         self,
@@ -102,10 +106,6 @@ class MeshCheckEngine(DeviceCheckEngine):
         **kwargs,
     ):
         super().__init__(store, namespace_manager, **kwargs)
-        # the mesh overrides _dispatch/_collect wholesale (per-shard
-        # routing, all_to_all collectives); the single-program fused wave
-        # does not apply here, whatever the shared config says
-        self.fused_dispatch = False
         self.mesh = make_mesh(mesh_devices, axis=mesh_axis)
         if self.mesh.devices.size != mesh_devices:
             # make_mesh silently truncates to what exists; serving with
@@ -413,12 +413,26 @@ class MeshCheckEngine(DeviceCheckEngine):
             self._mesh_run_lock.release()
         return out
 
-    def _sharded_run(self, stacked, padded, active, boost: int = 1,
-                     assign=None):
-        return self._run_locked(
+    def _sync_view(self):
+        """The base's atomic view, with the sharded stacks for device
+        arrays.  The stamp is the DRAIN cursor where the base takes the
+        served one: the mesh has no background compaction
+        (``supports_background_compaction``), so every drain is served
+        before the lock is released and the two are equal."""
+        with self._sync_lock:
+            snap = self._snapshot_locked()
+            return snap, self._stacked, self._log_cursor
+
+    def _run_fast(self, wave, padded, active, boost: int = 1, rows=None):
+        """The sharded BFS for padded rows, finished under the run lock;
+        returns (FastResult, no occupancy).  Launched whether or not a
+        row is active."""
+        assign = wave.assign if rows is None else wave.assign[rows]
+        assign = np.pad(assign, (0, len(active) - len(assign)))
+        res = self._run_locked(
             "check_mesh_fast" if boost == 1 else "check_mesh_retry",
             lambda: graphshard.sharded_check(
-                stacked,
+                wave.arrays,
                 padded,
                 self.mesh,
                 axis=self.mesh_axis,
@@ -431,33 +445,56 @@ class MeshCheckEngine(DeviceCheckEngine):
             ),
             rows=int(np.count_nonzero(active)), boost=boost,
         )
+        return res, None
 
-    def _run_general_mesh(self, stacked, enc, gi, boost: int = 1):
+    def _general_program(self, stacked, qpack, rows, boost, **shapes):
         """One fused algebra dispatch over the SHARDED graph stacks for
         the general (AND/NOT) roots (graphshard.sharded_general_check,
         VERDICT r4 #5): no replicated graph copy — per-device graph
         memory keeps scaling down with mesh size; only the per-batch
-        skeleton working set is replicated.  Overlay-aware like the
+        skeleton working set is replicated (GLOBAL shapes: the whole
+        batch's skeleton lives on every shard).  Overlay-aware like the
         single-chip program: each shard's slice carries its own overlay
-        tables, probes run owner-side, and dirty bits psum-merge.
-        Returns (codes, occ_rows, n, fast_b)."""
-        n = len(gi)
-        qpad = min(_bucket15(max(n, 256), 256), self.max_batch)
-        genc = self._pad(tuple(a[gi] for a in enc), n, qpad)
-        active = np.arange(qpad) < n
-        qpack = np.stack([*genc, active.astype(np.int32)]).astype(np.int32)
-        # GLOBAL shapes: the whole batch's skeleton lives on every shard
-        sizes, fast_b, fast_sched, vcap = self._gen_schedule(qpad, boost)
-        codes, occ = self._run_locked(
+        tables, probes run owner-side, and dirty bits psum-merge."""
+        return self._run_locked(
             "check_mesh_general" if boost == 1 else "check_mesh_retry",
             lambda: graphshard.sharded_general_check(
-                stacked, qpack, self.mesh, axis=self.mesh_axis,
-                sizes=sizes, fast_b=fast_b, fast_sched=fast_sched,
-                max_width=self.max_width, vcap=vcap,
+                stacked, qpack, self.mesh, axis=self.mesh_axis, **shapes,
             ),
-            rows=n, boost=boost,
+            rows=rows, boost=boost,
         )
-        return codes, occ, n, fast_b
+
+    def _general_occ(self, occ) -> np.ndarray:
+        # occ rows: the skeleton level counts and fast_n ([0..D+1]) come
+        # from the psum-merged levels — replicated GLOBAL values on every
+        # shard (take one row, not the n-fold sum) — while the BFS
+        # sub-run counts ([D+2:]) are owner-masked per-shard partials
+        # whose sum is the true global
+        rows = np.asarray(occ)
+        split = self.gen_levels + 2
+        self._shard_gen_occ = rows[:, split:].sum(axis=1).astype(float)
+        return np.concatenate([rows[0, :split], rows[:, split:].sum(axis=0)])
+
+    def _fast_bits(self, res, k: int) -> wv.FastBits:
+        # copies: a retry's bits are written over the first pass's
+        found, over = np.array(res.found)[:k], np.array(res.over)[:k]
+        dirty = (
+            np.array(res.dirty)[:k] if res.dirty is not None
+            else np.zeros(k, bool)
+        )
+        return wv.FastBits(found, over, dirty)
+
+    def _fetch_span(self, phase: str, **fields):
+        # every sharded program is finished where it is launched, under
+        # the run lock, and timed there (check_mesh_fast / _general /
+        # _retry, check_mesh_lock_wait): collect waits for nothing and
+        # opens no span of its own
+        return profiler.null_span(phase, **fields)
+
+    def _fast_retry_cap(self) -> int:
+        # the wave's own cap, not the boosted frontier: a retry never
+        # holds more rows than the wave that overflowed
+        return self.frontier
 
     # -- routing / failover -------------------------------------------------
 
@@ -547,7 +584,7 @@ class MeshCheckEngine(DeviceCheckEngine):
         owner host plus any heartbeat-published replica hosts; the
         least-loaded LIVE member serves it.  Rows landing on a peer batch
         into one framed round trip per peer (fired here, joined in
-        _collect); rows with every copy down — and every cross-host row
+        _after_collect); rows with every copy down — and every cross-host row
         of a wave whose deadline budget is already spent — degrade to the
         oracle instead of blocking the wave."""
         cand = np.flatnonzero(cand_mask)
@@ -733,194 +770,75 @@ class MeshCheckEngine(DeviceCheckEngine):
         snap, cursor = self.hostlink.bootstrap_from(int(hid))
         self.adopt_snapshot(snap, cursor=cursor)
 
-    def _dispatch(self, queries, rest_depth: int, fused=None):
-        # ``fused`` accepted for base-class call compatibility and
-        # ignored: the sharded cascade has no fused-wave variant
-        n = len(queries)
-        if n == 0:
-            return None
-        faults.inject("device_dispatch")
-        self.dispatches += 1
-        with self._span("check_encode", rows=n):
-            with self._sync_lock:
-                snap = self._snapshot_locked()
-                stacked = self._stacked
-                # cache-entry freshness stamp: captured under the same lock as
-                # the snapshot the verdicts will be computed against
-                cursor = self._log_cursor
-            enc = self._encode(snap, queries, rest_depth)
-            err, general = self._classify(snap, enc[0], enc[2])
-            # Leopard first: checks the closure index answers drop out of the
-            # sharded BFS entirely (same interception as the single-chip path)
-            leo_res = self._leopard_answers(enc, err, general)
-            act = ~(err | general)
-            if leo_res is not None:
-                act &= ~leo_res[1]
-            # hot-spot shield after Leopard (shared _cache_consult): cached
-            # queries leave both the sharded BFS and the algebra dispatch
-            cache_res = self._cache_consult(queries, rest_depth, err, general,
-                                            leo_res, cursor)
-            if cache_res is not None:
-                act &= ~cache_res[0]
-                general = general & ~cache_res[0]
-            # cross-host routing BEFORE the shard-level machinery: rows whose
-            # serving host is a peer leave the local wave entirely (one framed
-            # round trip per peer, launched now so the DCN exchange overlaps
-            # the local device run; joined last in _collect).  Rows with no
-            # live serving host degrade to the oracle via the err-mask.
-            peerh = None
-            if (self.hostlink is not None and self.n_hosts > 1
-                    and not getattr(_LOCAL_SERVE, "serving", False)):
-                peerh = self._route_hosts(queries, act | general, rest_depth)
-                if peerh is not None:
-                    gone = peerh["sent"] | peerh["lost"]
-                    act = act & ~gone
-                    general = general & ~gone
-                    err = err | gone
-            self._poll_shard_faults()
-            assign, owner = self._route_assign(enc[0], enc[1])
-            if self._shard_down.any():
-                # roots whose serving shard is down and that no live replica
-                # can absorb degrade to the host oracle; the wave itself keeps
-                # serving (general roots activate by hash owner on-device, so
-                # a down owner sends them to the oracle too)
-                down_fast = act & self._shard_down[assign]
-                down_gen = general & self._shard_down[owner]
-                act = act & ~down_fast
-                general = general & ~down_gen
-                err = err | down_fast | down_gen
-            if self.replicate_hot and act.any():
-                live = np.flatnonzero(act)
-                self._hot.observe_many(list(zip(
-                    np.clip(np.asarray(enc[0])[live], 0, None).tolist(),
-                    np.clip(np.asarray(enc[1])[live], 0, None).tolist(),
-                )))
-            # per-shard routed-root accounting: the skew/rebalance signal and
-            # the wave ledger's per-shard deltas
-            with self._mesh_run_lock:
-                np.add.at(self._shard_batches, assign[act], 1)
-                if general.any():
-                    np.add.at(self._shard_batches, owner[general], 1)
-            qpad = min(_bucket(n), self.frontier)
-            padded = self._pad(enc, n, qpad)
-            active = np.pad(act, (0, qpad - n))
-            passign = np.pad(assign, (0, qpad - n))
-        with self._span("check_mesh_dispatch", rows=n):
-            res = self._sharded_run(stacked, padded, active, assign=passign)
-            gres = gi = None
-            if general.any():
-                gi = np.flatnonzero(general)
-                gres = self._run_general_mesh(stacked, enc, gi)
-        return (enc, err, general, res, gi, gres, stacked, assign, leo_res,
-                cache_res, cursor, peerh)
+    def _route(self, queries, rest_depth, wave, active):
+        """What a mesh adds between the cache consult and the launch:
+        rows a peer host serves leave the wave, rows on a down shard go
+        to the oracle, and every remaining row gets its serving shard."""
+        enc = wave.enc
+        # cross-host routing BEFORE the shard-level machinery: rows whose
+        # serving host is a peer leave the local wave entirely (one framed
+        # round trip per peer, launched now so the DCN exchange overlaps
+        # the local device run; joined last in _after_collect).  Rows with
+        # no live serving host degrade to the oracle via the err-mask.
+        if (self.hostlink is not None and self.n_hosts > 1
+                and not getattr(_LOCAL_SERVE, "serving", False)):
+            wave.peers = self._route_hosts(
+                queries, active | wave.general, rest_depth)
+            if wave.peers is not None:
+                gone = wave.peers["sent"] | wave.peers["lost"]
+                active = active & ~gone
+                wave.general = wave.general & ~gone
+                wave.err = wave.err | gone
+        self._poll_shard_faults()
+        wave.assign, owner = self._route_assign(enc[0], enc[1])
+        if self._shard_down.any():
+            # roots whose serving shard is down and that no live replica
+            # can absorb degrade to the host oracle; the wave itself keeps
+            # serving (general roots activate by hash owner on-device, so
+            # a down owner sends them to the oracle too)
+            down_fast = active & self._shard_down[wave.assign]
+            down_gen = wave.general & self._shard_down[owner]
+            active = active & ~down_fast
+            wave.general = wave.general & ~down_gen
+            wave.err = wave.err | down_fast | down_gen
+        if self.replicate_hot and active.any():
+            live = np.flatnonzero(active)
+            self._hot.observe_many(list(zip(
+                np.clip(np.asarray(enc[0])[live], 0, None).tolist(),
+                np.clip(np.asarray(enc[1])[live], 0, None).tolist(),
+            )))
+        # per-shard routed-root accounting: the skew/rebalance signal and
+        # the wave ledger's per-shard deltas
+        with self._mesh_run_lock:
+            np.add.at(self._shard_batches, wave.assign[active], 1)
+            if wave.general.any():
+                np.add.at(self._shard_batches, owner[wave.general], 1)
+        return active
 
-    def _note_fast_tiers(self, mask, handle) -> None:
+    def _launch(self, wave, padded, active) -> None:
+        # both synchronous launches of a wave, and the waits for the run
+        # lock between them, as one phase
+        with self._span("check_mesh_dispatch", rows=wave.n):
+            super()._launch(wave, padded, active)
+
+    def _note_fast_tiers(self, mask, wave) -> None:
         # split the fast-path attribution by serving shard so a divergence
         # record names the exact replica that answered
-        assign = handle[7]
+        assign = wave.assign
         for s in np.unique(assign[mask]):
             flightrec.note_tier(
                 f"mesh-shard-{int(s)}", int((assign[mask] == s).sum())
             )
 
-    def _collect(self, handle, retry: bool = True):
-        (enc, fallback_mask, general, res, gi, gres, stacked, assign,
-         leo_res, cache_res, _cursor, peerh) = handle
-        n = fallback_mask.shape[0]
-        allowed = np.zeros(n, bool)
-        fallback = fallback_mask.copy()
-
-        if gres is not None:
-            packed = np.asarray(gres[0])[: gres[2]]
-            # occ rows: the skeleton level counts and fast_n ([0..D+1])
-            # come from the psum-merged levels — replicated GLOBAL values
-            # on every shard (take one row, not the n-fold sum) — while
-            # the BFS sub-run counts ([D+2:]) are owner-masked per-shard
-            # partials whose sum is the true global
-            rows = np.asarray(gres[1])
-            split = self.gen_levels + 2
-            self._shard_gen_occ = rows[:, split:].sum(axis=1).astype(float)
-            self._update_gen_occ(
-                np.concatenate(
-                    [rows[0, :split], rows[:, split:].sum(axis=0)]
-                ),
-                gres[3],
-            )
-            codes = (packed & 3).astype(np.int8)
-            gover = ((packed >> 2) & 1).astype(bool)
-            # dirty: some shard's overlay marked a row the skeleton or a
-            # fast leaf touched — oracle answers, no device retry (the
-            # retry would read the same stale base)
-            gdirty = ((packed >> 3) & 1).astype(bool)
-            allowed[gi] = codes == R_IS
-            gunres = gover & ~gdirty & (codes != R_ERR)
-            if retry and gunres.any() and self.retry_scale > 1:
-                ri = gi[np.flatnonzero(gunres)]
-                self.retries += len(ri)
-                rh = self._run_general_mesh(
-                    stacked, enc, ri, boost=self.retry_scale
-                )
-                rpacked = np.asarray(rh[0])[: rh[2]]
-                rcodes = (rpacked & 3).astype(np.int8)
-                rover = ((rpacked >> 2) & 1).astype(bool)
-                rdirty = ((rpacked >> 3) & 1).astype(bool)
-                allowed[ri] = rcodes == R_IS
-                gover[gunres] = rover | rdirty | (rcodes == R_ERR)
-                codes = codes.copy()
-                codes[np.flatnonzero(gunres)] = rcodes
-            fallback[gi] |= gover | gdirty | (codes == R_ERR)
-        found = np.asarray(res.found)[:n]
-        over = np.asarray(res.over)[:n]
-        dirty = (
-            np.asarray(res.dirty)[:n]
-            if res.dirty is not None else np.zeros(n, bool)
-        )
-        fmask = ~(fallback_mask | general)
-        allowed[fmask] = found[fmask]
-        # found is monotone and overlay-exact: a dirty/overflow brush only
-        # voids not-yet-found queries
-        fallback |= fmask & dirty & ~found
-        unres = fmask & over & ~found & ~dirty
-        if retry and unres.any() and self.retry_scale > 1:
-            ri = np.flatnonzero(unres)
-            rpad = min(_bucket(len(ri), 256), self.frontier)
-            renc = self._pad(tuple(a[ri] for a in enc), len(ri), rpad)
-            self.retries += len(ri)
-            ract = np.pad(np.ones(len(ri), bool), (0, rpad - len(ri)))
-            rassign = (
-                np.pad(assign[ri], (0, rpad - len(ri)))
-                if assign is not None else None
-            )
-            rres = self._sharded_run(
-                stacked, renc, ract, boost=self.retry_scale, assign=rassign,
-            )
-            rfound = np.asarray(rres.found)[: len(ri)]
-            rover = np.asarray(rres.over)[: len(ri)]
-            rdirty = (
-                np.asarray(rres.dirty)[: len(ri)]
-                if rres.dirty is not None else np.zeros(len(ri), bool)
-            )
-            allowed[ri] = rfound
-            unres[ri] = (rover | rdirty) & ~rfound
-        fallback |= unres
-        if leo_res is not None:
-            # closure-answered queries never fall back: they were masked
-            # out of the BFS, so their device bits are inert zeros
-            ans = leo_res[1]
-            allowed[ans] = leo_res[0][ans]
-            fallback &= ~ans
-        if cache_res is not None:
-            # cached verdicts likewise rode inactive all-zero BFS slots
-            allowed[cache_res[0]] = cache_res[1][cache_res[0]]
-            fallback &= ~cache_res[0]
+    def _after_collect(self, wave, allowed, fallback) -> None:
         # join the cross-host exchanges LAST and with no lock held: the
         # local device work (including retries) above overlapped the DCN
         # round trips, and a peer serving OUR rows may itself be waiting
         # for this host's run lock
         peer_attr = None
-        if peerh is not None:
-            peer_attr = peerh["sent"] | peerh["lost"]
-            for hid, (idx, pending, tmo) in peerh["pend"].items():
+        if wave.peers is not None:
+            peer_attr = wave.peers["sent"] | wave.peers["lost"]
+            for hid, (idx, pending, tmo) in wave.peers["pend"].items():
                 ok = pending.wait(tmo)
                 if ok is not None:
                     allowed[idx] = ok
@@ -951,12 +869,11 @@ class MeshCheckEngine(DeviceCheckEngine):
             # queries may carry -1 ids — clip, the attribution is
             # advisory telemetry, not a routing decision
             shards = graphshard.shard_of_np(
-                np.clip(enc[0][fb], 0, None),
-                np.clip(enc[1][fb], 0, None),
+                np.clip(wave.enc[0][fb], 0, None),
+                np.clip(wave.enc[1][fb], 0, None),
                 self.n_shards,
             )
             np.add.at(self._shard_fallbacks, shards, 1)
-        return allowed, fallback
 
     def consistency_cursors(self) -> tuple:
         """Per-shard drained-cursor vector for the freshness barrier and

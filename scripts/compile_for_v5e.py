@@ -134,7 +134,7 @@ def main() -> None:
             report("_sharded_general_run", graphshard._sharded_general_run,
                    *placed(arguments_of(
                        graphshard, "_sharded_general_run",
-                       lambda: eng._run_general_mesh(
+                       lambda: eng._run_general(
                            eng._stacked, enc, general
                        ),
                    )))

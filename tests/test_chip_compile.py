@@ -211,7 +211,7 @@ def test_sharded_general_run_compiles_for_four_chips(
     enc = mesh_engine._encode(snap, queries, 0)
     args, static = _arguments_of(
         monkeypatch, graphshard, "_sharded_general_run",
-        lambda: mesh_engine._run_general_mesh(
+        lambda: mesh_engine._run_general(
             mesh_engine._stacked, enc, np.arange(len(queries))
         ),
     )

@@ -103,7 +103,7 @@ def pinned(monkeypatch):
 
 
 def run_wave(eng, queries, retry=True):
-    """Dispatch and collect one chunk on ``eng``: the handle, the
+    """Dispatch and collect one chunk on ``eng``: the wave, the
     verdicts, the fallback mask and what ``_update_gen_occ`` was fed."""
     fed = []
     with pytest.MonkeyPatch.context() as mp:
@@ -113,12 +113,9 @@ def run_wave(eng, queries, retry=True):
             lambda occ, fast_b: (fed.append((np.array(occ), fast_b)),
                                  real(occ, fast_b)),
         )
-        handle = eng._dispatch(queries, 0)
-        if isinstance(handle, list):
-            allowed, fallback = eng._collect(handle)
-        else:
-            allowed, fallback = eng._collect(handle, retry=retry)
-    return handle, allowed, fallback, fed
+        wave = eng._dispatch(queries, 0)
+        allowed, fallback = eng._collect(wave, retry=retry)
+    return wave, allowed, fallback, fed
 
 
 # (rows, general rows, gen_lanes expected, retry lanes)
@@ -150,10 +147,11 @@ def test_general_tier_runs_at_its_rows(world, pinned, monkeypatch, n,
         plain, queries, retry=bool(retry_lanes))
 
     # the size follows the rows that need the tier, not the wave
-    assert fh[5]["gen_rows"] == n_general and fh[5]["gen_lanes"] == lanes
+    assert fh.meta["gen_rows"] == n_general
+    assert fh.meta["gen_lanes"] == lanes and ph.meta is None
     assert (fused.fused_general_rows - sized0[0],
             fused.fused_general_lanes - sized0[1]) == (n_general, lanes)
-    assert (fh[2] == general).all() and (ph[2] == general).all()
+    assert (fh.general == general).all() and (ph.general == general).all()
 
     # verdicts and the fallback mask: the cascade's, and where the device
     # answered, the oracle's
@@ -166,10 +164,10 @@ def test_general_tier_runs_at_its_rows(world, pinned, monkeypatch, n,
     assert f_fallback[general].sum() == (
         0 if retry_lanes else max(n_general - 256, 0))
 
-    bits = np.asarray(fh[3])[:n]
+    bits = np.asarray(fh.fused)[:n]
     # tier 2, base run: code, over, dirty of every general row
     if n_general:
-        base = np.asarray(ph[5][0])[:n_general].astype(np.int32)
+        base = np.asarray(ph.gen[0])[:n_general].astype(np.int32)
         unres = (((base >> 2) & 1) == 1) & (((base >> 3) & 1) == 0) & (
             (base & 3) != R_ERR)
         retried = ((bits >> 9) & 1).astype(bool)
@@ -184,12 +182,12 @@ def test_general_tier_runs_at_its_rows(world, pinned, monkeypatch, n,
     assert not (bits[~general] & 0x20F).any()
     # tier 1 and tier 0 masks
     fast = ~general
-    if ph[3] is not None:
+    if ph.fast is not None:
         assert (((bits >> 4) & 1)[fast]
-                == (np.asarray(ph[3])[:n] & 1)[fast]).all()
-    if ph[8] is not None:
-        assert (((bits >> 6) & 1).astype(bool) == ph[8][1]).all()
-        assert (((bits >> 7) & 1).astype(bool) == ph[8][0]).all()
+                == (np.asarray(ph.fast)[:n] & 1)[fast]).all()
+    if ph.leo_res is not None:
+        assert (((bits >> 6) & 1).astype(bool) == ph.leo_res[1]).all()
+        assert (((bits >> 7) & 1).astype(bool) == ph.leo_res[0]).all()
 
     # what the adaptive EMAs are fed: the cascade's vector, root for root
     assert len(f_fed) == len(p_fed) == (1 if n_general else 0)
@@ -233,3 +231,76 @@ def test_one_program_for_every_count_in_a_bucket(world, pinned, monkeypatch):
     assert [fn for fn, _ in texts] == ["fused_wave", "fused_wave"]
     assert texts[0][1] == texts[1][1]
     assert texts[0][1].startswith("Q=1024 GQ=384 ")
+
+
+
+def test_launchers_agree_on_a_dirty_row_and_a_forced_overflow(
+        world, pinned, monkeypatch):
+    """One seeded mixed wave through every branch the launchers share
+    (engine/wave.py).  Rows the overlay made dirty fall back on the
+    cascade and on the fused wave alike; fast rows made to read
+    "overflowed" on the cascade's first pass are answered by its retry as
+    if nothing had happened, and stay in ``fallback`` without one.  The
+    file's last test: the write leaves a dirty row behind.
+    (tests/test_parallel.py holds the mesh's cascade to the same.)"""
+    oracle, fused, plain, editors = world
+    monkeypatch.setattr(fused, "fused_retry_lanes", 1)
+    queries, general = seeded_wave(editors, 1024, 307, seed=1331)
+    # a group edge onto a doc that had none: the doc's editors row goes
+    # dirty, and the group's member reaches the doc through it alone
+    tuples, _ = seeded_graph(30)
+    doc = next(d for d in range(N_DOCS)
+               if not any(t.startswith(f"Doc:d{d}#editors@Group:")
+                          for t in tuples))
+    user = next(int(t.split("User:u")[1]) for t in tuples
+                if t.startswith("Group:g1#members@User:")
+                and int(t.split("User:u")[1]) not in editors[doc])
+    fused.snapshot(), plain.snapshot()  # the write below rides the overlay
+    plain.store.write_relation_tuples(
+        T(f"Doc:d{doc}#editors@Group:g1#members"))
+    dirty = [int(np.flatnonzero(~general)[0]), int(np.flatnonzero(general)[0])]
+    queries[dirty[0]] = T(f"Doc:d{doc}#view@User:u{user}")
+    queries[dirty[1]] = T(f"Doc:d{doc}#edit@User:u{user}")
+    want = np.array([oracle.check_is_member(q, 0) for q in queries])
+    assert want[dirty[0]]  # through the new edge
+
+    _, f_allowed, f_fallback, _ = run_wave(fused, queries)
+    assert f_fallback[dirty[0]], "the device cannot walk the new edge"
+    # ... nor can it for the other rows on that doc; nothing else falls
+    # back: the retry lanes take every overflow
+    assert all(q.object == f"d{doc}"
+               for q, fell in zip(queries, f_fallback) if fell)
+    assert (f_allowed[~f_fallback] == want[~f_fallback]).all()
+
+    # the cascade, its first fast fetch made to say that five rows the
+    # device answered ran over
+    real = plain._fast_bits
+    forced = np.flatnonzero(
+        ~general & np.array([q.relation == "view" for q in queries])
+        & ~f_fallback)[:5]
+    passes = []
+
+    def overflowed(res, k):
+        bits = real(res, k)
+        if not passes:
+            bits.found[forced], bits.over[forced] = False, True
+        passes.append(k)
+        return bits
+
+    monkeypatch.setattr(plain, "_fast_bits", overflowed)
+    retries0, retry0 = plain.retries, plain.phase_counts.get("check_retry", 0)
+    _, p_allowed, p_fallback, _ = run_wave(plain, queries)
+    assert passes == [1024, 5]  # the wave, then the retry of the five
+    assert (p_allowed == f_allowed).all() and (p_fallback == f_fallback).all()
+    # 5 fast rows and the 51 general rows past the base schedule's 256
+    assert plain.retries - retries0 == 5 + 307 - 256
+    assert plain.phase_counts["check_retry"] - retry0 == 2
+
+    del passes[:]
+    _, p_allowed, p_fallback, _ = run_wave(plain, queries, retry=False)
+    assert passes == [1024] and p_fallback[forced].all()
+    past = np.zeros(1024, bool)
+    past[np.flatnonzero(general)[256:]] = True  # the base schedule's 256
+    past[forced] = True
+    assert (p_fallback == f_fallback | past).all()
+    assert (p_allowed[~p_fallback] == want[~p_fallback]).all()

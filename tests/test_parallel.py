@@ -476,6 +476,9 @@ def test_mesh_engine_general_synth_differential():
 
     graph = build_synth(n_users=64, n_groups=8, n_folders=32, n_docs=128,
                         seed=3)
+    # the (Doc, viewers, Group, members) pair, for the overlay write below
+    graph.store.write_relation_tuples(
+        RelationTuple.from_string("Doc:d99#viewers@Group:g0#members"))
     eng = MeshCheckEngine(
         graph.store, graph.manager, mesh_devices=8,
         frontier=1024, arena=4096, gen_arena=4096, vcap=1024,
@@ -497,6 +500,64 @@ def test_mesh_engine_general_synth_differential():
     )
     # full path stays exact for the fallback slice too
     assert eng.batch_check(queries) == want
+
+    # One seeded mixed wave through every branch the mesh shares with the
+    # one-chip launchers (engine/wave.py; tests/test_fused_lanes.py holds
+    # those two to the same): rows the overlay made dirty fall back; fast
+    # rows made to read "overflowed" on the first pass are answered by the
+    # retry as if nothing had happened, and stay in fallback without one.
+    member = next(
+        u for u in graph.users
+        if eng.oracle.check_is_member(T(f"Group:g1#members@{u}"))
+        and not eng.oracle.check_is_member(T(f"Doc:d5#view@{u}")))
+    graph.store.write_relation_tuples(T("Doc:d5#viewers@Group:g1#members"))
+    mixed = synth_queries_mixed(graph, 62, seed=22, general_frac=0.5)
+    mixed += [T(f"Doc:d5#view@{member}"), T(f"Doc:d5#edit@{member}")]
+    want = np.array([eng.oracle.check_is_member(q) for q in mixed])
+
+    def run(retry):
+        before = dict(eng.phase_counts)
+        wave = eng._dispatch(mixed, 0)
+        allowed, fallback = eng._collect(wave, retry=retry)
+        return wave, allowed, fallback, {
+            k: v - before.get(k, 0) for k, v in eng.phase_counts.items()
+            if v != before.get(k, 0)}
+
+    wave, base_allowed, base_fallback, counts = run(True)
+    assert base_fallback[-2:].all(), "both dirty rows are the oracle's"
+    assert (base_allowed[~base_fallback] == want[~base_fallback]).all()
+    assert "check_mesh_retry" not in counts
+
+    real, passes = eng._fast_bits, []
+    answered = wave.err | wave.general | base_fallback
+    if wave.leo_res is not None:
+        answered |= wave.leo_res[1]
+    forced = np.flatnonzero(~answered)[:5]
+    assert len(forced) == 5
+
+    def overflowed(res, k):
+        bits = real(res, k)
+        if not passes:
+            bits.found[forced], bits.over[forced] = False, True
+        passes.append(k)
+        return bits
+
+    eng._fast_bits = overflowed
+    retries0 = eng.retries
+    _, allowed, fallback, counts = run(True)
+    assert passes == [64, 5] and eng.retries - retries0 == 5
+    assert (allowed == base_allowed).all()
+    assert (fallback == base_fallback).all()
+    # the mesh's own span set: the retry under the run lock, and neither
+    # of the one-chip collect's phases
+    assert counts["check_mesh_retry"] == 1 and counts["check_mesh_fast"] == 1
+    assert not {"check_retry", "check_collect_sync"} & set(eng.phase_counts)
+
+    del passes[:]
+    _, allowed, fallback, counts = run(False)
+    assert passes == [64] and "check_mesh_retry" not in counts
+    assert (fallback == base_fallback | np.isin(np.arange(64), forced)).all()
+    assert (allowed[~fallback] == want[~fallback]).all()
 
 
 # ---------------------------------------------------------------------------

@@ -1,55 +1,8 @@
 """Unit tests for the device-engine array utilities."""
 
 import jax.numpy as jnp
-import numpy as np
 
-from ketotpu.engine.xutil import arena_assign, lex_searchsorted, lex_sort
-
-
-def test_lex_searchsorted_pairs():
-    keys = [(0, 1), (0, 5), (2, 2), (2, 3), (7, 0)]
-    a = jnp.array([k[0] for k in keys], jnp.int32)
-    b = jnp.array([k[1] for k in keys], jnp.int32)
-    queries = [(0, 1), (0, 2), (2, 3), (7, 0), (8, 8), (-1, 0), (0, 0)]
-    qa = jnp.array([q[0] for q in queries], jnp.int32)
-    qb = jnp.array([q[1] for q in queries], jnp.int32)
-    idx, found = lex_searchsorted((a, b), (qa, qb))
-    assert found.tolist() == [True, False, True, True, False, False, False]
-    assert idx.tolist() == [0, 1, 3, 4, 5, 0, 0]
-
-
-def test_lex_searchsorted_empty():
-    idx, found = lex_searchsorted(
-        (jnp.zeros((0,), jnp.int32),), (jnp.array([3], jnp.int32),)
-    )
-    assert found.tolist() == [False]
-
-
-def test_lex_searchsorted_random_vs_numpy():
-    rng = np.random.default_rng(0)
-    for _ in range(10):
-        n = int(rng.integers(1, 200))
-        a = rng.integers(0, 10, n).astype(np.int32)
-        b = rng.integers(0, 10, n).astype(np.int32)
-        order = np.lexsort((b, a))
-        a, b = a[order], b[order]
-        qa = rng.integers(-1, 11, 50).astype(np.int32)
-        qb = rng.integers(-1, 11, 50).astype(np.int32)
-        idx, found = lex_searchsorted(
-            (jnp.array(a), jnp.array(b)), (jnp.array(qa), jnp.array(qb))
-        )
-        keyset = set(zip(a.tolist(), b.tolist()))
-        for i in range(50):
-            assert found[i] == ((qa[i], qb[i]) in keyset)
-
-
-def test_lex_sort_carries_payload():
-    keys = (jnp.array([2, 1, 2], jnp.int32), jnp.array([0, 9, -1], jnp.int32))
-    payload = jnp.array([10, 20, 30], jnp.int32)
-    (ka, kb), (p,) = lex_sort(keys, payload)
-    assert ka.tolist() == [1, 2, 2]
-    assert kb.tolist() == [9, -1, 0]
-    assert p.tolist() == [20, 30, 10]
+from ketotpu.engine.xutil import arena_assign
 
 
 def test_arena_assign():
