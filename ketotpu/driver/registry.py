@@ -1523,6 +1523,9 @@ class Registry:
             outer.flush_thread_states()
             m.gauge("keto_engine_coalesced_waves", outer.waves,
                     help="coalesced check dispatch waves")
+            m.gauge("keto_coalescer_waves_ahead_total", outer.waves_ahead,
+                    help="waves submitted to the device while an earlier "
+                         "wave was not yet collected")
             m.gauge("keto_engine_coalesced_checks", outer.coalesced,
                     help="single checks served via coalesced waves")
             m.gauge("keto_singleflight_collapsed", outer.singleflight_collapsed,

@@ -7,7 +7,10 @@ ACROSS requests instead — a single check costs a full device dispatch
 serving concurrent Check RPCs one dispatch each wastes almost all of the
 machine.  The coalescer queues single checks for up to ``window``
 seconds (or until ``max_pending``) and answers the whole wave with one
-``batch_check`` call on the underlying engine.
+dispatch of the underlying engine: ``submit`` on the thread that cut the
+wave, ``collect`` on a second one, so the next wave is encoded and
+launched while this one is on the device (``batch_check`` for an engine
+without the pair).
 
 Semantics are unchanged: per-query typed errors (the oracle's client
 errors) are re-raised in the calling thread; other queries in the same
@@ -58,6 +61,63 @@ def _fused_counts(inner) -> Tuple[Dict[str, int], Dict[str, int]]:
     return counts, dict(getattr(inner, "fused_tier_rows", None) or {})
 
 
+def _gained(after: Dict[str, float], before: Dict[str, float]) -> dict:
+    """The entries of ``after`` that grew since ``before``, by how much."""
+    grown = {k: v - before.get(k, 0) for k, v in after.items()}
+    return {k: d for k, d in grown.items() if d > 0}
+
+
+def _twice(first, again):
+    """``first()``, and ``again()`` where that failed with anything but a
+    typed error: ONE bounded whole-batch retry — a transient device /
+    runtime hiccup should not error up to max_pending concurrent callers
+    when a second dispatch would have succeeded (per-query degradation is
+    still avoided: it would serialize the wave on one thread)."""
+    try:
+        return first()
+    except KetoAPIError:
+        raise
+    except Exception:  # noqa: BLE001
+        return again()
+
+
+def _phases_here(inner) -> Dict[str, float]:
+    """The engine phase seconds the calling thread has spent so far."""
+    here = getattr(inner, "thread_phase_seconds", None)
+    return dict(here()) if here is not None else {}
+
+
+def _routes(inner, attr: str) -> Optional[np.ndarray]:
+    """A mesh engine's cumulative routed rows per shard or per peer host."""
+    fn = getattr(inner, attr, None)
+    return fn() if fn is not None else None
+
+
+def _routed(after, before) -> Dict[str, int]:
+    """Rows routed between two readings of :func:`_routes`, by index."""
+    if after is None:
+        return {}
+    return {str(i): int(d) for i, d in enumerate(after - before) if d > 0}
+
+
+def _submit_side(inner) -> tuple:
+    """What a wave's submit moves, read on the thread that submits it:
+    this thread's phase seconds and, on a mesh, the rows routed to each
+    shard and shipped to each peer host."""
+    return (_phases_here(inner), _routes(inner, "shard_route_counts"),
+            _routes(inner, "peer_route_counts"))
+
+
+def _collect_side(inner) -> tuple:
+    """What a wave's collect moves, read on the thread that collects it:
+    this thread's phase seconds, Leopard's answers, the oracle's
+    fallbacks and the fused-wave counters (all counted at collect)."""
+    return (_phases_here(inner),
+            int(getattr(inner, "leopard_answered", 0) or 0),
+            int(getattr(inner, "fallbacks", 0) or 0),
+            _fused_counts(inner))
+
+
 class _Slot:
     __slots__ = ("tuple", "depth", "bypass", "event", "result", "error",
                  "t_enq", "t_dispatch", "wave", "traceparent", "followers")
@@ -70,7 +130,7 @@ class _Slot:
         self.result: Optional[bool] = None
         self.error: Optional[BaseException] = None
         self.t_enq = time.perf_counter()
-        self.t_dispatch: Optional[float] = None  # set by the wave worker
+        self.t_dispatch: Optional[float] = None  # _Group.stamp
         self.wave: Optional[int] = None
         # wave-ledger cross-link: the enqueuing RPC's trace id, and how
         # many identical pending checks singleflight-parked on this slot
@@ -101,6 +161,57 @@ class _ColumnGroup:
         self.wave: Optional[int] = None
         self.traceparent: Optional[str] = None
         self.followers = 0  # groups never singleflight; ledger parity
+
+
+class _Group:
+    """One (depth, bypass) group of a cut wave: its scalar slots, its
+    column groups, the merged block that carries both (None: scalar slots
+    alone, or an inner engine without a block surface) and, from an inner
+    engine with the ``submit`` / ``collect`` pair, the launched ticket —
+    or the failure that stopped its launch."""
+
+    __slots__ = ("depth", "bypass", "slots", "cgroups", "merged", "ticket",
+                 "failure", "t_dispatch")
+
+    def __init__(self, depth: int, bypass: bool, members):
+        self.depth = depth
+        self.bypass = bypass
+        self.slots = [m for m in members if not isinstance(m, _ColumnGroup)]
+        self.cgroups = [m for m in members if isinstance(m, _ColumnGroup)]
+        self.merged = None
+        self.ticket = None
+        self.failure: Optional[BaseException] = None
+        self.t_dispatch: Optional[float] = None
+
+    def scope(self):
+        """Re-bind the cache escape hatch on the calling thread for a
+        group of bypass members, so the inner engine's own cache probe
+        (at submit) and insert (at collect) honor it.  A fresh scope per
+        entry: generator context managers are one-shot."""
+        return (cache_context.scope(bypass=True) if self.bypass
+                else contextlib.nullcontext())
+
+    def stamp(self, wave_id: int) -> None:
+        """The engine's dispatch of this group starts now."""
+        self.t_dispatch = time.perf_counter()
+        for m in (*self.slots, *self.cgroups):
+            m.t_dispatch = self.t_dispatch
+            m.wave = wave_id
+
+
+class _Cut:
+    """A wave from its cut to its filing: what the collector thread hands
+    the dispatcher thread."""
+
+    __slots__ = ("wave_id", "members", "rows", "groups", "submitted")
+
+    def __init__(self, wave_id: int, members, rows: int):
+        self.wave_id = wave_id
+        self.members = members
+        self.rows = rows
+        self.groups: List[_Group] = []
+        #: (phase seconds, shard rows, peer rows) the submit moved
+        self.submitted: tuple = ({}, {}, {})
 
 
 class CoalescingEngine:
@@ -145,12 +256,22 @@ class CoalescingEngine:
         self.cache_hits = 0  # observability: checks served pre-admission
         self.batch_ingested = 0  # observability: batch items ridden on waves
         self.block_waves = 0  # observability: waves carrying column groups
-        # double-buffered dispatch: the collector thread cuts wave N+1 and
-        # does its host-side prep (grouping, merged-block build, vocab
-        # pre-encode) WHILE the dispatcher thread drives wave N through
-        # the device — host encode time leaves the wave cadence.  The
-        # depth-1 queue is the pair of staging buffers: one wave in
-        # flight, one staged.
+        # waves whose submit returned while an earlier wave was not yet
+        # collected (keto_coalescer_waves_ahead_total), and how many
+        # submitted waves are uncollected right now (under _lock)
+        self.waves_ahead = 0
+        self._uncollected = 0
+        # two threads, one wave apart: the collector thread cuts wave N+1
+        # and SUBMITS it (grouping, merged-block build, and the inner
+        # engine's encode + launch, for scalar and merged waves alike)
+        # while wave N is on the device; the dispatcher thread only
+        # COLLECTS (sync, decode, scatter, wake the callers, file the
+        # ledger record).  The device's queue then holds the next wave
+        # behind the running one, so host encode time is off the wave
+        # period.  An inner engine without the submit / collect pair is
+        # dispatched whole by the dispatcher thread.  The depth-1 queue is
+        # the pair of staging buffers: one wave being collected, one
+        # staged, and a third held by the collector at put().
         self._stage: Optional[queue.Queue] = (
             queue.Queue(maxsize=1) if pipeline else None
         )
@@ -160,7 +281,7 @@ class CoalescingEngine:
         # idle (nothing pending), window, prepare, stage_blocked (a wave
         # staged and one in flight); dispatcher: stage_empty, serve, file
         # (waking the callers + the ledger record).  Unpipelined, the
-        # collector serves and files too.
+        # collector serves and files too.  ``prepare`` holds the submit.
         self.thread_seconds: Dict[Tuple[str, str], float] = {}
         self._collector_states = self._thread_states("collector")
         self._dispatcher_states = self._thread_states("dispatcher")
@@ -258,8 +379,10 @@ class CoalescingEngine:
                 f"(waited {waited:.3f}s)"
             )
         # stage decomposition for the RPC that enqueued us: queue wait is
-        # enqueue -> wave cut, device compute is wave cut -> wakeup (both
-        # no-ops when this thread isn't serving an instrumented RPC)
+        # enqueue -> the start of the wave's dispatch (its submit), device
+        # compute is from there to the wakeup, the wait behind the waves
+        # launched before it included: the wave is in the device's queue
+        # (both no-ops when this thread isn't serving an instrumented RPC)
         done = time.perf_counter()
         if slot.t_dispatch is not None:
             flightrec.note_stage("coalesce_wait", slot.t_dispatch - slot.t_enq)
@@ -494,223 +617,193 @@ class CoalescingEngine:
                     if remaining <= 0:
                         break
                     self._wake.wait(remaining)
-                states.enter("prepare", rows=len(self._pending))
+                states.enter("prepare", rows=len(self._pending),
+                             ahead=self._uncollected)
                 wave, self._pending = self._pending, []
                 # the wave owns its slots now: identical checks arriving
                 # from here on start a fresh flight (the cache, refilled
-                # by this wave's dispatch, catches them instead)
+                # by this wave's collect, catches them instead)
                 self._inflight.clear()
+            # the view is taken inside submit, after the cut: every row of
+            # the wave was enqueued before it, so a write acknowledged
+            # before a Check was sent is in the view that answers it
+            cut = self._prepare(wave)
             if self._stage is None:
-                self._serve(wave, states=states)
+                self._serve(cut, states=states)
             else:
-                # double-buffer handoff: prep (grouping + merged-block
-                # build + vocab pre-encode) runs here on the collector
-                # while the dispatcher drives the PREVIOUS wave; put()
-                # blocks only when a wave is staged AND one is in flight
-                prepared = self._prepare(wave)
+                # put() blocks only when a wave is staged AND one is being
+                # collected: both, and this one, are already launched
                 states.enter("stage_blocked")
-                self._stage.put((wave, prepared))
+                self._stage.put(cut)
 
     def _run_dispatch(self) -> None:
         states = self._dispatcher_states
         while True:
             states.enter("stage_empty")
-            item = self._stage.get()
-            if item is None:
+            cut = self._stage.get()
+            if cut is None:
                 states.close()
                 return
-            wave, prepared = item
-            self._serve(wave, prepared, states=states)
+            self._serve(cut, states=states)
 
-    def _prepare(self, wave) -> dict:
-        """Host-side wave prep, off the dispatch critical path: group by
-        (depth, bypass), split scalar slots from column groups, build the
-        merged block per group, and pre-encode it against the engine's
-        current vocabulary (append-only ids: anything resolved now is
-        still exact at dispatch; misses refresh then)."""
-        inner_bc = getattr(self.inner, "batch_check_block", None)
-        raw: dict = {}
-        for s in wave:
-            raw.setdefault((s.depth, s.bypass), []).append(s)
-        prepared = {}
-        for key, members in raw.items():
-            slots = [m for m in members if not isinstance(m, _ColumnGroup)]
-            cgroups = [m for m in members if isinstance(m, _ColumnGroup)]
-            merged = None
-            if cgroups and inner_bc is not None:
-                parts = []
-                if slots:
-                    # scalar singles ride the merged block: their tuples
-                    # ARE the pre-materialized items, so the fold is free
-                    parts.append(colmod.ColumnBlock.from_tuples(
-                        [s.tuple for s in slots]
-                    ))
-                parts.extend(g.block for g in cgroups)
-                merged = colmod.ColumnBlock.concat(parts)
-                vocab = getattr(self.inner, "_vocab", None)
-                if vocab is not None:
-                    try:
-                        merged.encode_for(vocab)
-                    except Exception:  # noqa: BLE001 - prep is advisory;
-                        pass  # the dispatch encode is the authority
-            prepared[key] = (slots, cgroups, merged)
-        return prepared
-
-    def _serve(self, wave, prepared: Optional[dict] = None, *,
-               states: profiler.ThreadStates) -> None:
+    def _prepare(self, wave) -> _Cut:
+        """The submit half of a wave, on the thread that cut it: number
+        it, group it by (depth, bypass), split scalar slots from column
+        groups, build each group's merged block and, where the inner
+        engine has the ``submit`` / ``collect`` pair, submit every group
+        (scalar slots as a tuple list, a group with column groups as its
+        merged block) under the group's cache-bypass scope."""
+        inner = self.inner
         self.waves += 1
-        # the ledger is the wave-id authority when present so flight
-        # recorder entries (wave=) and /debug/waves join on the same id
-        wave_id = (
-            self.ledger.next_wave_id() if self.ledger is not None
-            else self.waves
-        )
         rows = sum(
             len(s.block) if isinstance(s, _ColumnGroup) else 1 for s in wave
         )
         self.coalesced += rows
-        states.enter("serve", wave=wave_id, rows=rows)
-        # engine counter/phase deltas around the dispatches: only one
-        # thread dispatches waves (the collector, or the dispatcher when
-        # pipelining), so the deltas attribute cleanly
-        inner = self.inner
-        leo_before = int(getattr(inner, "leopard_answered", 0) or 0)
-        fb_before = int(getattr(inner, "fallbacks", 0) or 0)
-        phase_before = dict(getattr(inner, "phase_seconds", None) or {})
-        # fused tiered dispatch (engine/fused.py): per-wave deltas of the
-        # fused-wave count, its D2H fetches (the single-fetch invariant is
-        # checked as waves == fetches), the general tier's rows and lanes
-        # (how full the tier ran) and the per-tier row attribution
-        fused_before = _fused_counts(inner)
-        # per-shard wave accounting (mesh serving): routed-root deltas
-        # across this wave's dispatches land in the ledger entry
-        routes_fn = getattr(inner, "shard_route_counts", None)
-        shards_before = routes_fn() if routes_fn is not None else None
-        # per-peer wave accounting (multi-host mesh): rows shipped to
-        # each peer host across this wave's dispatches
-        peers_fn = getattr(inner, "peer_route_counts", None)
-        peers_before = peers_fn() if peers_fn is not None else None
-        device_s = 0.0
-        if prepared is None:
-            prepared = self._prepare(wave)
-        if any(cg for _, cg, _ in prepared.values()):
-            self.block_waves += 1
-        for k, ((depth, byp), (slots, cgroups, merged)) in enumerate(
-                prepared.items()):
-            if k:  # the group before left this thread filing
-                states.enter("serve", wave=wave_id, rows=rows)
-            t_dispatch = time.perf_counter()
-            for s in slots:
-                s.t_dispatch = t_dispatch
-                s.wave = wave_id
-            for g in cgroups:
-                g.t_dispatch = t_dispatch
-                g.wave = wave_id
-            # re-bind the escape hatch on THIS thread for bypass slots so
-            # the inner engine's own cache probe/insert honor it (fresh
-            # scope per entry — generator context managers are one-shot)
-            def _ctx(byp=byp):
-                return (cache_context.scope(bypass=True) if byp
-                        else contextlib.nullcontext())
+        # the ledger is the wave-id authority when present so flight
+        # recorder entries (wave=) and /debug/waves join on the same id
+        cut = _Cut(
+            self.ledger.next_wave_id() if self.ledger is not None
+            else self.waves, wave, rows,
+        )
+        raw: dict = {}
+        for s in wave:
+            raw.setdefault((s.depth, s.bypass), []).append(s)
+        cut.groups = [_Group(*key, members) for key, members in raw.items()]
+        inner_bc = getattr(inner, "batch_check_block", None)
+        submit = getattr(inner, "submit", None)
+        if getattr(inner, "collect", None) is None:
+            submit = None
+        before = _submit_side(inner)
+        for g in cut.groups:
+            if g.cgroups and inner_bc is not None:
+                parts = []
+                if g.slots:
+                    # scalar singles ride the merged block: their tuples
+                    # ARE the pre-materialized items, so the fold is free
+                    parts.append(colmod.ColumnBlock.from_tuples(
+                        [s.tuple for s in g.slots]
+                    ))
+                parts.extend(c.block for c in g.cgroups)
+                g.merged = colmod.ColumnBlock.concat(parts)
+            batch = (g.merged if g.merged is not None
+                     else [s.tuple for s in g.slots])
+            if submit is None or not len(batch):
+                continue
+            g.stamp(cut.wave_id)
             try:
-                if merged is not None:
-                    self._dispatch_merged(slots, cgroups, merged, depth, _ctx)
-                    continue
-                for g in cgroups:
-                    # inner engine without a block surface (fakes, the CPU
-                    # oracle): serve each group through the item shim
-                    self._dispatch_group_via_tuples(g, depth, _ctx)
-                if not slots:
-                    continue
-                with _ctx():
-                    # one bounded whole-batch retry: a transient device /
-                    # runtime hiccup should not error up to max_pending
-                    # concurrent callers when a second dispatch would have
-                    # succeeded (per-query degradation is still avoided —
-                    # it would serialize the wave on this one thread)
-                    for attempt in range(2):
-                        try:
-                            verdicts = self.inner.batch_check(
-                                [s.tuple for s in slots], depth
-                            )
-                            break
-                        except KetoAPIError:
-                            raise
-                        except Exception:  # noqa: BLE001
-                            if attempt:
-                                raise
-                    for s, v in zip(slots, verdicts):
-                        s.result = bool(v)
-            except KetoAPIError:
-                # a typed client error aborted the batch: answer each query
-                # individually so only the erroring ones raise
-                with _ctx():
-                    for s in slots:
-                        try:
-                            s.result = bool(
-                                self.inner.batch_check([s.tuple], depth)[0]
-                            )
-                        except Exception as e:  # noqa: BLE001
-                            s.error = e
-            except Exception as e:  # noqa: BLE001
-                # retry also failed: raise to every caller and let them
-                # retry against a (hopefully) recovered engine
-                for s in slots:
-                    s.error = e
+                with g.scope():
+                    g.ticket = submit(batch, g.depth)
+            except Exception as e:  # noqa: BLE001 - answered for in _serve
+                g.failure = e
+        after = _submit_side(inner)
+        cut.submitted = (
+            _gained(after[0], before[0]),
+            _routed(after[1], before[1]), _routed(after[2], before[2]),
+        )
+        if any(g.cgroups for g in cut.groups):
+            self.block_waves += 1
+        with self._lock:
+            if submit is not None and self._uncollected:
+                self.waves_ahead += 1
+            self._uncollected += 1
+        return cut
+
+    def _serve(self, cut: _Cut, *, states: profiler.ThreadStates) -> None:
+        """The collect half of a wave: every group's ticket collected
+        (or, from an inner engine without the pair, its dispatch made
+        now), verdicts and errors scattered to the members, their events
+        set, the ledger record filed."""
+        states.enter("serve", wave=cut.wave_id, rows=cut.rows)
+        before = _collect_side(self.inner)
+        device_s = 0.0
+        for k, g in enumerate(cut.groups):
+            if k:  # the group before left this thread filing
+                states.enter("serve", wave=cut.wave_id, rows=cut.rows)
+            if g.t_dispatch is None:
+                g.stamp(cut.wave_id)
+            try:
+                self._answer(g)
             finally:
-                device_s += time.perf_counter() - t_dispatch
-                states.enter("file", wave=wave_id)
-                for s in slots:
-                    s.event.set()
-                for g in cgroups:
-                    g.event.set()
+                device_s += time.perf_counter() - g.t_dispatch
+                states.enter("file", wave=cut.wave_id)
+                for m in (*g.slots, *g.cgroups):
+                    m.event.set()
+        with self._lock:
+            self._uncollected -= 1
         if self.ledger is not None:
             try:
-                shard_delta = None
-                if shards_before is not None:
-                    after = routes_fn()
-                    shard_delta = {
-                        str(i): int(d)
-                        for i, d in enumerate(after - shards_before)
-                        if d > 0
-                    }
-                peer_delta = None
-                if peers_before is not None:
-                    pafter = peers_fn()
-                    peer_delta = {
-                        str(i): int(d)
-                        for i, d in enumerate(pafter - peers_before)
-                        if d > 0
-                    }
-                self._file_wave(
-                    wave_id, wave, len(prepared), device_s,
-                    leo_before, fb_before, phase_before,
-                    shards=shard_delta, peers=peer_delta,
-                    fused_before=fused_before,
-                )
+                self._file_wave(cut, device_s, before)
             except Exception:  # noqa: BLE001 - diagnostics must never
                 pass  # take down the wave worker
 
-    def _dispatch_merged(self, slots, cgroups, merged, depth, _ctx) -> None:
+    def _first(self, g: _Group, again, errs: Optional[dict] = None):
+        """A group's answer: its ticket collected (or the failure of its
+        submit raised), or ``again()``, the whole dispatch on this thread,
+        for an inner engine without the pair; once more ``again()`` by
+        :func:`_twice`'s rule."""
+        def first():
+            if g.failure is not None:
+                raise g.failure
+            if g.ticket is None:
+                return again()
+            return self.inner.collect(g.ticket, errs)
+
+        return _twice(first, again)
+
+    def _answer_each(self, g: _Group) -> None:
+        """A typed error aborted the group's batch: answer each scalar
+        slot individually, so only the erroring ones raise."""
+        with g.scope():
+            for s in g.slots:
+                try:
+                    s.result = bool(
+                        self.inner.batch_check([s.tuple], g.depth)[0]
+                    )
+                except Exception as e:  # noqa: BLE001
+                    s.error = e
+
+    def _answer(self, g: _Group) -> None:
+        """Answer one group's members.  Never raises: failures land on
+        the members."""
+        if g.merged is not None:
+            self._answer_merged(g)
+            return
+        for c in g.cgroups:
+            # inner engine without a block surface (fakes, the CPU
+            # oracle): serve each group through the item shim
+            self._answer_via_tuples(c, g)
+        slots, depth = g.slots, g.depth
+        if not slots:
+            return
+        try:
+            with g.scope():
+                verdicts = self._first(g, lambda: self.inner.batch_check(
+                    [s.tuple for s in slots], depth
+                ))
+            for s, v in zip(slots, verdicts):
+                s.result = bool(v)
+        except KetoAPIError:
+            self._answer_each(g)
+        except Exception as e:  # noqa: BLE001
+            # retry also failed: raise to every caller and let them
+            # retry against a (hopefully) recovered engine
+            for s in slots:
+                s.error = e
+
+    def _answer_merged(self, g: _Group) -> None:
         """ONE columnar dispatch for a (depth, bypass) group's scalar
         slots + column groups; verdicts and typed per-item errors scatter
         back by row offset.  Never raises — failures land on the members
         (scalar-slot semantics match the item-list path: typed batch-wide
         errors re-dispatch singles individually; generic failures after
         the bounded retry error every member)."""
+        slots, cgroups, depth = g.slots, g.cgroups, g.depth
         try:
-            with _ctx():
-                for attempt in range(2):
-                    try:
-                        allowed, errs = self.inner.batch_check_block(
-                            merged, depth
-                        )
-                        break
-                    except KetoAPIError:
-                        raise
-                    except Exception:  # noqa: BLE001
-                        if attempt:
-                            raise
+            with g.scope():
+                allowed, errs = self._first(
+                    g, lambda: self.inner.batch_check_block(g.merged, depth),
+                    errs={},
+                )
             off = 0
             for s in slots:
                 e = errs.get(off)
@@ -719,10 +812,10 @@ class CoalescingEngine:
                 else:
                     s.result = bool(allowed[off])
                 off += 1
-            for g in cgroups:
-                m = len(g.block)
-                g.verdicts = allowed[off:off + m].copy()
-                g.errors = {
+            for c in cgroups:
+                m = len(c.block)
+                c.verdicts = allowed[off:off + m].copy()
+                c.errors = {
                     i - off: e for i, e in errs.items() if off <= i < off + m
                 }
                 off += m
@@ -730,63 +823,49 @@ class CoalescingEngine:
             # batch-wide typed error (deadline, shed): scalar slots retry
             # individually (scalar-wave parity); groups surface the error
             # to their caller, whose handler owns the per-item fan-out
-            with _ctx():
-                for s in slots:
-                    try:
-                        s.result = bool(
-                            self.inner.batch_check([s.tuple], depth)[0]
-                        )
-                    except Exception as e2:  # noqa: BLE001
-                        s.error = e2
-            for g in cgroups:
-                g.error = e
+            self._answer_each(g)
+            for c in cgroups:
+                c.error = e
         except Exception as e:  # noqa: BLE001
-            for s in slots:
-                s.error = e
-            for g in cgroups:
-                g.error = e
+            for m in (*slots, *cgroups):
+                m.error = e
 
-    def _dispatch_group_via_tuples(self, g, depth, _ctx) -> None:
+    def _answer_via_tuples(self, c: _ColumnGroup, g: _Group) -> None:
         """Serve one column group on an inner engine that only speaks item
         lists; same bounded retry as scalar waves.  Never raises."""
-        try:
-            with _ctx():
-                for attempt in range(2):
-                    try:
-                        g.verdicts, g.errors = colmod.block_check_via_tuples(
-                            self.inner, g.block, depth
-                        )
-                        return
-                    except KetoAPIError:
-                        raise
-                    except Exception:  # noqa: BLE001
-                        if attempt:
-                            raise
-        except Exception as e:  # noqa: BLE001
-            g.error = e
+        def call():
+            return colmod.block_check_via_tuples(self.inner, c.block, g.depth)
 
-    def _file_wave(self, wave_id: int, wave: List[_Slot], n_groups: int,
-                   device_s: float, leo_before: int, fb_before: int,
-                   phase_before: dict, shards: Optional[dict] = None,
-                   peers: Optional[dict] = None,
-                   fused_before: Optional[tuple] = None) -> None:
+        try:
+            with g.scope():
+                c.verdicts, c.errors = _twice(call, call)
+        except Exception as e:  # noqa: BLE001
+            c.error = e
+
+    def _file_wave(self, cut: _Cut, device_s: float, before: tuple) -> None:
         """One ledger record per wave: occupancy, waits, device time,
         short-circuit counts, engine phase deltas, slowest traceparents —
         and, when the inner engine is sharded, the per-shard routed-root
         deltas this wave produced (plus per-peer shipped-row deltas on a
         multi-host topology).  Fused-dispatch waves additionally carry
-        the per-tier attribution deltas the single D2H fetch returned."""
-        inner = self.inner
+        the per-tier attribution deltas the single D2H fetch returned.
+        Each delta is read on the thread that moves it, around its half
+        of the wave (``_submit_side`` in ``_prepare``, ``_collect_side``
+        here), so a record holds its own wave's and no other's while the
+        next wave is submitted beside this one's collect."""
+        wave, wave_id = cut.members, cut.wave_id
+        phase_before, leo_before, fb_before, (fused_before, ftiers) = before
+        phase_now, leo_now, fb_now, (fused_now, tiers_now) = _collect_side(
+            self.inner)
+        phase_s, shards, peers = cut.submitted
+        phase_s = dict(phase_s)
+        for k, d in _gained(phase_now, phase_before).items():
+            phase_s[k] = phase_s.get(k, 0.0) + d
+        phase_ms = {k: round(d * 1000.0, 3) for k, d in phase_s.items()}
         waits = sorted(
             (s.t_dispatch - s.t_enq) for s in wave
             if s.t_dispatch is not None
         )
-        phase_after = dict(getattr(inner, "phase_seconds", None) or {})
-        phase_ms = {
-            k: round((phase_after[k] - phase_before.get(k, 0.0)) * 1000.0, 3)
-            for k in phase_after
-            if phase_after[k] - phase_before.get(k, 0.0) > 0
-        }
         # cache hits answer BEFORE admission (they never occupy a slot);
         # the delta since the previous wave is the short-circuit traffic
         # this wave's window interval absorbed
@@ -798,18 +877,13 @@ class CoalescingEngine:
              if s.t_dispatch is not None and s.traceparent is not None),
             key=lambda s: s.t_dispatch - s.t_enq, reverse=True,
         )[:3]
-        fused = dict.fromkeys(_FUSED_COUNTERS.values(), 0)
-        fused["tiers"] = {}
-        if fused_before is not None:
-            before, ftiers = fused_before
-            after, now = _fused_counts(inner)
-            for field in after:
-                fused[field] = max(0, after[field] - before[field])
-            fused["tiers"] = {
-                t: d for t, d in (
-                    (t, int(now[t]) - int(ftiers.get(t, 0))) for t in now
-                ) if d > 0
-            }
+        fused = {
+            field: max(0, fused_now[field] - fused_before[field])
+            for field in fused_now
+        }
+        fused["tiers"] = {
+            t: int(d) for t, d in _gained(tiers_now, ftiers).items()
+        }
         self.ledger.record({
             "wave": wave_id,
             "size": len(wave),
@@ -818,7 +892,7 @@ class CoalescingEngine:
             "block_items": sum(
                 len(s.block) for s in wave if isinstance(s, _ColumnGroup)
             ),
-            "groups": n_groups,
+            "groups": len(cut.groups),
             "window_wait_ms_p50": round(
                 waits[len(waits) // 2] * 1000.0, 3
             ) if waits else 0.0,
@@ -828,16 +902,11 @@ class CoalescingEngine:
             "device_ms": round(device_s * 1000.0, 3),
             "singleflight_collapsed": sum(s.followers for s in wave),
             "cache_hits_since_prev": max(0, hits_delta),
-            "leopard_answered": max(
-                0, int(getattr(inner, "leopard_answered", 0) or 0)
-                - leo_before
-            ),
-            "fallbacks": max(
-                0, int(getattr(inner, "fallbacks", 0) or 0) - fb_before
-            ),
+            "leopard_answered": max(0, leo_now - leo_before),
+            "fallbacks": max(0, fb_now - fb_before),
             "errors": sum(1 for s in wave if s.error is not None),
-            "shards": shards or {},
-            "peers": peers or {},
+            "shards": shards,
+            "peers": peers,
             "fused": fused,
             "phase_ms": phase_ms,
             "slowest": [
@@ -852,8 +921,10 @@ class CoalescingEngine:
 
 
 #: The places a single check waits between its enqueue and its answer
-#: (``_run``, ``_run_dispatch``): pending, the wave the collector holds at
-#: ``_stage.put``, the staged wave, the wave in flight.  Callers that keep
-#: coming fill each with a wave, so a front door has to let in this many
-#: waves' worth of them before a wave can be full (server/daemon.py).
+#: (``_run``, ``_run_dispatch``): pending (not yet cut), the wave the
+#: collector holds at ``_stage.put``, the staged wave, the wave being
+#: collected — the last three already submitted, so already in the
+#: device's queue.  Callers that keep coming fill each with a wave, so a
+#: front door has to let in this many waves' worth of them before a wave
+#: can be full (server/daemon.py).
 PLACES = 4

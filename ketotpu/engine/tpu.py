@@ -69,7 +69,7 @@ from ketotpu.engine.oracle import (
 )
 from ketotpu.engine.snapshot import Snapshot
 from ketotpu.engine.vocab import Vocab
-from ketotpu.engine.wave import Wave, _bucket, _bucket15
+from ketotpu.engine.wave import Ticket, Wave, _bucket, _bucket15
 from ketotpu.leopard import closure as leo
 from ketotpu.leopard import device as leodev
 from ketotpu.leopard import hostlist as leolist
@@ -256,6 +256,7 @@ class DeviceCheckEngine:
         # keto_engine_phase_seconds when a Metrics registry is attached
         self.phase_seconds: dict = {}
         self.phase_counts: dict = {}
+        self._on_thread = threading.local()  # thread_phase_seconds()
         # Leopard closure index (ketotpu/leopard/): rebuilt with the
         # snapshot, folded incrementally from the same changelog as the
         # overlay; None while disabled or stale (everything then serves
@@ -330,11 +331,24 @@ class DeviceCheckEngine:
         where they run (projection build, leopard build) call."""
         self.phase_seconds[name] = self.phase_seconds.get(name, 0.0) + dt
         self.phase_counts[name] = self.phase_counts.get(name, 0) + 1
+        mine = self.thread_phase_seconds()
+        mine[name] = mine.get(name, 0.0) + dt
         if self.metrics is not None:
             self.metrics.observe(
                 "keto_engine_phase_seconds", dt,
                 help="engine phase wall time", phase=name,
             )
+
+    def thread_phase_seconds(self) -> dict:
+        """The calling thread's own share of ``phase_seconds``: what a
+        caller that dispatches beside others (the coalescer's two wave
+        threads, engine/coalesce.py) reads before and after its half of a
+        wave, so that one wave's record holds no other's seconds."""
+        try:
+            return self._on_thread.phases
+        except AttributeError:
+            mine = self._on_thread.phases = {}
+            return mine
 
     def _span(self, name: str, **fields) -> profiler.Span:
         """``with self._span("check_encode", rows=n):`` one engine phase:
@@ -1240,22 +1254,52 @@ class DeviceCheckEngine:
     def batch_check(
         self, queries: Sequence[RelationTuple], rest_depth: int = 0
     ) -> List[bool]:
-        t0 = time.perf_counter()
-        queries = list(queries)
-        chunks = [
-            queries[lo : lo + self.max_batch]
-            for lo in range(0, len(queries), self.max_batch)
-        ]
-        watch = compilewatch.get()
-        compiles_before = watch.compiles_total
+        return self.collect(self.submit(queries, rest_depth))
+
+    def submit(self, queries, rest_depth: int = 0) -> Ticket:
+        """First half of a check: encode ``queries`` (a tuple sequence or
+        a ColumnBlock) and launch them, in chunks of ``max_batch``, without
+        waiting for the device.  Everything is dispatched before anything
+        is synced on, so device executions queue back to back: the chunks
+        of one ticket, and the tickets of a caller that submits the next
+        batch before it collects this one (engine/coalesce.py).  Never
+        raises: a failure rides in the ticket and :meth:`collect` answers
+        for it, so the failure contract has one place."""
+        block = hasattr(queries, "slice")  # engine/columns.py ColumnBlock
+        if not block:
+            queries = list(queries)
+        n = len(queries)
+        ticket = Ticket(queries, rest_depth, time.perf_counter(),
+                        compilewatch.get().compiles_total)
+        for lo in range(0, n, self.max_batch):
+            hi = min(lo + self.max_batch, n)
+            ticket.chunks.append(
+                (lo, queries.slice(lo, hi) if block else queries[lo:hi]))
         try:
-            # dispatch everything before syncing on anything: device
-            # executions queue back-to-back while the host reads earlier
-            # chunks' results
-            waves = [self._dispatch(c, rest_depth) for c in chunks]
-            out: List[bool] = []
-            for c, w in zip(chunks, waves):
-                out.extend(self._finish_chunk(c, w, rest_depth).tolist())
+            for _, c in ticket.chunks:
+                ticket.waves.append(self._dispatch(c, rest_depth))
+        except Exception as e:  # noqa: BLE001 - collect's to handle
+            ticket.failure = e
+        return ticket
+
+    def collect(self, ticket: Ticket, errs: Optional[dict] = None):
+        """Second half: sync each chunk's wave, decode, retry, ask the
+        oracle for the flagged rows, fill the cache.  Returns the verdicts
+        as a list of bool; with ``errs`` (the columnar path's per-item
+        contract: a typed oracle error lands in ``errs[row]`` instead of
+        aborting the batch) ``(allowed bool array, errs)``.  A typed
+        ``KetoAPIError`` passes through (deadline expiry is batch-wide by
+        design); any other exception, in either half, is a device failure
+        and the whole batch is answered on the oracle."""
+        queries, rest_depth = ticket.queries, ticket.rest_depth
+        allowed = np.zeros(len(queries), bool)
+        try:
+            if ticket.failure is not None:
+                raise ticket.failure
+            for (lo, c), w in zip(ticket.chunks, ticket.waves):
+                allowed[lo:lo + len(c)] = self._finish_chunk(
+                    c, w, rest_depth, errs=errs, base=lo
+                )
         except KetoAPIError:
             raise  # typed client errors (and deadline/shed) pass through
         except Exception:  # noqa: BLE001
@@ -1264,11 +1308,14 @@ class DeviceCheckEngine:
             # degraded answer beats an error for every concurrent caller.
             # Health reports ``degraded`` until dispatches stay clean.
             self._device_failure("check dispatch")
-            out = self._serve_batch_on_oracle(queries, rest_depth)
+            if errs is not None:
+                errs.clear()
+            allowed = self._oracle_batch(queries, rest_depth, errs)
         # warm heuristic: consecutive compile-free dispatches mean the
         # steady-state shape set is fully compiled; declare warm so any
         # later compile fires the observatory's after-warm alarm
-        if watch.compiles_total == compiles_before:
+        watch = compilewatch.get()
+        if watch.compiles_total == ticket.compiles_before:
             self._clean_dispatches += 1
             if self._clean_dispatches >= self.warm_after_clean and not watch.warm:
                 watch.declare_warm()
@@ -1276,19 +1323,28 @@ class DeviceCheckEngine:
             self._clean_dispatches = 0
         # RPCs that reach the engine without the coalescer (batch routes)
         # still get a device_compute stage; no-op outside a request context
-        flightrec.note_stage("device_compute", time.perf_counter() - t0)
-        return out
+        flightrec.note_stage("device_compute", time.perf_counter() - ticket.t0)
+        return allowed.tolist() if errs is None else (allowed, errs)
 
-    def _serve_batch_on_oracle(
-        self, queries: Sequence[RelationTuple], rest_depth: int
-    ) -> List[bool]:
+    def _oracle_batch(self, queries, rest_depth: int,
+                      errs: Optional[dict]) -> np.ndarray:
+        """Whole-batch oracle fallback (the device dispatch died); typed
+        errors are captured per item into ``errs`` where the caller gave
+        one (deadline expiry never is), and raised otherwise."""
         t_fb = time.perf_counter()
-        out: List[bool] = []
+        out = np.zeros(len(queries), bool)
         with self._span("check_oracle_fallback", rows=len(queries)):
-            for q in queries:
+            for i in range(len(queries)):
                 deadline.check("oracle fallback")
                 self.fallbacks += 1
-                out.append(bool(self.oracle.check_is_member(q, rest_depth)))
+                try:
+                    out[i] = bool(
+                        self.oracle.check_is_member(queries[i], rest_depth)
+                    )
+                except KetoAPIError as e:
+                    if errs is None or isinstance(e, DeadlineExceededError):
+                        raise
+                    errs[i] = e
         self._rpc_fallback_stage("check", time.perf_counter() - t_fb)
         return out
 
@@ -1338,8 +1394,6 @@ class DeviceCheckEngine:
             )
         answered &= ~(err | general)
         allowed &= answered
-        self.leopard_answered += int(answered.sum())
-        self.leopard_hits += int(allowed.sum())
         return allowed, answered
 
     def _dispatch(self, queries: Sequence[RelationTuple], rest_depth: int,
@@ -1811,6 +1865,8 @@ class DeviceCheckEngine:
         if wave.meta is not None:
             return self._collect_fused(wave)
         n = wave.n
+        if wave.leo_res is not None:
+            self._count_leopard(*wave.leo_res)
         boosted = retry and self.retry_scale > 1
         g_is = np.zeros(n, bool)
         g_fb = np.zeros(n, bool)
@@ -1855,14 +1911,20 @@ class DeviceCheckEngine:
         self._after_collect(wave, allowed, fallback)
         return allowed, fallback
 
+    def _count_leopard(self, allowed, answered) -> None:
+        """Leopard's answers are counted where their wave is collected,
+        whichever launcher ran it: every counter a wave moves after its
+        launch moves on the collecting thread."""
+        self.leopard_answered += int(answered.sum())
+        self.leopard_hits += int(allowed.sum())
+
     def _collect_fused(self, wave):
         """Sync one fused wave: ONE D2H fetch returns the verdict codes
         AND the per-tier attribution masks (engine/fused.py bit layout).
         Decode, feed the occupancy EMAs, update the leopard/retry
-        counters from the returned masks (totals match the unfused
-        dispatch-time increments exactly), and write the decoded
-        leopard answers into the wave so ``_note_tiers`` and
-        ``_cache_fill`` read them like a cascade's."""
+        counters from the returned masks (totals match the cascade's
+        exactly), and write the decoded leopard answers into the wave so
+        ``_note_tiers`` and ``_cache_fill`` read them like a cascade's."""
         meta, n = wave.meta, wave.n
         with self._span("check_collect_sync", rows=n):
             packed = np.asarray(wave.fused)  # the wave's single D2H fetch
@@ -1881,8 +1943,7 @@ class DeviceCheckEngine:
         self.retries += int(bits.retried.sum()) + int(bits.gen_retried.sum())
         if meta["has_leo"]:
             wave.leo_res = (bits.leo_allow, bits.leo_ans)
-            self.leopard_answered += int(bits.leo_ans.sum())
-            self.leopard_hits += int(bits.leo_allow.sum())
+            self._count_leopard(*wave.leo_res)
         # fast_fb is masked to the fast-active rows in-program, which
         # already exclude leopard/cache-answered rows
         allowed, fallback = wv.merge(
@@ -2072,59 +2133,7 @@ class DeviceCheckEngine:
         with per-item error isolation: a typed oracle error lands in the
         erroring row's slot, never aborts the block.  Deadline expiry
         still raises batch-wide (one budget, handler fans out 504s)."""
-        t0 = time.perf_counter()
-        n = len(block)
-        errs: dict = {}
-        if n == 0:
-            return np.zeros(0, bool), errs
-        chunks = [
-            (lo, block.slice(lo, min(lo + self.max_batch, n)))
-            for lo in range(0, n, self.max_batch)
-        ]
-        watch = compilewatch.get()
-        compiles_before = watch.compiles_total
-        allowed = np.zeros(n, bool)
-        try:
-            # same dispatch-all-then-sync pipelining as batch_check
-            waves = [self._dispatch(c, rest_depth) for _, c in chunks]
-            for (lo, c), w in zip(chunks, waves):
-                allowed[lo:lo + len(c)] = self._finish_chunk(
-                    c, w, rest_depth, errs=errs, base=lo
-                )
-        except KetoAPIError:
-            raise  # typed client errors (and deadline/shed) pass through
-        except Exception:  # noqa: BLE001
-            self._device_failure("block check dispatch")
-            errs.clear()
-            allowed = self._oracle_block(block, rest_depth, errs)
-        if watch.compiles_total == compiles_before:
-            self._clean_dispatches += 1
-            if self._clean_dispatches >= self.warm_after_clean and not watch.warm:
-                watch.declare_warm()
-        else:
-            self._clean_dispatches = 0
-        flightrec.note_stage("device_compute", time.perf_counter() - t0)
-        return allowed, errs
-
-    def _oracle_block(self, block, rest_depth: int, errs: dict) -> np.ndarray:
-        """Whole-block oracle fallback (device dispatch died) with the
-        columnar path's per-item error capture."""
-        t_fb = time.perf_counter()
-        out = np.zeros(len(block), bool)
-        with self._span("check_oracle_fallback", rows=len(block)):
-            for i in range(len(block)):
-                deadline.check("oracle fallback")
-                self.fallbacks += 1
-                try:
-                    out[i] = bool(
-                        self.oracle.check_is_member(block[i], rest_depth)
-                    )
-                except DeadlineExceededError:
-                    raise
-                except KetoAPIError as e:
-                    errs[i] = e
-        self._rpc_fallback_stage("check", time.perf_counter() - t_fb)
-        return out
+        return self.collect(self.submit(block, rest_depth), errs={})
 
     # -- Leopard listing APIs ------------------------------------------------
     #

@@ -13,7 +13,7 @@ padded.  No engine and no jax: tests/test_wave.py runs it in milliseconds.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, NamedTuple, Optional, Tuple
+from typing import Any, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -51,6 +51,21 @@ class Wave:
     # mesh only: each row's serving shard; the exchanges with peer hosts
     assign: Optional[np.ndarray] = None
     peers: Optional[dict] = None
+
+
+@dataclasses.dataclass(slots=True)
+class Ticket:
+    """What ``submit`` hands to ``collect``: a batch cut into chunks, each
+    with its launched, uncollected :class:`Wave` (None for no rows), or
+    the failure that stopped the launch, for ``collect`` to answer for."""
+
+    queries: Any  # the whole batch: a tuple list or a ColumnBlock
+    rest_depth: int
+    t0: float  # perf_counter at submit, for the ``device_compute`` stage
+    compiles_before: int  # compilewatch total at submit (warm heuristic)
+    chunks: List[Tuple[int, Any]] = dataclasses.field(default_factory=list)
+    waves: List[Optional[Wave]] = dataclasses.field(default_factory=list)
+    failure: Optional[BaseException] = None
 
 
 # -- padding ------------------------------------------------------------------
