@@ -1548,6 +1548,16 @@ class Registry:
                 help="projection checkpoint save failures")
         m.gauge("keto_engine_dispatches", eng.dispatches,
                 help="device batch dispatches")
+        # how submit cut its batches (engine/wave.py) and what the waves'
+        # capacities left unanswered on the first pass, before any retry
+        m.gauge("keto_engine_tickets_total", eng.tickets,
+                help="batches submitted to the device engine")
+        m.gauge("keto_engine_ticket_waves_total", eng.ticket_waves,
+                help="waves the submitted batches were cut into")
+        for tier, rows in eng.overflow_rows.items():
+            m.gauge("keto_engine_overflow_rows_total", rows,
+                    help="rows a wave's capacity overflowed on, per tier",
+                    tier=tier)
         # fused tiered dispatch (engine/fused.py): whole-cascade waves
         # and per-tier row attribution from the returned device masks
         m.gauge("keto_fused_waves_total", eng.fused_waves,

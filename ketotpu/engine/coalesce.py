@@ -893,6 +893,12 @@ class CoalescingEngine:
                 len(s.block) for s in wave if isinstance(s, _ColumnGroup)
             ),
             "groups": len(cut.groups),
+            # device waves the engine cut the groups' tickets into (one a
+            # group unless a group outgrows the frontier: engine/wave.py)
+            "ticket_waves": sum(
+                len(g.ticket.waves) for g in cut.groups
+                if g.ticket is not None
+            ),
             "window_wait_ms_p50": round(
                 waits[len(waits) // 2] * 1000.0, 3
             ) if waits else 0.0,

@@ -158,7 +158,8 @@ class ColumnBlock:
         return b
 
     def take(self, idx: Sequence[int]) -> "ColumnBlock":
-        """Row subset by index list (handler-side namespace exclusion)."""
+        """Row subset by index list (handler-side namespace exclusion; a
+        wave's rows of a batch the engine cuts, engine/wave.py)."""
         b = ColumnBlock(
             [self.ns[i] for i in idx], [self.obj[i] for i in idx],
             [self.rel[i] for i in idx], [self.skind[i] for i in idx],

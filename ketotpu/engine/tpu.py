@@ -45,7 +45,7 @@ import os
 import sys
 import threading
 import time
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -208,16 +208,12 @@ class DeviceCheckEngine:
         self.occ_headroom = 1.15
         # fused tiered dispatch (engine/fused.py): the whole wave cascade
         # (leopard probe -> fast BFS -> general algebra, with in-program
-        # retry lanes) compiles into ONE device program with ONE D2H
-        # fetch.  The unfused cascade stays as the fallback/oracle path
-        # (flag off, mesh engine, diagnostic surfaces).  The SERVING
-        # default is ON — the driver wires engine.fused_dispatch
-        # (spec/config.schema.json, default true) through the registry;
-        # the constructor default stays off so directly-built engines
-        # (tests, diagnostic tooling, one-shot scripts) keep the
-        # per-tier programs, whose XLA modules compile several times
-        # faster — the fused module's compile cost is superlinear in
-        # its size, prohibitive on XLA:CPU for throwaway engines.
+        # retry lanes) is ONE device program with ONE D2H fetch.  The
+        # unfused cascade stays (flag off, mesh engine, diagnostic
+        # surfaces).  The SERVING default is ON (engine.fused_dispatch,
+        # spec/config.schema.json, wired by the registry); the constructor
+        # default stays off: directly-built engines (tests, tooling) keep
+        # the per-tier programs, which compile several times faster.
         self.fused_dispatch = bool(fused_dispatch)
         self.fused_retry_lanes = max(int(fused_retry_lanes), 0)
         self.fused_waves = 0  # observability: fused waves collected
@@ -235,6 +231,10 @@ class DeviceCheckEngine:
         self.fallbacks = 0  # observability: host-fallback counter
         self.retries = 0  # observability: device-retry (tier-2) counter
         self.rebuilds = 0  # observability: full snapshot rebuilds
+        # tickets submitted, their waves (``_cut``); rows a capacity
+        # left unanswered on a wave's first pass, by tier
+        self.tickets = self.ticket_waves = 0
+        self.overflow_rows = {"fast": 0, "general": 0}
         self.projection_build_s = 0.0  # host-side snapshot build
         self.projection_upload_s = 0.0  # device upload (blocked)
         self._expand_extra = None  # lazily shipped expand tables
@@ -1258,32 +1258,32 @@ class DeviceCheckEngine:
 
     def submit(self, queries, rest_depth: int = 0) -> Ticket:
         """First half of a check: encode ``queries`` (a tuple sequence or
-        a ColumnBlock) and launch them, in chunks of ``max_batch``, without
+        a ColumnBlock) and launch them, cut into the waves the frontier
+        holds (:meth:`_cut`; ``wave.wave_cap`` rows or fewer are one), without
         waiting for the device.  Everything is dispatched before anything
-        is synced on, so device executions queue back to back: the chunks
+        is synced on, so device executions queue back to back: the waves
         of one ticket, and the tickets of a caller that submits the next
         batch before it collects this one (engine/coalesce.py).  Never
         raises: a failure rides in the ticket and :meth:`collect` answers
         for it, so the failure contract has one place."""
-        block = hasattr(queries, "slice")  # engine/columns.py ColumnBlock
-        if not block:
+        if not hasattr(queries, "take"):  # engine/columns.py ColumnBlock
             queries = list(queries)
-        n = len(queries)
         ticket = Ticket(queries, rest_depth, time.perf_counter(),
                         compilewatch.get().compiles_total)
-        for lo in range(0, n, self.max_batch):
-            hi = min(lo + self.max_batch, n)
-            ticket.chunks.append(
-                (lo, queries.slice(lo, hi) if block else queries[lo:hi]))
+        self.tickets += 1
+        cap = min(self.max_batch, wv.wave_cap(
+            lambda q, f, a: fp.level_schedule(q, f, a, self.max_depth),
+            self.frontier, self.arena))
         try:
+            like = self._cut(ticket, cap)
             for _, c in ticket.chunks:
-                ticket.waves.append(self._dispatch(c, rest_depth))
+                ticket.waves.append(self._dispatch(c, rest_depth, None, like))
         except Exception as e:  # noqa: BLE001 - collect's to handle
             ticket.failure = e
         return ticket
 
     def collect(self, ticket: Ticket, errs: Optional[dict] = None):
-        """Second half: sync each chunk's wave, decode, retry, ask the
+        """Second half: sync each wave in turn, decode, retry, ask the
         oracle for the flagged rows, fill the cache.  Returns the verdicts
         as a list of bool; with ``errs`` (the columnar path's per-item
         contract: a typed oracle error lands in ``errs[row]`` instead of
@@ -1296,9 +1296,9 @@ class DeviceCheckEngine:
         try:
             if ticket.failure is not None:
                 raise ticket.failure
-            for (lo, c), w in zip(ticket.chunks, ticket.waves):
-                allowed[lo:lo + len(c)] = self._finish_chunk(
-                    c, w, rest_depth, errs=errs, base=lo
+            for (rows, c), w in zip(ticket.chunks, ticket.waves):
+                allowed[rows] = self._finish_chunk(
+                    c, w, rest_depth, errs=errs, rows=rows
                 )
         except KetoAPIError:
             raise  # typed client errors (and deadline/shed) pass through
@@ -1397,12 +1397,12 @@ class DeviceCheckEngine:
         return allowed, answered
 
     def _dispatch(self, queries: Sequence[RelationTuple], rest_depth: int,
-                  fused: Optional[bool] = None):
-        """Enqueue one chunk's device work; returns the uncollected
-        :class:`Wave`.  ``fused`` overrides the engine flag per call
-        (diagnostic surfaces pin the unfused cascade: its host-side tiers
-        are individually observable).  Every launcher shares the prefix
-        view -> encode -> classify -> Leopard -> cache -> route -> pad."""
+                  fused: Optional[bool] = None, like=(0, 0)):
+        """Enqueue one wave's device work; returns the uncollected
+        :class:`Wave`.  ``fused`` overrides the engine flag per call (the
+        diagnostic surfaces pin the cascade: its tiers show one by one);
+        ``like``: pad as a wave of (rows, general rows) would (``wave.Cut``).
+        Shared prefix: view, encode, classify, Leopard, cache, route, pad."""
         n = len(queries)
         if n == 0:
             return None
@@ -1437,10 +1437,10 @@ class DeviceCheckEngine:
                 active &= ~cache_res[0]
                 general = general & ~cache_res[0]
             wave = Wave(
-                n=n, qpad=wv.wave_rows(n, self.frontier), enc=enc, err=err,
-                general=general, cursor=cursor, arrays=arrays,
-                leo_res=leo_res, cache_res=cache_res,
-            )
+                n=n, qpad=wv.wave_rows(max(n, like[0]), self.frontier),
+                enc=enc, err=err, general=general, cursor=cursor,
+                arrays=arrays, leo_res=leo_res, cache_res=cache_res,
+                gen_like=like[1])
             active = self._route(queries, rest_depth, wave, active)
             padded = self._pad(enc, n, wave.qpad)
             if use_fused:
@@ -1464,13 +1464,13 @@ class DeviceCheckEngine:
         """Enqueue the cascade's tiers; their uncollected results go into
         ``wave``."""
         wave.fast, wave.occ = self._run_fast(wave, padded, active)
-        # the algebra program is overlay-aware (probes consult the om_
-        # delta tables, stale edge rows raise the per-query dirty bit that
-        # routes just those queries to the oracle), so general queries
-        # dispatch on-device even with pending writes
+        # the algebra program is overlay-aware (probes consult the om_ delta
+        # tables; a stale edge row raises its query's dirty bit, which sends
+        # that query to the oracle): general rows dispatch with writes pending
         if wave.general.any():
             wave.gi = np.flatnonzero(wave.general)
-            wave.gen = self._run_general(wave.arrays, wave.enc, wave.gi)
+            arrays, enc, like = wave.arrays, wave.enc, wave.gen_like
+            wave.gen = self._run_general(arrays, enc, wave.gi, like=like)
 
     def _run_fast(self, wave, padded, active, boost: int = 1, rows=None):
         """Enqueue the fast tier for padded rows; returns the uncollected
@@ -1573,15 +1573,15 @@ class DeviceCheckEngine:
             np.pad(leo_set, (0, pad), constant_values=-1),
             np.pad(leo_elt, (0, pad), constant_values=-1),
         ]).astype(np.int32)
-        # tiers the wave doesn't hold compile OUT of the program — XLA
-        # compile cost is superlinear in module size, and an all-fast
-        # wave must not pay for a traced-but-masked general skeleton.
-        # Retry lanes stay in whenever their base tier is in: overflow
-        # is only knowable on device, and the lane firing on zero rows
-        # is free at run time.
+        # the general tier compiles OUT of an all-fast wave (XLA's compile
+        # cost is superlinear in module size: no traced-but-masked
+        # skeleton).  The fast tier stays IN a wave of general rows alone:
+        # a shape has two programs, not three, and a single AND/NOT Check
+        # warms the program that a mixed wave of singles runs.  Retry
+        # lanes stay in with their base tier: overflow shows on device only.
         fast_sched = retry_sched = None
         lanes = 0
-        if fast_elig.any():
+        if fast_elig.any() or general.any():
             fast_sched = fp.level_schedule(
                 qpad, self.frontier, self.arena, self.max_depth, 1,
                 self._adaptive_mults(),
@@ -1596,7 +1596,7 @@ class DeviceCheckEngine:
         # one program a bucket the general count falls into
         gen = gen_retry = None
         n_general = int(general.sum())
-        gen_lanes = wv.general_lanes(n_general, qpad)
+        gen_lanes = wv.general_lanes(n_general, qpad, wave.gen_like)
         if gen_lanes:
             gen = self._gen_schedule(gen_lanes, 1)
             if self.retry_scale > 1 and self.fused_retry_lanes > 0:
@@ -1802,15 +1802,15 @@ class DeviceCheckEngine:
                 else:
                     self._gen_fast_occ_ema = focc
 
-    def _run_general(self, arrays, enc, gi, boost: int = 1):
+    def _run_general(self, arrays, enc, gi, boost: int = 1, like: int = 0):
         """Enqueue ONE fused algebra dispatch for the general (AND/NOT)
         roots — whole-chunk batches, no host round-trips (the round-3
         host-stepped interpreter paid a flags sync per 6 levels and
         ~128-task-slots-per-root sub-batching; VERDICT r3 #1).  Returns an
-        uncollected (codes, occ, n, fast_b); ``boost`` widens
-        every capacity for the retry tier."""
+        uncollected (codes, occ, n, fast_b); ``boost`` widens every
+        capacity for the retry tier; ``like``: ``wave.general_lanes``' own."""
         n = len(gi)
-        qpad = wv.general_lanes(n, self.max_batch)
+        qpad = wv.general_lanes(n, self.max_batch, like)
         genc = self._pad(tuple(a[gi] for a in enc), n, qpad)
         active = np.arange(qpad) < n
         qpack = np.stack([*genc, active.astype(np.int32)]).astype(np.int32)
@@ -1856,12 +1856,11 @@ class DeviceCheckEngine:
         nothing to add."""
 
     def _collect(self, wave, retry: bool = True):
-        """Sync one chunk's results; device-retry the overflow tail of
-        either tier at ``retry_scale``x caps (small batch => ample
-        per-row slots) before any oracle fallback.  Returns (allowed,
-        fallback).  The retry runs against the wave's own device arrays —
-        a write landing between dispatch and retry must not pair these
-        encodings with a newer projection."""
+        """Sync one wave's results; device-retry the overflow tail of
+        either tier (counted first) at ``retry_scale``x caps before any
+        oracle fallback.  Returns (allowed, fallback).  The retry runs
+        against the wave's own device arrays: a write landing meanwhile
+        must not pair these encodings with a newer projection."""
         if wave.meta is not None:
             return self._collect_fused(wave)
         n = wave.n
@@ -1876,6 +1875,7 @@ class DeviceCheckEngine:
                 g = wv.decode_general(np.asarray(codes)[:rows])  # one fetch
                 self._update_gen_occ(self._general_occ(occ), fast_b)
             again = wv.general_retry_rows(g)
+            self.overflow_rows["general"] += int(again.sum())
             if boosted and again.any():
                 ri = wave.gi[again]
                 with self._fetch_span("check_retry", rows=len(ri)):
@@ -1886,12 +1886,12 @@ class DeviceCheckEngine:
                         g, again, wv.decode_general(np.asarray(rcodes)[:k]))
             g_is[wave.gi] = wv.general_allowed(g)
             g_fb[wave.gi] = wv.general_fallback(g)
-
         with self._fetch_span("check_collect_sync"):
             f = self._fast_bits(wave.fast, n)
             if wave.occ is not None:
                 self._update_occ(np.asarray(wave.occ))
         again = ~(wave.err | wave.general) & wv.fast_retry_rows(f)
+        self.overflow_rows["fast"] += int(again.sum())
         if boosted and again.any():
             ri = np.flatnonzero(again)
             k = len(ri)
@@ -1921,10 +1921,9 @@ class DeviceCheckEngine:
     def _collect_fused(self, wave):
         """Sync one fused wave: ONE D2H fetch returns the verdict codes
         AND the per-tier attribution masks (engine/fused.py bit layout).
-        Decode, feed the occupancy EMAs, update the leopard/retry
-        counters from the returned masks (totals match the cascade's
-        exactly), and write the decoded leopard answers into the wave so
-        ``_note_tiers`` and ``_cache_fill`` read them like a cascade's."""
+        Decode, feed the occupancy EMAs, move the leopard, retry and
+        overflow counters by the returned masks, and write the leopard
+        answers into the wave for ``_note_tiers`` and ``_cache_fill``."""
         meta, n = wave.meta, wave.n
         with self._span("check_collect_sync", rows=n):
             packed = np.asarray(wave.fused)  # the wave's single D2H fetch
@@ -1941,11 +1940,12 @@ class DeviceCheckEngine:
             self._update_gen_occ(
                 packed[f_end:f_end + meta["glen"]], meta["gen_fast_b"])
         self.retries += int(bits.retried.sum()) + int(bits.gen_retried.sum())
+        for tier, rows in zip(("fast", "general"), wv.fused_overflowed(bits)):
+            self.overflow_rows[tier] += int(rows.sum())
         if meta["has_leo"]:
             wave.leo_res = (bits.leo_allow, bits.leo_ans)
             self._count_leopard(*wave.leo_res)
-        # fast_fb is masked to the fast-active rows in-program, which
-        # already exclude leopard/cache-answered rows
+        # fast_fb: of the fast-active rows alone (no leopard or cache hit)
         allowed, fallback = wv.merge(
             wave.err, wave.general, wv.general_allowed(bits.general),
             wv.general_fallback(bits.general), bits.found, bits.fast_fb,
@@ -1987,13 +1987,13 @@ class DeviceCheckEngine:
         flightrec.note_tier("fastpath", int(mask.sum()))
 
     def _finish_chunk(
-        self, queries, wave, rest_depth: int, errs=None, base: int = 0
+        self, queries, wave, rest_depth: int, errs=None, rows=None
     ) -> np.ndarray:
-        """Collect one chunk's verdicts as a bool array.  With ``errs``
+        """Collect one wave's verdicts as a bool array.  With ``errs``
         (the columnar path's per-item contract) a typed oracle error is
-        captured into ``errs[base + i]`` instead of aborting the chunk;
-        deadline expiry still propagates — it is batch-wide by design and
-        the handler fans it out as per-item 504s."""
+        captured into ``errs[rows[i]]`` (``rows``: the batch rows the wave
+        holds) instead of aborting it; deadline expiry still propagates:
+        batch-wide by design, the handler fans it out as per-item 504s."""
         if wave is None:
             return np.zeros(0, bool)
         allowed, fallback = self._collect(wave)
@@ -2016,7 +2016,7 @@ class DeviceCheckEngine:
                     except KetoAPIError as e:
                         if errs is None or isinstance(e, DeadlineExceededError):
                             raise
-                        errs[base + int(i)] = e
+                        errs[int(i if rows is None else rows[i])] = e
                         if skip is None:
                             skip = np.zeros(allowed.shape[0], bool)
                         skip[i] = True
@@ -2134,6 +2134,36 @@ class DeviceCheckEngine:
         erroring row's slot, never aborts the block.  Deadline expiry
         still raises batch-wide (one budget, handler fans out 504s)."""
         return self.collect(self.submit(block, rest_depth), errs={})
+
+    def _cut(self, ticket: Ticket, cap: int) -> Tuple[int, int]:
+        """Cut a ticket's batch into the waves ``submit`` launches, of
+        ``cap`` rows at most (``wave.wave_cap`` of this engine's level
+        schedule, under ``max_batch``), by the one rule of engine/wave.py
+        (``cut``): ``ticket.chunks`` gets each wave's batch rows and
+        queries; returns what every wave pads like (``Cut.like``).  A
+        batch that is cut is classified here first, whole, so that its
+        AND/NOT rows can be dealt evenly (each wave is classified again,
+        against the view it is launched on)."""
+        queries, n = ticket.queries, len(ticket.queries)
+        general = None
+        if n > cap:
+            with self._span("check_encode", rows=n):
+                snap = self._sync_view()[0]
+                enc = self._encode(snap, queries, ticket.rest_depth)
+                general = self._classify(snap, enc[0], enc[2])[1]
+        cut = wv.cut(n, general, cap)
+        self.ticket_waves += len(cut.rows)
+        if len(cut.rows) < 2:
+            ticket.chunks = [(rows, queries) for rows in cut.rows]
+            return cut.like
+        lanes = wv.general_lanes(
+            cut.like[1], wv.wave_rows(cut.like[0], self.frontier))
+        take = getattr(queries, "take", None) or (
+            lambda rows: [queries[i] for i in rows])
+        with self._span("check_cut", rows=n, waves=len(cut.rows),
+                        gen_lanes=lanes):
+            ticket.chunks = [(rows, take(rows)) for rows in cut.rows]
+        return cut.like
 
     # -- Leopard listing APIs ------------------------------------------------
     #

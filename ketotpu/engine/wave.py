@@ -13,7 +13,7 @@ padded.  No engine and no jax: tests/test_wave.py runs it in milliseconds.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, List, NamedTuple, Optional, Tuple
+from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -48,6 +48,9 @@ class Wave:
     gen: Optional[tuple] = None
     fused: Any = None
     meta: Optional[dict] = None
+    #: general rows of the widest wave of this wave's ticket
+    #: (``Cut.like``): the general tier pads as if it held them
+    gen_like: int = 0
     # mesh only: each row's serving shard; the exchanges with peer hosts
     assign: Optional[np.ndarray] = None
     peers: Optional[dict] = None
@@ -55,15 +58,17 @@ class Wave:
 
 @dataclasses.dataclass(slots=True)
 class Ticket:
-    """What ``submit`` hands to ``collect``: a batch cut into chunks, each
-    with its launched, uncollected :class:`Wave` (None for no rows), or
-    the failure that stopped the launch, for ``collect`` to answer for."""
+    """What ``submit`` hands to ``collect``: a batch cut into chunks
+    (:func:`cut`), each ``(rows, queries)``: the batch rows a wave holds
+    and those queries; each with its launched, uncollected :class:`Wave`;
+    or the failure that stopped the launch, for ``collect`` to answer for."""
 
     queries: Any  # the whole batch: a tuple list or a ColumnBlock
     rest_depth: int
     t0: float  # perf_counter at submit, for the ``device_compute`` stage
     compiles_before: int  # compilewatch total at submit (warm heuristic)
-    chunks: List[Tuple[int, Any]] = dataclasses.field(default_factory=list)
+    chunks: List[Tuple[np.ndarray, Any]] = dataclasses.field(
+        default_factory=list)
     waves: List[Optional[Wave]] = dataclasses.field(default_factory=list)
     failure: Optional[BaseException] = None
 
@@ -93,22 +98,83 @@ def _bucket15(n: int, floor: int = 64) -> int:
 
 def wave_rows(n: int, frontier: int) -> int:
     """Rows a wave of ``n`` is padded to: pow2 for compile-cache reuse,
-    never beyond the frontier cap (max_batch <= frontier: n fits)."""
+    never beyond the frontier cap.  That ``n`` rows fit a wave is not
+    decided here: a row needs several frontier slots by the deepest BFS
+    level, so ``submit`` cuts a batch by :func:`wave_cap` first."""
     return min(_bucket(n), frontier)
 
 
-def general_lanes(n: int, cap: int) -> int:
+def general_lanes(n: int, cap: int, like: int = 0) -> int:
     """Root lanes of the general tier for ``n`` AND/NOT rows: every buffer
     of the algebra program scales with them, so they pad by half octaves
     (333 rows run in 384 lanes, not the wave's 1024), one program a
     bucket, up to what the launcher holds (``cap``: the wave's rows when
-    fused, ``max_batch`` for a launch of its own).  No rows, no tier."""
-    return min(_bucket15(n, 256), cap) if n else 0
+    fused, ``max_batch`` for a launch of its own).  ``like``: pad as if
+    the wave held that many, the most a wave of its ticket holds, so a
+    ticket's waves run one program.  No rows, no tier."""
+    return min(_bucket15(max(n, like), 256), cap) if n else 0
 
 
 def retry_rows(k: int, cap: int) -> int:
     """Rows the fast tier's retry of ``k`` overflowed rows is padded to."""
     return min(_bucket(k, 256), cap)
+
+
+# -- cutting a batch into waves -----------------------------------------------
+
+
+def wave_cap(schedule: Callable[[int, int, int], tuple], frontier: int,
+             arena: int) -> int:
+    """Most rows of a wave that the frontier holds: the widest padded wave
+    (a power of two, :func:`wave_rows`) whose worst-case level schedule
+    ``schedule(rows, frontier, arena)`` (``fastpath.level_schedule``)
+    neither cap clips at any level.  A wider wave still compiles, but its
+    rows share the deeper levels' slots, overflow, and are answered on
+    the host.  Never under the narrowest wave there is."""
+    free = 1 << 62
+    q = _bucket(1)
+    while schedule(2 * q, frontier, arena) == schedule(2 * q, free, free):
+        q *= 2
+    return q
+
+
+class Cut(NamedTuple):
+    """A batch as the waves of one ticket."""
+
+    #: the batch rows of each wave, ascending
+    rows: List[np.ndarray]
+    #: (rows, general rows) of the widest wave: every wave of the ticket
+    #: pads as if it held them (``wave_rows``, ``general_lanes``), so the
+    #: ticket runs one program; (0, 0): pad by the wave's own
+    like: Tuple[int, int]
+
+
+def _dealt(count: int, k: int) -> np.ndarray:
+    """``count`` things over ``k`` waves, evenly, the first ones one more."""
+    return count // k + (np.arange(k) < count % k)
+
+
+def cut(n: int, general: Optional[np.ndarray], cap: int) -> Cut:
+    """Cut a batch of ``n`` rows into waves of at most ``cap``
+    (:func:`wave_cap`).  Up to ``cap`` rows are one wave, untouched.  A
+    larger batch becomes the fewest waves that hold it, of equal size
+    (one row apart), with the AND/NOT rows (``general``, a mask) dealt
+    evenly too: a wave's program is chosen by its padded rows and its
+    general rows' bucket, and a blind cut leaves a short last wave and a
+    general count that wanders across a bucket's edge, each a program
+    nobody compiled.  Every row is in exactly one wave and keeps its
+    place among that wave's rows, so ``allowed[rows] = verdicts`` restores
+    the request's order."""
+    if n <= cap:
+        return Cut([np.arange(n)] if n else [], (0, 0))
+    k = -(-n // cap)
+    n_general = int(general.sum())
+    of = np.empty(n, np.int64)
+    gens = _dealt(n_general, k)
+    of[general] = np.repeat(np.arange(k), gens)
+    of[~general] = np.repeat(np.arange(k), _dealt(n, k) - gens)
+    return Cut([np.flatnonzero(of == w) for w in range(k)],
+               (-(-n // k), -(-n_general // k)))
 
 
 # -- decoding -----------------------------------------------------------------
@@ -199,6 +265,17 @@ def fast_fallback(f: FastBits) -> np.ndarray:
     """A found verdict stands even when the exploration brushed a dirty
     row or overflowed; anything else that did either is the oracle's."""
     return (f.over | f.dirty) & ~f.found
+
+
+def fused_overflowed(bits: FusedBits) -> Tuple[np.ndarray, np.ndarray]:
+    """(fast rows, general rows) of a fused wave that a capacity left
+    without a verdict on the first pass, as ``fast_retry_rows`` and
+    ``general_retry_rows`` say it of a cascade's: the rows that entered a
+    retry lane, and without lanes the rows still flagged (where a
+    dirty-unfound fast row and a dirty or erring general row are flagged
+    as well: the program returns the first pass folded)."""
+    return (bits.retried | bits.fast_fb,
+            bits.gen_retried | bits.general.over)
 
 
 # -- who answers a row --------------------------------------------------------

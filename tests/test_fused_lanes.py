@@ -207,6 +207,25 @@ def test_general_tier_runs_at_its_rows(world, pinned, monkeypatch, n,
     }
 
 
+def test_a_wave_of_general_rows_alone_keeps_the_fast_tier(world, monkeypatch):
+    """Two programs a shape, not three: the general tier compiles out of
+    an all-fast wave, the fast tier stays in whatever a wave with general
+    rows holds, so one AND/NOT single warms what a mixed wave runs."""
+    _, fused, _, editors = world
+    seen = []
+    monkeypatch.setattr(
+        fused, "_dispatch_fused",
+        lambda wave, g, qpack, scheds: seen.append(scheds))
+    for n_general in (0, 1, 7, 256):
+        queries, _ = seeded_wave(editors, 256, n_general, seed=n_general)
+        fused._dispatch(queries, 0)
+    single, _ = seeded_wave(editors, 1, 1, seed=9)
+    fused._dispatch(single, 0)
+    assert [(s["fast_sched"] is not None, s["gen_lanes"]) for s in seen] == [
+        (True, 0), (True, 256), (True, 256), (True, 256), (True, 256)]
+    assert len({(s["fast_sched"], s["gen"]) for s in seen[1:]}) == 1
+
+
 def test_one_program_for_every_count_in_a_bucket(world, pinned, monkeypatch):
     """300 and 333 general rows of 1024 both pad to 384 lanes: the second
     wave compiles nothing, and the compile scope's text names the size."""
@@ -222,10 +241,16 @@ def test_one_program_for_every_count_in_a_bucket(world, pinned, monkeypatch):
     monkeypatch.setattr(fdx.compilewatch, "scope", spy)
     first, _ = seeded_wave(editors, 1024, 300, seed=1)
     second, _ = seeded_wave(editors, 1024, 333, seed=2)
-    fused.batch_check(first, 0)
+
+    def one_wave(queries):
+        # a wave of its own: ``batch_check`` would cut 1024 rows by what
+        # this file's small frontier holds (engine/wave.py wave_cap)
+        fused._finish_chunk(queries, fused._dispatch(queries, 0), 0)
+
+    one_wave(first)
     before = compilewatch.get().compiles_total
     waves = fused.fused_waves
-    fused.batch_check(second, 0)
+    one_wave(second)
     assert fused.fused_waves == waves + 1
     assert compilewatch.get().compiles_total == before
     assert [fn for fn, _ in texts] == ["fused_wave", "fused_wave"]

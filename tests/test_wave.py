@@ -185,6 +185,106 @@ def test_padding_edges():
         512, 768, 1024, 1536]
 
 
+# -- cutting a batch into waves -----------------------------------------------
+
+
+def _schedule(depth=5):
+    from ketotpu.engine import fastpath as fp
+
+    return lambda q, f, a: fp.level_schedule(q, f, a, depth)
+
+
+@pytest.mark.parametrize("frontier, arena, cap", [
+    (8192, 16384, 1024),   # the daemon's defaults: 6 x 1024 <= 8192
+    (8192, 8192, 512),     # the arena binds: level 3 wants 2 x 6 x 1024
+    (4096, 8192, 512),     # the engine's defaults
+    (2048, 4096, 256),
+    (256, 512, 256),       # never under the narrowest wave
+])
+def test_wave_cap_is_the_widest_wave_no_level_clips(frontier, arena, cap):
+    schedule = _schedule()
+    assert wv.wave_cap(schedule, frontier, arena) == cap
+    free = 1 << 62
+    if cap > 256:
+        assert schedule(cap, frontier, arena) == schedule(cap, free, free)
+    assert schedule(2 * cap, frontier, arena) != schedule(2 * cap, free, free)
+
+
+CAP = 1024
+
+
+@pytest.mark.parametrize("share", [0.0, 0.33, 1.0])
+@pytest.mark.parametrize("n", [1, 700, CAP, CAP + 1, 2 * CAP + 7, 10_000])
+def test_cut(n, share):
+    """Every row in one wave and back in its place, no wave over the cap,
+    one program a ticket, and up to the cap nothing is touched."""
+    rng = np.random.default_rng([n, int(share * 100)])
+    general = rng.random(n) < share
+    cut = wv.cut(n, general, CAP)
+    waves = cut.rows
+    # (a) a partition, each wave ascending: scattering restores the order
+    assert sorted(np.concatenate(waves).tolist()) == list(range(n))
+    assert all((np.diff(w) > 0).all() for w in waves)
+    back = np.full(n, -1)
+    for w in waves:
+        back[w] = w
+    assert back.tolist() == list(range(n))
+    # (b) the fewest waves that hold the batch, none over the cap
+    assert len(waves) == -(-n // CAP)
+    assert max(len(w) for w in waves) <= CAP
+    if n <= CAP:
+        # (d) one wave, in order, padded by its own size: today's wave
+        assert [w.tolist() for w in waves] == [list(range(n))]
+        assert cut.like == (0, 0)
+        return
+    # (c) equal waves, one row and one general row apart ...
+    sizes = [len(w) for w in waves]
+    gens = [int(general[w].sum()) for w in waves]
+    assert max(sizes) - min(sizes) <= 1 and max(gens) - min(gens) <= 1
+    assert cut.like == (max(sizes), max(gens))
+    # ... so, padded like the widest, one program: one wave_rows, and one
+    # general_lanes bucket among the waves that hold a general row
+    rows = {wv.wave_rows(max(k, cut.like[0]), 8192) for k in sizes}
+    lanes = {wv.general_lanes(g, wv.wave_rows(cut.like[0], 8192), cut.like[1])
+             for g in gens if g}
+    assert len(rows) == 1 and len(lanes) <= 1
+    assert (not lanes) == (share == 0.0)
+
+
+def test_cut_pads_a_short_wave_like_the_ticket():
+    """cap + 1 rows are waves of 513 and 512: by their own size two
+    programs (1024 and 512 rows), padded like the widest one."""
+    cut = wv.cut(CAP + 1, np.zeros(CAP + 1, bool), CAP)
+    assert [len(w) for w in cut.rows] == [513, 512]
+    assert [wv.wave_rows(len(w), 8192) for w in cut.rows] == [1024, 512]
+    assert cut.like == (513, 0)
+    # 2561 general rows in ten waves: 257 in one, 256 in nine; by their
+    # own count two buckets (384 and 256 lanes), like the widest one
+    general = np.zeros(10_000, bool)
+    general[:2561] = True
+    cut = wv.cut(10_000, general, CAP)
+    gens = [int(general[w].sum()) for w in cut.rows]
+    assert sorted(set(gens)) == [256, 257] and cut.like == (1000, 257)
+    assert {wv.general_lanes(g, 1024) for g in gens} == {256, 384}
+    assert {wv.general_lanes(g, 1024, cut.like[1]) for g in gens} == {384}
+
+
+def test_cut_of_nothing_is_no_wave():
+    assert wv.cut(0, None, CAP) == wv.Cut([], (0, 0))
+
+
+def test_fused_overflowed_reads_lanes_and_flags():
+    """A row that entered a retry lane overflowed whatever the lane
+    found; without lanes the rows still flagged did."""
+    word = lambda *bits: sum(1 << b for b in bits)  # noqa: E731
+    bits = wv.decode_fused(np.array([
+        word(4), word(4, 8), word(5), word(0), word(0, 9), word(2), 0,
+    ], np.int32))
+    fast, general = wv.fused_overflowed(bits)
+    assert fast.tolist() == [0, 1, 1, 0, 0, 0, 0]
+    assert general.tolist() == [0, 0, 0, 0, 1, 1, 0]
+
+
 # -- structure ----------------------------------------------------------------
 
 
