@@ -1608,6 +1608,26 @@ class Registry:
             m.gauge("keto_projection_overlay_occupancy",
                     ps["overlay_pairs"] / cap,
                     help="overlay pair fill fraction against its threshold")
+            for table, st in ps["tables"].items():
+                m.gauge("keto_projection_table_rounds", st["rounds"],
+                        help="probe rounds a lookup of the table unrolls",
+                        table=table)
+                m.gauge("keto_projection_table_lookup_gathers",
+                        st["lookup_gathers"],
+                        help="element gathers one lookup of the table issues",
+                        table=table)
+                m.gauge("keto_projection_table_tag_salt",
+                        int(np.max(st["tag_salt"])),
+                        help="tag salt index of the table (a mesh: the "
+                             "largest shard's); above 0 the tag invariant "
+                             "walked it",
+                        table=table)
+            for op, times in ps["tag_rejects"].items():
+                m.gauge("keto_projection_tag_rejects_total", times,
+                        help="times two keys of one bucket shared a tag: a "
+                             "build or an overlay build took another tag "
+                             "salt, a splice fell back to a full build",
+                        op=op)
         # demand-adaptive scheduling state: EMA frontier occupancy per BFS
         # level (units of active roots), for the fast path and the general
         # (AND/NOT) tier's skeleton + fast-leaf sub-runs

@@ -37,8 +37,10 @@ from ketotpu.engine.vocab import Interner, Vocab
 #: nodes the folded interpreters would mis-handle; v5: host-side
 #: node_hi/node_lo/mem_node/mem_subj serialize unpadded — a v4
 #: checkpoint's padded columns would break the fold path's exact-length
-#: merges)
-SNAPSHOT_FORMAT = 5
+#: merges; v6: the hash tables store ``tag``/``key_b`` and a tag salt in
+#: ``meta`` (engine/hashtab.py) — a v5 table has ``key_a``/``key_b`` and
+#: no tag column for the lookups to gather)
+SNAPSHOT_FORMAT = 6
 
 _SCALARS = ("num_rels", "n_nodes", "n_edges", "n_tuples", "version")
 _ARRAYS = (

@@ -49,9 +49,13 @@ the only formulation that shards: the target row lives on the owner shard
 of the child's object, so only the owner can probe it.
 
 Kernel strategy (SURVEY §7 step 6, measured on a v5 lite chip): the
-per-level cost is bounded by arena-sized random gathers from HBM tables
-(~6-18 ms per 196k-element gather; probes, scans, scatters and the
-linear-dedup pack measure at noise level beside them).  Pallas/Mosaic
+per-level cost is bounded by random 1-D gathers from HBM tables, and the
+hash probes are most of them: `probe/node_table` and `probe/mem_table`
+hold 76 % of the 1024-row mixed wave's device time and 82 % of the
+singles' (PERF.md §5, traces of PR 30 and PR 32).  A gather costs 12-13 ns
+an element whatever it reads (micro-run, PR 34: 80 us at 6,144 slots, 18
+us at 1,536), so a lookup's time is its gather count times its slots
+(`hashtab.lookup_gathers`).  Pallas/Mosaic
 alternatives were evaluated and rejected with measurements rather than
 assumed: (a) one fused [A,16] row gather — 2.5x SLOWER than 16 separate
 1-D gathers when benchmarked in isolation, while rewriting this module's

@@ -5,7 +5,9 @@ The unfused cascade resolves a wave as host leopard probe ->
 fetch -> optional width-escalation re-runs, each separated by a host
 sync (engine/tpu.py).  Every one of those syncs stalls the host on the
 device and the device on the host, and the three tiers cannot overlap.
-(Not measured on the chip yet: PERF.md.)
+(On the chip the fused wave leaves the device idle 0.09 % of a batch1k
+window; the unfused cascade, which the mesh still runs, 7.9 %: ledger,
+PR 33.)
 
 This module compiles the whole cascade into ONE program:
 

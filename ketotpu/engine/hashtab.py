@@ -13,19 +13,35 @@ from O(log N) to O(1), which matters at the 10M-tuple target.
 Layout (all host-built with vectorized numpy, no per-row Python):
 
 * ``ptr``: int32[buckets+1] CSR over hash buckets,
-* ``key_a`` / ``key_b``: int32[capacity] entries grouped by bucket,
+* ``tag``: int32[capacity] ``key_a ^ f(key_b)``, entries grouped by bucket
+  (``f`` a 32-bit mix with a salt of its own, :func:`_tag_np`),
+* ``key_b``: int32[capacity] the key's second half, in the same order,
 * ``val``: int32[capacity] payload (node ids), optional,
-* ``meta``: int32[2] = (salt index, bucket mask) as device scalars.
+* ``meta``: int32[3] = (salt index, bucket mask, tag salt index) as
+  device scalars.
 
 The build hashes into a fixed 2n-bucket table, walking a salt schedule
 for the flattest distribution; the achieved max-bucket depth is carried in
 the table's ``pw`` array shape and lookups unroll exactly that many probe
 rounds, so device probes never miss a present key.  Keys are non-negative;
 -1 is the empty/pad sentinel and negative queries never match.
+
+A probe round gathers ONE column: ``tag[j]`` against the query's tag.
+After the rounds the key is verified once, at the first tag hit:
+``tag[j] == qtag`` and ``key_b[j] == b`` together give ``key_a[j] == a``
+(the tag is an XOR with ``a``), so ``key_a`` is stored nowhere and a lookup
+of a ``P``-round table issues ``1 + P + 1`` gathers and one more for a
+payload (:func:`lookup_gathers`), where two key columns cost ``1 + 2P``.
+The first hit is the key's own entry because of a build invariant, not a
+probability: **no two entries of one bucket with different keys share a
+tag** (:func:`_tag_clash`).  ``build_table`` walks the tag salt when a
+table breaks it, ``splice_table`` declines the edit; entries of following
+buckets and pads may share the query's tag and fail the verify.
 """
 
 from __future__ import annotations
 
+import threading
 from typing import Dict, Optional, Tuple
 
 import numpy as np
@@ -40,11 +56,14 @@ PROBE_SHALLOW = 4  # for small side tables on hot probe paths (delta overlay)
 # are fixed at 2x entries (forcing max-bucket <= 4 at the 10M-entry scale
 # needs ~32x-entry bucket arrays and dozens of multi-GB hash/bincount
 # passes — measured as the dominant cost of a 10M projection — and every
-# bucket is 4 bytes of ptr array uploaded over a ~20-40MB/s link, while
-# extra probe rounds measured ~free on-chip).  The salt schedule picks
-# the flattest distribution and the achieved depth rides in the table's
-# `pw` array SHAPE, so jitted lookups unroll exactly that many rounds
-# (shape changes recompile naturally).
+# bucket is 4 bytes of ptr array in HBM).  A probe round is NOT free on
+# the chip: the two tables' probes are 76 % of the 1024-row mixed wave's
+# device time and 82 % of the singles' (PERF.md §5, traces of PR 30 and
+# PR 32), at a deepest bucket of 8-9 for 10M keys; bounding the depth is
+# ROADMAP.md queue 3 item 9.  The salt schedule picks the flattest
+# distribution and the achieved depth rides in the table's `pw` array
+# SHAPE, so jitted lookups unroll exactly that many rounds (shape changes
+# recompile naturally).
 SNAPSHOT_PROBE = 4
 
 def subtables(g, prefix):
@@ -91,6 +110,64 @@ def mix_device(a, b, salt):
     h = h * jnp.uint32(0xC2B2AE3D)
     h = h ^ (h >> jnp.uint32(13))
     return h
+
+
+def _fmix(f, u):
+    """murmur3's 32-bit finalizer over a uint32 lattice: a bijection, so
+    two different ``b`` never share an ``f(b)``.  ``u`` casts the constants
+    (``np.uint32`` on the host, ``jnp.uint32`` on the device)."""
+    f = (f ^ (f >> u(16))) * u(0x85EBCA6B)
+    f = (f ^ (f >> u(13))) * u(0xC2B2AE35)
+    return f ^ (f >> u(16))
+
+
+def _tag_np(a: np.ndarray, b: np.ndarray, salt: np.uint32) -> np.ndarray:
+    """An entry's (or a query's) tag, ``a ^ f(b + salt)``, as int32.  The
+    XOR makes the verify one gather: equal tags and equal ``b`` are equal
+    ``a``.  A mix of its own: the bucket hash shares no constant with it."""
+    f = _fmix(b.astype(np.uint32) + salt, np.uint32)
+    return (a.astype(np.uint32) ^ f).view(np.int32)
+
+
+def tag_device(a, b, salt):
+    """:func:`_tag_np` for jnp arrays (int32 in, int32 out)."""
+    import jax
+    import jax.numpy as jnp
+
+    f = _fmix(b.astype(jnp.uint32) + salt.astype(jnp.uint32), jnp.uint32)
+    return jax.lax.bitcast_convert_type(a.astype(jnp.uint32) ^ f, jnp.int32)
+
+
+#: times the tag invariant refused a table: a ``build`` or an ``overlay``
+#: (fixed-shape) build walked to another tag salt, a ``splice`` fell back
+#: to a full build (scrape: ``keto_projection_tag_rejects_total{op}``)
+TAG_REJECTS = {"build": 0, "splice": 0, "overlay": 0}
+_TAG_REJECTS_LOCK = threading.Lock()  # builds run on the compactor's thread too
+
+
+def _tag_reject(op: str) -> None:
+    with _TAG_REJECTS_LOCK:
+        TAG_REJECTS[op] += 1
+
+
+def _tag_clash(ptr, tag, key_b, n: int, depth: int) -> bool:
+    """True when two of the first ``n`` entries sit in one bucket (none
+    deeper than ``depth``) with one tag and different keys: the layout's
+    one invariant, without which a lookup's first tag hit could be
+    another key's entry.  Equal tags with equal ``key_b`` are the same
+    key (duplicates are allowed).  One compare pass a distance; equal
+    32-bit tags that close are rare (or duplicates), so the bucket test
+    runs on a handful of positions."""
+    for d in range(1, min(depth, n)):
+        at = np.flatnonzero(tag[d:n] == tag[: n - d])
+        at = at[key_b[at] != key_b[at + d]]
+        # the CSR position's bucket: the last ptr at or before it
+        if at.size and (
+            np.searchsorted(ptr, at, side="right")
+            == np.searchsorted(ptr, at + d, side="right")
+        ).any():
+            return True
+    return False
 
 
 def _bincount(h: np.ndarray, buckets: int) -> np.ndarray:
@@ -160,11 +237,12 @@ def build_table(
     min_buckets: int = 128,
     # lean tables allocate ~n buckets instead of ~2n: at the 10M-tuple
     # scale the bucket POINTER array alone is 134MB of device upload
-    # (and HBM) per table, while the deeper buckets only add probe
-    # rounds — measured ~free on this path (r3: ablating all hash probes
-    # changed per-level time by ~0).  Pair with a probe bound the higher
-    # load factor can satisfy on the first salt, or the build burns the
-    # whole salt schedule (a bincount+mix per salt) before settling.
+    # (and HBM) per table; the price is deeper buckets, one more tag
+    # gather a round in every lookup (rounds 8-9 at 10M keys: the probes
+    # hold three quarters of a wave, PERF.md §5).  Pair with a probe bound
+    # the higher load factor can satisfy on the first salt, or the build
+    # burns the whole salt schedule (a bincount+mix per salt) before
+    # settling.
     lean: bool = False,
     probe: int = PROBE,
     fixed_shape: Optional[Tuple[int, int]] = None,
@@ -198,8 +276,8 @@ def build_table(
     # TARGET for every salt (they all draw from the same distribution), so
     # walking the schedule is mix+bincount passes over multi-GB arrays
     # just to settle for salt 0's depth anyway — big tables take the first
-    # salt's achieved depth immediately (lookups pay ~1 extra probe round,
-    # measured ~free on-chip).  Small and fixed-shape tables keep the full
+    # salt's achieved depth immediately (lookups pay ~1 extra probe round:
+    # one tag gather a lookup).  Small and fixed-shape tables keep the full
     # schedule (there a lucky salt genuinely changes the shape/fit).
     max_salts = (
         len(_SALTS) if n <= (1 << 20) or fixed_shape is not None else 1
@@ -241,6 +319,7 @@ def build_table(
 
                 parallel.shard_apply(n, _rehash)
             break
+    depth = probe_eff  # the deepest bucket, before any pinning
     if n <= 512 and fixed_shape is None:
         # pin the probe depth (== the pw array SHAPE) for small tables:
         # the achieved max-bucket is data-dependent (1 vs 2 vs 3 on a few
@@ -254,24 +333,37 @@ def build_table(
     # empty + range fills instead of full(-1) + overwrite: one write pass
     # over the entry region instead of two (real at 10M+ rows), and the
     # gather through ``order`` shards across cores when the host has them
-    ta = np.empty(cap, np.int32)
+    tt = np.empty(cap, np.int32)
     tb = np.empty(cap, np.int32)
-
-    def _fill(lo, hi):
-        seg = order[lo:hi]
-        ta[lo:hi] = key_a[seg]
-        tb[lo:hi] = key_b[seg]
-
-    parallel.shard_apply(n, _fill)
-    ta[n:] = -1
+    tt[n:] = -1
     tb[n:] = -1
     ptr = np.zeros(buckets + 1, np.int32)
     np.cumsum(counts, out=ptr[1:])
+    # the tag column, and the walk of its salt: at random keys a bucket
+    # holds two keys of one tag once in a thousand 10M-entry tables, so
+    # the second pass is a guard; it rehashes nothing but the tags
+    tag_i = 0
+    while True:
+        def _fill(lo, hi, _s=_SALTS[tag_i]):
+            seg = order[lo:hi]
+            b = key_b[seg]
+            tb[lo:hi] = b
+            tt[lo:hi] = _tag_np(key_a[seg], b, _s)
+
+        parallel.shard_apply(n, _fill)
+        if not _tag_clash(ptr, tt, tb, n, depth):
+            break
+        _tag_reject("overlay" if fixed_shape is not None else "build")
+        tag_i += 1
+        if tag_i == len(_SALTS):
+            raise ValueError(
+                f"no tag salt keeps {n} entries in {buckets} buckets apart"
+            )
     out = {
         "ptr": ptr,
-        "key_a": ta,
+        "tag": tt,
         "key_b": tb,
-        "meta": np.array([salt_i, buckets - 1], np.int32),
+        "meta": np.array([salt_i, buckets - 1, tag_i], np.int32),
         # probe depth as SHAPE: jitted lookups read it statically at trace
         # time, so a table that settled for a deeper bound (or achieved a
         # shallower one) unrolls exactly the right number of rounds with
@@ -311,12 +403,14 @@ def splice_table(
     Returns None when the edit cannot keep that shape contract — more
     entries than capacity, a bucket growing past the recorded probe
     rounds, or a removal key that is not resident (inconsistent caller
-    bookkeeping).  The caller falls back to a full ``build_table``.
+    bookkeeping) — or when an insert would put two keys of one tag into
+    a bucket (the tag salt is the table's, so it cannot be walked here).
+    The caller falls back to a full ``build_table``.
     """
     salt_i = int(t["meta"][0])
     mask = np.uint32(int(t["meta"][1]))
     buckets = int(mask) + 1
-    cap = len(t["key_a"])
+    cap = len(t["key_b"])
     pw = t["pw"].shape[0]
     ptr = t["ptr"]
     n_old = int(ptr[-1])
@@ -325,7 +419,8 @@ def splice_table(
     if n_new > cap:
         return None
     salt = _SALTS[salt_i]
-    ka, kb = t["key_a"], t["key_b"]
+    tag_salt = _SALTS[int(t["meta"][2])]
+    tg, kb = t["tag"], t["key_b"]
 
     if n_rm:
         h_rm = (
@@ -333,13 +428,14 @@ def splice_table(
         ).astype(np.int64)
         del_pos = np.empty(n_rm, np.int64)
         used: set = set()
-        rm_a_l = np.asarray(rm_a).tolist()
+        # a resident key is its tag and its second half (module docstring)
+        rm_t_l = _tag_np(np.asarray(rm_a), np.asarray(rm_b), tag_salt).tolist()
         rm_b_l = np.asarray(rm_b).tolist()
         for i in range(n_rm):
             b = int(h_rm[i])
             found = -1
             for j in range(int(ptr[b]), int(ptr[b + 1])):
-                if j not in used and ka[j] == rm_a_l[i] and kb[j] == rm_b_l[i]:
+                if j not in used and tg[j] == rm_t_l[i] and kb[j] == rm_b_l[i]:
                     found = j
                     break
             if found < 0:
@@ -373,23 +469,26 @@ def splice_table(
     # bucket is free — lookups scan the whole bucket
     order = np.argsort(h_add, kind="stable")
     ins_pos = ptr_mid[h_add[order]]
-    a_body = np.insert(ka[:n_old][body_sel], ins_pos,
-                       np.asarray(add_a, np.int32)[order])
-    b_body = np.insert(kb[:n_old][body_sel], ins_pos,
-                       np.asarray(add_b, np.int32)[order])
+    add_b = np.asarray(add_b, np.int32)[order]
+    t_body = np.insert(tg[:n_old][body_sel], ins_pos,
+                       _tag_np(np.asarray(add_a)[order], add_b, tag_salt))
+    b_body = np.insert(kb[:n_old][body_sel], ins_pos, add_b)
     cum_add = np.zeros(buckets + 1, np.int64)
     np.cumsum(add_per_bucket, out=cum_add[1:])
     ptr_new = (ptr_mid + cum_add).astype(np.int32)
 
-    out_a = np.empty(cap, np.int32)
-    out_a[:n_new] = a_body
-    out_a[n_new:] = -1
+    out_t = np.empty(cap, np.int32)
+    out_t[:n_new] = t_body
+    out_t[n_new:] = -1
     out_b = np.empty(cap, np.int32)
     out_b[:n_new] = b_body
     out_b[n_new:] = -1
+    if n_add and _tag_clash(ptr_new, out_t, out_b, n_new, pw):
+        _tag_reject("splice")
+        return None
     out = {
         "ptr": ptr_new,
-        "key_a": out_a,
+        "tag": out_t,
         "key_b": out_b,
         "meta": t["meta"],
         "pw": t["pw"],
@@ -417,9 +516,11 @@ def lookup_np(t: Dict, a: np.ndarray, b: np.ndarray) -> Tuple:
     One vectorized probe over a whole query column — the columnar batch
     decode uses this to encode request strings to vocabulary ids without
     a per-item Python dict walk.  Semantics match the device probe
-    exactly: negative queries never match, probing past a bucket's end
-    is safe (CSR-contiguous entries of other buckets can never equal the
-    query key), and the round count comes from the ``pw`` shape."""
+    exactly: negative queries never match, the rounds compare tags and
+    the key is verified once at the first tag hit, probing past a
+    bucket's end is safe (a CSR-contiguous entry of another bucket that
+    shares the tag fails the verify), and the round count comes from the
+    ``pw`` shape."""
     probe = t["pw"].shape[0] if "pw" in t else PROBE
     salt = _SALTS[min(int(t["meta"][0]), len(_SALTS) - 1)]
     mask = np.uint32(int(t["meta"][1]))
@@ -427,16 +528,19 @@ def lookup_np(t: Dict, a: np.ndarray, b: np.ndarray) -> Tuple:
     b = np.asarray(b)
     h = (_mix_np(a, b, salt) & mask).astype(np.int64)
     base = t["ptr"][h].astype(np.int64)
-    ka, kb = t["key_a"], t["key_b"]
-    cap = ka.shape[0]
-    ok = (a >= 0) & (b >= 0)
-    found = np.zeros(a.shape, bool)
+    tg, kb = t["tag"], t["key_b"]
+    cap = kb.shape[0]
+    qtag = _tag_np(a, b, _SALTS[min(int(t["meta"][2]), len(_SALTS) - 1)])
+    # the tag reads a's low 32 bits: a wider query is no int32 key
+    ok = (a >= 0) & (b >= 0) & (a <= np.iinfo(np.int32).max)
+    seen = np.zeros(a.shape, bool)
     res_j = np.zeros(a.shape, np.int64)
     for i in range(probe):
         j = np.minimum(base + i, cap - 1)
-        hit = ok & (ka[j] == a) & (kb[j] == b)
-        res_j = np.where(hit & ~found, j, res_j)
-        found |= hit
+        hit = tg[j] == qtag
+        res_j = np.where(hit & ~seen, j, res_j)
+        seen |= hit
+    found = ok & seen & (kb[res_j] == b)
     vals = t.get("val")
     payload = vals[res_j] if vals is not None else res_j
     return np.where(found, payload, -1).astype(np.int32), found
@@ -450,7 +554,8 @@ def lookup(t: Dict, a, b, *, probe: int = PROBE) -> Tuple:
     flow, safe anywhere in a jitted program.  The round count comes from
     the table's own ``pw`` shape when present (the build records the
     achieved max-bucket bound there); ``probe`` is the fallback for
-    tables predating it.
+    tables predating it.  A round gathers the tag column alone; the key
+    is verified once, at the first tag hit (:func:`lookup_gathers`).
     """
     import jax.numpy as jnp
 
@@ -458,26 +563,49 @@ def lookup(t: Dict, a, b, *, probe: int = PROBE) -> Tuple:
         probe = t["pw"].shape[0]
     salt = t["meta"][0]
     mask = t["meta"][1]
-    salt_v = jnp.asarray(_SALTS, np.uint32)[jnp.clip(salt, 0, len(_SALTS) - 1)]
+    salts = jnp.asarray(_SALTS, np.uint32)
+    salt_v = salts[jnp.clip(salt, 0, len(_SALTS) - 1)]
     h = (mix_device(a, b, salt_v) & mask.astype(jnp.uint32)).astype(jnp.int32)
     base = t["ptr"][h]
-    cap = t["key_a"].shape[0]
-    ok = (a >= 0) & (b >= 0)
-    found = jnp.zeros(jnp.shape(a), bool)
+    cap = t["tag"].shape[0]
+    qtag = tag_device(a, b, salts[jnp.clip(t["meta"][2], 0, len(_SALTS) - 1)])
+    seen = jnp.zeros(jnp.shape(a), bool)
     res_j = jnp.zeros(jnp.shape(a), jnp.int32)
     vals = t.get("val", None)
     # No bucket-length check: entries are CSR-contiguous, so probing past
-    # the bucket's end reads entries of FOLLOWING buckets (or -1 padding) —
-    # and an entry of another bucket can never equal the query key, because
-    # an equal key hashes to the query's own bucket.  Dropping the check
-    # removes the ptr[h+1] gather and the per-round bound test from the
-    # hottest gather site in the engine.
+    # the bucket's end reads entries of FOLLOWING buckets (or -1 padding).
+    # The key's own bucket comes first and holds no other key of its tag
+    # (the build invariant), so the first tag hit is the key's entry
+    # whenever the key is present; a later entry or a pad that shares the
+    # tag is met only by an absent key, and fails the verify.  Dropping
+    # the check removes the ptr[h+1] gather and the per-round bound test
+    # from the hottest gather site in the engine.
     for i in range(probe):
         j = jnp.clip(base + i, 0, cap - 1)
-        hit = ok & (t["key_a"][j] == a) & (t["key_b"][j] == b)
-        res_j = jnp.where(hit & ~found, j, res_j)
-        found = found | hit
+        hit = t["tag"][j] == qtag
+        res_j = jnp.where(hit & ~seen, j, res_j)
+        seen = seen | hit
+    # the key, verified once: tag and key_b equal give key_a equal
+    found = seen & (a >= 0) & (b >= 0) & (t["key_b"][res_j] == b)
     # one payload gather at the matched index instead of one per round:
     # each avoided gather is a real cost at arena-sized call sites
     payload = vals[res_j] if vals is not None else res_j
     return jnp.where(found, payload, -1), found
+
+
+def lookup_gathers(t: Dict) -> int:
+    """Element gathers one :func:`lookup` of ``t`` issues: ``ptr``, a tag
+    a round, the verify, and the payload where the table has one (the
+    lowered program is held to it in ``tests/test_hashtab.py``)."""
+    return 1 + t["pw"].shape[-1] + 1 + ("val" in t)
+
+
+def table_stats(t: Dict) -> Dict:
+    """What ``/debug/projection`` shows of a table (host or device
+    arrays; a mesh's stack gives one tag salt a shard)."""
+    tag_salt = np.asarray(t["meta"])[..., 2]
+    return {
+        "rounds": int(t["pw"].shape[-1]),
+        "lookup_gathers": lookup_gathers(t),
+        "tag_salt": tag_salt.tolist(),
+    }

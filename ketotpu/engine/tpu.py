@@ -61,6 +61,7 @@ from ketotpu.engine import algebra as alg
 from ketotpu.engine import delta as dl
 from ketotpu.engine import fastpath as fp
 from ketotpu.engine import fused as fdx
+from ketotpu.engine import hashtab
 from ketotpu.engine import wave as wv
 from ketotpu.engine.oracle import (
     DEFAULT_MAX_DEPTH,
@@ -913,7 +914,8 @@ class DeviceCheckEngine:
             pairs, dirty = (
                 self._overlay.size() if self._overlay is not None else (0, 0)
             )
-            return {
+            arrays = self._served_arrays() or {}
+            out = {
                 "generation": self.generation,
                 "rebuilds": self.rebuilds,
                 "folds": self.folds,
@@ -943,7 +945,23 @@ class DeviceCheckEngine:
                     k: round(v, 6)
                     for k, v in self.last_build_phases.items()
                 },
+                "tag_rejects": dict(hashtab.TAG_REJECTS),
             }
+        # the served hash tables, as the device programs unroll them
+        # (engine/hashtab.py): probe rounds, gathers a lookup, and the tag
+        # salt (above 0: the invariant walked it).  Read off the lock: the
+        # salt is a fetch from the device
+        out["tables"] = {
+            p: hashtab.table_stats(hashtab.subtables(arrays, p + "_"))
+            for p in ("nt", "mt", "ovt", "om")
+            if p + "_meta" in arrays
+        }
+        return out
+
+    def _served_arrays(self):
+        """The device-array dict the check programs are served from (the
+        mesh engine overrides: its sharded stacks)."""
+        return self._device_arrays
 
     def _sync_view(self):
         """Atomic (snapshot, device_arrays, cursor) view.

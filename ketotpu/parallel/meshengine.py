@@ -413,6 +413,9 @@ class MeshCheckEngine(DeviceCheckEngine):
             self._mesh_run_lock.release()
         return out
 
+    def _served_arrays(self):
+        return self._stacked
+
     def _sync_view(self):
         """The base's atomic view, with the sharded stacks for device
         arrays.  The stamp is the DRAIN cursor where the base takes the

@@ -238,9 +238,20 @@ def test_projection_metric_vocabulary(scrape):
         "keto_projection_overlay_pairs",
         "keto_projection_overlay_occupancy",
         "keto_projection_phase_seconds",
+        # PR 34: the served hash tables and the tag invariant's counter
+        'keto_projection_table_rounds{table="nt"}',
+        'keto_projection_table_lookup_gathers{table="mt"}',
+        'keto_projection_table_tag_salt{table="ovt"}',
+        'keto_projection_tag_rejects_total{op="splice"}',
     ):
         assert g in text, g
     proj = scrape["projection"]
+    assert set(proj["tables"]) == {"nt", "mt", "ovt", "om"}
+    assert all(
+        t["lookup_gathers"] <= t["rounds"] + 3 and t["tag_salt"] == 0
+        for t in proj["tables"].values()
+    )
+    assert set(proj["tag_rejects"]) == {"build", "splice", "overlay"}
     assert proj["generation"] >= 1
     assert proj["rebuilds"] >= 1  # the boot projection
     assert proj["served_cursor"] == proj["log_cursor"]

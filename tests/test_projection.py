@@ -41,14 +41,18 @@ CMP = (
 
 def _host_lookup(t, a, b):
     """Host-side replica of the device probe: same salt/mask bucketing,
-    linear scan within the bucket."""
+    linear scan within the bucket (an entry's key is its tag and its
+    second half: ``tag = key_a ^ f(key_b)``)."""
     salt = hashtab._SALTS[int(t["meta"][0])]
     mask = np.uint32(int(t["meta"][1]))
     h = int(hashtab._mix_np(np.array([a]), np.array([b]), salt)[0] & mask)
+    tag = int(hashtab._tag_np(
+        np.array([a]), np.array([b]), hashtab._SALTS[int(t["meta"][2])]
+    )[0])
     lo, hi = int(t["ptr"][h]), int(t["ptr"][h + 1])
     assert hi - lo <= t["pw"].shape[0], "bucket deeper than probe depth"
     for j in range(lo, hi):
-        if t["key_a"][j] == a and t["key_b"][j] == b:
+        if t["tag"][j] == tag and t["key_b"][j] == b:
             return True, int(t["val"][j]) if "val" in t else -1
     return False, -1
 
