@@ -1392,6 +1392,10 @@ class Registry:
                     "resumed" if resumed else "stale/absent, will refresh",
                 )
             eng.snapshot()
+        # a bulk-loaded store's own indexes, before anything queries it
+        build_indexes = getattr(self.store(), "build_indexes", None)
+        if build_indexes is not None:
+            build_indexes()
         # arm the fleet health plane: the SLO engine pre-registers its
         # gauge vocabulary, the watchdog starts its rule-evaluation loop
         self.slo()
@@ -1568,10 +1572,22 @@ class Registry:
                 help="fused-wave rows that needed the general tier")
         m.gauge("keto_fused_general_lanes_total", eng.fused_general_lanes,
                 help="lanes the fused waves ran the general tier at")
+        for table, gathers in eng.fused_probe_gathers.items():
+            m.gauge("keto_fused_probe_gathers_total", gathers,
+                    help="element gathers one lookup of the table cost, "
+                         "added a fused wave at its collect (over "
+                         "keto_fused_waves_total: gathers a lookup)",
+                    table=table)
         for tier, rows in eng.fused_tier_rows.items():
             m.gauge("keto_fused_tier_rows_total", rows,
                     help="fused-wave rows attributed per answering tier",
                     tier=tier)
+        for what, seconds in hostwaits.LAZY_BUILD_SECONDS.items():
+            m.gauge("keto_host_lazy_build_seconds_total", seconds,
+                    help="seconds spent building a host index on first use "
+                         "(the vocabulary's bulk form, the store's forward "
+                         "index), in whatever thread met it first",
+                    what=what)
         m.gauge("keto_engine_projection_build_seconds",
                 eng.projection_build_s,
                 help="host-side snapshot projection build wall time")
@@ -1622,6 +1638,13 @@ class Registry:
                              "largest shard's); above 0 the tag invariant "
                              "walked it",
                         table=table)
+            for group, sizes in ps["device_bytes"].items():
+                for kind, nbytes in sizes.items():
+                    m.gauge("keto_projection_device_bytes", nbytes,
+                            help="device bytes of the served projection by "
+                                 "group of arrays, reckoned from its counts: "
+                                 "padded as built, live at exact lengths",
+                            group=group, kind=kind)
             for op, times in ps["tag_rejects"].items():
                 m.gauge("keto_projection_tag_rejects_total", times,
                         help="times two keys of one bucket shared a tag: a "

@@ -45,7 +45,7 @@ import numpy as np
 
 from ketotpu.api.types import RelationTuple, SubjectSet
 from ketotpu.engine import hashtab, parallel
-from ketotpu.engine.snapshot import Snapshot, _bucket
+from ketotpu.engine.snapshot import Snapshot, _bucket, check_caps
 from ketotpu.engine.vocab import Vocab
 
 _I32MAX = np.iinfo(np.int32).max
@@ -64,6 +64,9 @@ class TupleColumns:
         for c in self.COLS:
             setattr(self, c, np.full(self.cap, -1, np.int32))
         self.alive = np.zeros(self.cap, bool)
+        # False while the id columns are another owner's arrays
+        # (from_arrays, freeze, masked): nothing may be written into them
+        self._owned = True
         # tuple identity (vocab id 4-tuple) -> alive row indices (FIFO
         # delete order parity with the store's seq-ordered removal).
         # None = lazy: bulk-adopted columns skip the per-row dict build
@@ -75,22 +78,21 @@ class TupleColumns:
         cls, vocab: Vocab, cols: Dict[str, np.ndarray], alive: np.ndarray
     ) -> "TupleColumns":
         """Adopt pre-built id columns (a columnar store's base segment)
-        without any per-row Python — the row-key index is lazy."""
+        without any per-row Python and without a copy: the columns ARE
+        the store's arrays, which it never writes (a delete flips its
+        alive bitmap), borrowed at their exact length.  The first append
+        grows into arrays of this mirror's own, a compaction copies; a
+        padded copy of eight columns is 8.6 GB at 150M rows.  The
+        row-key index is lazy."""
         self = cls.__new__(cls)
         self.vocab = vocab
         n = int(len(alive))
-        cap = 1024
-        while cap < max(n, 1):
-            cap *= 2
-        self.cap = cap
-        self.n = n
+        self.cap = self.n = n
         for c in cls.COLS:
-            arr = np.full(cap, -1, np.int32)
-            arr[:n] = cols[c][:n]
-            setattr(self, c, arr)
-        self.alive = np.zeros(cap, bool)
-        self.alive[:n] = alive[:n]
-        self.alive_count = int(self.alive[:n].sum())
+            setattr(self, c, np.asarray(cols[c][:n], np.int32))
+        self._owned = False
+        self.alive = np.array(alive[:n], bool)
+        self.alive_count = int(self.alive.sum())
         self._rows_by_key = None
         return self
 
@@ -147,6 +149,7 @@ class TupleColumns:
         out.alive[: self.n] &= keep_rows[: self.n]
         out.alive_count = int(out.alive[: self.n].sum())
         out._rows_by_key = None
+        out._owned = False
         return out
 
     def freeze(self) -> "TupleColumns":
@@ -167,6 +170,7 @@ class TupleColumns:
         out.alive = self.alive[: self.n].copy()
         out.alive_count = int(out.alive.sum())
         out._rows_by_key = None
+        out._owned = False
         return out
 
     def _key_ids(self, t: RelationTuple) -> Optional[Tuple]:
@@ -195,7 +199,8 @@ class TupleColumns:
         self._rows_by_key = idx
 
     def _grow(self) -> None:
-        new_cap = self.cap * 2
+        new_cap = max(self.cap * 2, 1024)
+        self._owned = True  # the grown arrays are this mirror's own
         for c in self.COLS:
             arr = getattr(self, c)
             grown = np.full(new_cap, -1, np.int32)
@@ -252,8 +257,14 @@ class TupleColumns:
         keep = np.flatnonzero(self.alive[: self.n])
         for c in self.COLS:
             arr = getattr(self, c)
-            arr[: len(keep)] = arr[keep]
+            if not self._owned:  # borrowed: compact into a copy
+                arr = np.empty(self.cap, np.int32)
+                arr[: len(keep)] = getattr(self, c)[keep]
+                setattr(self, c, arr)
+            else:
+                arr[: len(keep)] = arr[keep]
             arr[len(keep):] = -1
+        self._owned = True
         self.alive[: len(keep)] = True
         self.alive[len(keep):] = False
         self.n = len(keep)
@@ -277,6 +288,7 @@ def build_snapshot_cols(
     strict: bool = False,
     version: int = -1,
     phases: Optional[Dict[str, float]] = None,
+    table_sink=None,
 ) -> Snapshot:
     """Vectorized snapshot build from the column cache.
 
@@ -288,6 +300,13 @@ def build_snapshot_cols(
     ``phases`` (optional dict) accumulates per-phase wall seconds under
     the BUILD_PHASES keys, so a projection_build_s regression is
     attributable to a specific stage.
+
+    ``table_sink(prefix, table)`` (optional) is handed each hash table the
+    moment it is built and returns what the snapshot keeps in its place.
+    The tables are the largest arrays and are built first, while little
+    else is: a device engine ships each there and keeps the device's
+    columns (``hashtab.DeviceTable``), so one table's host copy is gone
+    before the next is built (2-3 GB each at 150M tuples).
     """
     import time
 
@@ -348,23 +367,34 @@ def build_snapshot_cols(
     # one stable argsort of the packed key replaces the old
     # unique + searchsorted + argsort(node_of_row) triple: equal packed
     # keys ARE equal nodes and packed order IS node order, so this
-    # permutation doubles as the membership insertion order (m_order)
+    # permutation doubles as the membership insertion order (m_order).
+    # From here on every array is dropped where its last reader ends: at
+    # 150M rows each int64 column is 1.2 GB, and the function's locals
+    # would otherwise all live to its end.
     s1 = np.argsort(packed, kind="stable")
     sp = packed[s1]
-    subj_s1 = subj[s1]  # membership insertion order (seq within node)
+    del packed
+    mpad = _bucket(n_tuples)
+    # insertion-ordered member list per node (device Expand): s1 is stable
+    # by node, so it keeps the live rows' append (seq) order within each
+    # group; gathered straight into the padded array
+    mem_ord_subj = np.empty(mpad, np.int32)
+    mem_ord_subj[n_tuples:] = -1
+    subj_s1 = mem_ord_subj[:n_tuples]
+
+    def _by_node(lo, hi_):
+        # (clip: the indices are a permutation, and "raise" buffers out)
+        np.take(subj, s1[lo:hi_], out=subj_s1[lo:hi_], mode="clip")
+
+    parallel.shard_apply(n_tuples, _by_node)
+    newg = np.empty(n_tuples, bool)
     if n_tuples:
-        newg = np.empty(n_tuples, bool)
         newg[0] = True
         np.not_equal(sp[1:], sp[:-1], out=newg[1:])
-        uniq_packed = sp[newg]
-        gid32 = np.cumsum(newg, dtype=np.int32)  # node id + 1 per position
-        gid32 -= 1
-        node_of_row = np.empty(n_tuples, np.int32)
-        node_of_row[s1] = gid32  # scatter back to row order
-    else:
-        uniq_packed = np.zeros(0, np.int64)
-        node_of_row = np.zeros(0, np.int32)
-        gid32 = np.zeros(0, np.int32)
+    uniq_packed = sp[newg]
+    del sp
+    gid32 = np.cumsum(newg, dtype=np.int32)  # node id + 1 per position
+    gid32 -= 1
     n_nodes = len(uniq_packed)
 
     # membership pairs sorted by (node, subj): node values come free as
@@ -374,22 +404,50 @@ def build_snapshot_cols(
     # multi-group rows by a packed (node, subj) VALUE key.  Singleton
     # rows pass through in s1 order, which is already (node, subj) order.
     mem_node_v = gid32
+    mem_subj_v = subj_s1.copy()
     if n_tuples:
-        is_last = np.empty(n_tuples, bool)
-        is_last[:-1] = newg[1:]
-        is_last[-1] = True
-        multi = ~(newg & is_last)  # row sits in a group of size >= 2
-        mem_subj_v = subj_s1.copy()
+        multi = np.empty(n_tuples, bool)  # row sits in a group of size >= 2
+        multi[:-1] = newg[1:]
+        multi[-1] = True
+        multi &= newg
+        np.logical_not(multi, out=multi)
         rows_m = np.flatnonzero(multi)
+        del multi
         if len(rows_m):
             mk = gid32[rows_m].astype(np.int64)
             mk <<= 32
             mk += subj_s1[rows_m]
             mk.sort()  # values only: grouped by node, subj ascending
             mem_subj_v[rows_m] = mk & 0xFFFFFFFF
-    else:
-        mem_subj_v = subj_s1
+            del mk
+        del rows_m
     t0 = _mark("sort_unique", t0)
+
+    # -- O(1) device lookups (hashtab.py), before the CSR is packed ----------
+    check_caps(tuples=n_tuples, nodes=n_nodes, subjects=len(vocab.subjects))
+    node_hi = np.empty(n_nodes, np.int32)
+    node_lo = np.empty(n_nodes, np.int32)
+
+    def _node_cols(lo, hi_):
+        node_hi[lo:hi_] = uniq_packed[lo:hi_] >> 32
+        node_lo[lo:hi_] = uniq_packed[lo:hi_] & 0xFFFFFFFF
+
+    parallel.shard_apply(n_nodes, _node_cols)
+    node_tab = hashtab.build_table(
+        node_hi,
+        node_lo,
+        np.arange(n_nodes, dtype=np.int32),
+        lean=True, probe=2 * hashtab.SNAPSHOT_PROBE,
+    )
+    if table_sink is not None:
+        node_tab = table_sink("nt", node_tab)
+    mem_tab = hashtab.build_table(
+        mem_node_v, mem_subj_v,
+        lean=True, probe=2 * hashtab.SNAPSHOT_PROBE,
+    )
+    if table_sink is not None:
+        mem_tab = table_sink("mt", mem_tab)
+    t0 = _mark("hashtab", t0)
 
     # -- subject-set CSR (insertion order within each row) -------------------
     # s1 already groups rows by node with seq order preserved, so the set
@@ -401,32 +459,62 @@ def build_snapshot_cols(
 
     parallel.shard_apply(n_tuples, _sel)
     ss_sorted = s1[sel]  # row index (live-space) per edge, grouped by node
+    del s1
     ss_rows = gid32[sel]  # node id per edge
-    rows_set = ss_sorted if live is None else live[ss_sorted]
-    edge_ns_v = cols.s_ns[rows_set]
-    edge_obj_v = cols.s_obj[rows_set]
-    edge_rel_v = cols.s_rel[rows_set]
+    del sel
     n_edges = len(ss_sorted)
-    counts = np.bincount(ss_rows, minlength=max(n_nodes, 1))[: max(n_nodes, 1)]
 
-    # edge target node ids
-    e_hi = edge_ns_v.astype(np.int64) * num_rels + edge_rel_v
-    e_packed = (e_hi << 32) | edge_obj_v.astype(np.int64)
-    e_idx = np.searchsorted(uniq_packed, e_packed)
-    e_found = (e_idx < n_nodes) & (
-        uniq_packed[np.minimum(e_idx, max(n_nodes - 1, 0))] == e_packed
-    )
-    edge_node_v = np.where(e_found, e_idx, -1).astype(np.int32)
+    # only device-bound arrays get _bucket padding; node_hi/node_lo and the
+    # sorted membership columns stay host-side (checkpointing + overlay
+    # binary searches) and are stored at exact length
+    npad = _bucket(n_nodes)
+    epad = _bucket(n_edges)
+    check_caps(edges=n_edges)
+
+    def edge_col(col):
+        """The set rows' ``col``, gathered straight into a padded array."""
+        out = np.empty(epad, np.int32)
+        out[n_edges:] = -1
+        np.take(col, rows_set, out=out[:n_edges], mode="clip")
+        return out
+
+    rows_set = ss_sorted if live is None else live[ss_sorted]
+    edge_ns, edge_obj, edge_rel = (
+        edge_col(cols.s_ns), edge_col(cols.s_obj), edge_col(cols.s_rel))
+    del rows_set
+    edge_ns_v, edge_obj_v, edge_rel_v = (
+        edge_ns[:n_edges], edge_obj[:n_edges], edge_rel[:n_edges])
+
+    row_ptr = np.empty(npad + 1, np.int32)
+    row_ptr[0] = 0
+    if n_nodes:
+        np.cumsum(np.bincount(ss_rows, minlength=n_nodes)[:n_nodes],
+                  out=row_ptr[1 : n_nodes + 1])
+    row_ptr[n_nodes + 1:] = n_edges
 
     # -- dynamic relation-level pairs (for taint) ---------------------------
-    # packed unique over the edge rows instead of a Python set of 4-tuples
-    # over millions of lists; the source (ns, rel) pair is the high word
-    # of the node key already gathered into sp
-    src_pk = sp[sel] >> 32
-    dkey = (src_pk << 32) | (e_hi & 0xFFFFFFFF)
-    du = np.unique(dkey)
-    d_src = du >> 32
-    d_dst = du & 0xFFFFFFFF
+    # one (source (ns, rel), target (ns, rel)) code per edge; the source
+    # pair is the high word of the edge's node key.  The codes are few
+    # (the square of the op table's padded dims), so a histogram names the
+    # distinct ones without sorting a 100M-row column
+    e_hi = edge_ns_v.astype(np.int64)
+    e_hi *= num_rels
+    e_hi += edge_rel_v
+    hi_dim = num_ns * num_rels
+    dkey = uniq_packed[ss_rows]
+    dkey >>= 32
+    if n_edges and hi_dim * hi_dim <= (1 << 22) and (
+            int(dkey.max()) < hi_dim and int(e_hi.max()) < hi_dim):
+        dkey *= hi_dim
+        dkey += e_hi
+        du = np.flatnonzero(np.bincount(dkey))
+        d_src, d_dst = du // hi_dim, du % hi_dim
+    else:
+        dkey <<= 32
+        dkey |= e_hi
+        du = np.unique(dkey)
+        d_src, d_dst = du >> 32, du & 0xFFFFFFFF
+    del dkey
     dyn = set(
         zip(
             (d_src // num_rels).tolist(), (d_src % num_rels).tolist(),
@@ -434,46 +522,30 @@ def build_snapshot_cols(
         )
     )
 
-    # -- pack + pad ---------------------------------------------------------
-    # only device-bound arrays get _bucket padding; node_hi/node_lo and the
-    # sorted membership columns stay host-side (checkpointing + overlay
-    # binary searches) and are stored at exact length
-    npad = _bucket(n_nodes)
-    epad = _bucket(n_edges)
-    mpad = _bucket(n_tuples)
+    # edge target node ids: one binary search an edge, sharded (the
+    # searches are independent and numpy releases the GIL for them)
+    e_hi <<= 32
+    e_hi |= edge_obj_v
+    e_packed = e_hi
+    del e_hi
+    edge_node = np.empty(epad, np.int32)
+    edge_node[n_edges:] = -1
 
-    node_hi = np.empty(n_nodes, np.int32)
-    node_lo = np.empty(n_nodes, np.int32)
+    def _targets(lo, hi_):
+        want = e_packed[lo:hi_]
+        at = np.searchsorted(uniq_packed, want)
+        np.minimum(at, max(n_nodes - 1, 0), out=at)
+        hit = uniq_packed[at] == want if n_nodes else np.zeros(len(at), bool)
+        at[~hit] = -1
+        edge_node[lo:hi_] = at
 
-    def _node_cols(lo, hi_):
-        node_hi[lo:hi_] = uniq_packed[lo:hi_] >> 32
-        node_lo[lo:hi_] = uniq_packed[lo:hi_] & 0xFFFFFFFF
+    parallel.shard_apply(n_edges, _targets)
+    del e_packed
 
-    parallel.shard_apply(n_nodes, _node_cols)
-
-    row_ptr = np.empty(npad + 1, np.int32)
-    row_ptr[0] = 0
-    if n_nodes:
-        np.cumsum(counts, out=row_ptr[1 : n_nodes + 1])
-    row_ptr[n_nodes + 1:] = n_edges
-
-    def pad_edges(v):
-        out = np.empty(epad, np.int32)
-        out[:n_edges] = v
-        out[n_edges:] = -1
-        return out
+    del uniq_packed
 
     mem_node = mem_node_v
     mem_subj = mem_subj_v
-    mem_ord_subj = np.empty(mpad, np.int32)
-
-    def _mem_fill(lo, hi_):
-        # insertion-ordered member list per node: s1 is stable by node, so
-        # it keeps the live rows' append (seq) order within each group
-        mem_ord_subj[lo:hi_] = subj_s1[lo:hi_]
-
-    parallel.shard_apply(n_tuples, _mem_fill)
-    mem_ord_subj[n_tuples:] = -1
     # per-node membership CSR straight from the group boundaries: every
     # node owns >= 1 tuple, so the i-th True in newg IS the row offset of
     # node i (no bincount/cumsum pass over the 10M column)
@@ -481,15 +553,18 @@ def build_snapshot_cols(
     mem_row_ptr[n_nodes:] = n_tuples
     if n_nodes:
         mem_row_ptr[:n_nodes] = np.flatnonzero(newg)
+    del newg
 
     spad = _bucket(max(len(vocab.subjects), 1))
     sub_ns = np.full(spad, -1, np.int32)
     sub_obj = np.full(spad, -1, np.int32)
     sub_rel = np.full(spad, -1, np.int32)
     ss_subj = subj[ss_sorted]
+    del ss_sorted, ss_rows
     sub_ns[ss_subj] = edge_ns_v
     sub_obj[ss_subj] = edge_obj_v
     sub_rel[ss_subj] = edge_rel_v
+    del ss_subj
     t0 = _mark("csr_pack", t0)
 
     flat = compile_flat_tables(
@@ -497,18 +572,6 @@ def build_snapshot_cols(
     )
     taint, err_reach = _compute_taint(flat, op, dyn, num_ns, num_rels)
     t0 = _mark("optable", t0)
-
-    node_tab = hashtab.build_table(
-        node_hi,
-        node_lo,
-        np.arange(n_nodes, dtype=np.int32),
-        lean=True, probe=2 * hashtab.SNAPSHOT_PROBE,
-    )
-    mem_tab = hashtab.build_table(
-        mem_node_v, mem_subj_v,
-        lean=True, probe=2 * hashtab.SNAPSHOT_PROBE,
-    )
-    t0 = _mark("hashtab", t0)
 
     snap = Snapshot(
         vocab=vocab,
@@ -520,10 +583,10 @@ def build_snapshot_cols(
         node_hi=node_hi,
         node_lo=node_lo,
         row_ptr=row_ptr,
-        edge_ns=pad_edges(edge_ns_v),
-        edge_obj=pad_edges(edge_obj_v),
-        edge_rel=pad_edges(edge_rel_v),
-        edge_node=pad_edges(edge_node_v),
+        edge_ns=edge_ns,
+        edge_obj=edge_obj,
+        edge_rel=edge_rel,
+        edge_node=edge_node,
         mem_node=mem_node,
         mem_subj=mem_subj,
         mem_row_ptr=mem_row_ptr,

@@ -157,15 +157,18 @@ class _Decoder:
     a listing page."""
 
     def __init__(self, vocab: Vocab):
-        self.ns = vocab.namespaces.strings()
-        self.obj = vocab.objects.strings()
-        self.rel = vocab.relations.strings()
-        self.sub = vocab.subjects.strings()
+        # one id at a time (``Interner.string``): a decoder is made for
+        # every tree, and ``strings()`` would list the whole vocabulary
+        # for it (a second a million names once they are packed)
+        self.ns = vocab.namespaces.string
+        self.obj = vocab.objects.string
+        self.rel = vocab.relations.string
+        self.sub = vocab.subjects.string
 
     def subject(self, subj_id: int, s_ns: int, s_obj: int, s_rel: int) -> Subject:
         if s_ns >= 0:
-            return SubjectSet(self.ns[s_ns], self.obj[s_obj], self.rel[s_rel])
-        uid = self.sub[subj_id]
+            return SubjectSet(self.ns(s_ns), self.obj(s_obj), self.rel(s_rel))
+        uid = self.sub(subj_id)
         # unique_id format "id:<subject id>" (api/types.py)
         return SubjectID(uid[3:] if uid.startswith("id:") else uid)
 
@@ -173,7 +176,7 @@ class _Decoder:
         """Decode via the unique-id string alone — works for subjects
         interned AFTER the snapshot (overlay writes), which the snapshot's
         sub_ns/sub_obj/sub_rel arrays do not cover."""
-        uid = self.sub[subj_id]
+        uid = self.sub(subj_id)
         if uid.startswith("set:"):
             return SubjectSet.from_string(uid[4:])
         return SubjectID(uid[3:] if uid.startswith("id:") else uid)

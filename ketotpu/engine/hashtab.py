@@ -42,11 +42,14 @@ buckets and pads may share the query's tag and fail the verify.
 from __future__ import annotations
 
 import threading
+from collections.abc import Mapping
 from typing import Dict, Optional, Tuple
 
 import numpy as np
 
 from ketotpu.engine import parallel
+
+_I32MAX = int(np.iinfo(np.int32).max)
 
 PROBE = 8  # default probe depth; the build guarantees max bucket <= probe
 PROBE_SHALLOW = 4  # for small side tables on hot probe paths (delta overlay)
@@ -150,24 +153,30 @@ def _tag_reject(op: str) -> None:
         TAG_REJECTS[op] += 1
 
 
-def _tag_clash(ptr, tag, key_b, n: int, depth: int) -> bool:
-    """True when two of the first ``n`` entries sit in one bucket (none
-    deeper than ``depth``) with one tag and different keys: the layout's
-    one invariant, without which a lookup's first tag hit could be
-    another key's entry.  Equal tags with equal ``key_b`` are the same
-    key (duplicates are allowed).  One compare pass a distance; equal
-    32-bit tags that close are rare (or duplicates), so the bucket test
-    runs on a handful of positions."""
+def _tag_twins(ptr, tag, key_b, n: int, depth: int, same_key: bool):
+    """Among the first ``n`` entries (no bucket deeper than ``depth``),
+    the positions, a distance at a time, of those that share bucket and
+    tag with the entry that distance before them, and its ``key_b`` too
+    (``same_key``: the same key again) or not (two keys of one tag).  One
+    compare pass a distance; equal 32-bit tags that close are rare (or
+    duplicates), so the bucket test runs on a handful of positions."""
     for d in range(1, min(depth, n)):
         at = np.flatnonzero(tag[d:n] == tag[: n - d])
-        at = at[key_b[at] != key_b[at + d]]
-        # the CSR position's bucket: the last ptr at or before it
-        if at.size and (
-            np.searchsorted(ptr, at, side="right")
-            == np.searchsorted(ptr, at + d, side="right")
-        ).any():
-            return True
-    return False
+        at = at[(key_b[at] == key_b[at + d]) == same_key]
+        if at.size:
+            # the CSR position's bucket: the last ptr at or before it
+            at = at[np.searchsorted(ptr, at, side="right")
+                    == np.searchsorted(ptr, at + d, side="right")]
+        if at.size:
+            yield at + d
+
+
+def _tag_clash(ptr, tag, key_b, n: int, depth: int) -> bool:
+    """True when two of the first ``n`` entries sit in one bucket with one
+    tag and different keys: the layout's one invariant, without which a
+    lookup's first tag hit could be another key's entry.  Equal tags with
+    equal ``key_b`` are the same key (duplicates are allowed)."""
+    return next(_tag_twins(ptr, tag, key_b, n, depth, False), None) is not None
 
 
 def _bincount(h: np.ndarray, buckets: int) -> np.ndarray:
@@ -178,7 +187,11 @@ def _bincount(h: np.ndarray, buckets: int) -> np.ndarray:
     n = len(h)
     if threads <= 1 or n < (1 << 21):
         return np.bincount(h, minlength=buckets)
-    shards = min(threads, 4)  # partials are buckets-wide: cap the memory
+    # partials are buckets-wide int64: at most 1 GB of them (four at the
+    # 16.8M buckets of a 10M-key table, one from 134M up)
+    shards = min(threads, 4, (1 << 27) // buckets)
+    if shards <= 1:
+        return np.bincount(h, minlength=buckets)
     step = -(-n // shards)
     parts = [None] * shards
 
@@ -201,28 +214,32 @@ def _grouped_order(h: np.ndarray, buckets: int) -> np.ndarray:
     """A permutation grouping entries by bucket id.
 
     Bucket-CSR layout only needs entries GROUPED by bucket — order within
-    a bucket is free (lookups scan the whole bucket) — so this uses the
-    faster non-stable introsort, and on multi-core hosts partitions the
-    bucket space so each shard selects + sorts its own range
-    concurrently (concatenation preserves bucket grouping)."""
+    a bucket is free (lookups scan the whole bucket) — so each part is
+    sorted by the faster non-stable introsort.  On a multi-core host the
+    entries are first dealt into 256 ranges of the bucket space by one
+    radix pass over the bucket id's top byte, and the pool sorts each
+    range in place: beside the permutation itself (8 bytes an entry)
+    there is a byte an entry and one range's scratch a thread, where a
+    mask and an index list per shard cost 56 bytes an entry, 8 GB at the
+    150M entries of a chip-filling graph's membership table."""
     threads = parallel.pool_size()
     n = len(h)
     if threads <= 1 or n < (1 << 21):
         return np.argsort(h)
-    shards = min(threads, 8)
-    bstep = -(-buckets // shards)
-    parts = [None] * shards
+    shift = max(int(buckets).bit_length() - 1 - 8, 0)
+    top = (h >> np.uint32(shift)).astype(np.uint8)
+    order = np.argsort(top, kind="stable")  # 8-bit keys: a radix sort
+    ends = np.cumsum(np.bincount(top, minlength=256))
+    del top
 
     def _part(i):
-        lo, hi = np.uint32(i * bstep), np.uint32(min((i + 1) * bstep, buckets))
-        idx = np.flatnonzero((h >= lo) & (h < hi))
-        parts[i] = idx[np.argsort(h[idx])]
+        seg = order[ends[i - 1] if i else 0:ends[i]]
+        seg[:] = seg[np.argsort(h[seg])]
 
     pool = parallel._get_pool(threads)
-    futs = [pool.submit(_part, i) for i in range(shards)]
-    for f in futs:
+    for f in [pool.submit(_part, i) for i in range(256)]:
         f.result()
-    return np.concatenate(parts)
+    return order
 
 
 def build_table(
@@ -272,6 +289,11 @@ def build_table(
             raise ValueError(f"{n} entries exceed fixed cap {fixed_shape[1]}")
     else:
         buckets = _bucket_pow2(max(n if lean else 2 * n, 1), min_buckets)
+    if n > _I32MAX or buckets > _I32MAX + 1:
+        # ptr holds entry offsets and meta the bucket mask, both int32
+        raise ValueError(
+            f"{n} entries in {buckets} buckets pass a table's cap of "
+            f"{_I32MAX} entries and {_I32MAX + 1} buckets")
     # at lean 10M-entry load factors the max bucket sits above the probe
     # TARGET for every salt (they all draw from the same distribution), so
     # walking the schedule is mix+bincount passes over multi-GB arrays
@@ -320,6 +342,9 @@ def build_table(
                 parallel.shard_apply(n, _rehash)
             break
     depth = probe_eff  # the deepest bucket, before any pinning
+    ptr = np.zeros(buckets + 1, np.int32)
+    np.cumsum(counts, out=ptr[1:])
+    del counts, best  # buckets-wide int64: 2 GB at 268M buckets
     if n <= 512 and fixed_shape is None:
         # pin the probe depth (== the pw array SHAPE) for small tables:
         # the achieved max-bucket is data-dependent (1 vs 2 vs 3 on a few
@@ -329,6 +354,7 @@ def build_table(
         # tables this small; the 10M-scale adaptive depth is untouched.
         probe_eff = max(probe_eff, probe)
     order = _grouped_order(h, buckets) if n else np.zeros(0, np.int64)
+    del h
     cap = fixed_shape[1] if fixed_shape is not None else _bucket_pow2(max(n, 1), 64)
     # empty + range fills instead of full(-1) + overwrite: one write pass
     # over the entry region instead of two (real at 10M+ rows), and the
@@ -337,8 +363,6 @@ def build_table(
     tb = np.empty(cap, np.int32)
     tt[n:] = -1
     tb[n:] = -1
-    ptr = np.zeros(buckets + 1, np.int32)
-    np.cumsum(counts, out=ptr[1:])
     # the tag column, and the walk of its salt: at random keys a bucket
     # holds two keys of one tag once in a thousand 10M-entry tables, so
     # the second pass is a guard; it rehashes nothing but the tags
@@ -546,6 +570,47 @@ def lookup_np(t: Dict, a: np.ndarray, b: np.ndarray) -> Tuple:
     return np.where(found, payload, -1).astype(np.int32), found
 
 
+_U32 = 0xFFFFFFFF
+
+
+def lookup_one(t: Dict, a: int, b: int) -> int:
+    """:func:`lookup_np` for one key, in plain integers (a numpy call
+    costs more than the whole probe): the payload, or the entry's index,
+    or -1.  The host knows where the bucket ends, so it scans that."""
+    if a < 0 or b < 0:
+        return -1
+    meta = t["meta"]
+    h = ((a ^ (b * 0x85EBCA77)) * 0x9E3779B1 + int(_SALTS[meta[0]])) & _U32
+    h = ((h ^ (h >> 16)) * 0xC2B2AE3D) & _U32
+    h = (h ^ (h >> 13)) & int(meta[1])
+    f = (b + int(_SALTS[meta[2]])) & _U32
+    f = ((f ^ (f >> 16)) * 0x85EBCA6B) & _U32
+    f = ((f ^ (f >> 13)) * 0xC2B2AE35) & _U32
+    qtag = (a ^ f ^ (f >> 16)) & _U32
+    if qtag >= 1 << 31:
+        qtag -= 1 << 32  # the column holds the tag as int32
+    ptr = t["ptr"]
+    lo = int(ptr[h])
+    tags = t["tag"][lo:int(ptr[h + 1])].tolist()
+    if qtag not in tags:
+        return -1
+    j = lo + tags.index(qtag)
+    if t["key_b"][j] != b:
+        return -1
+    vals = t.get("val")
+    return int(vals[j]) if vals is not None else j
+
+
+def repeated_keys(t: Dict) -> np.ndarray:
+    """Entry positions (ascending) whose key an earlier entry of the same
+    bucket holds too: a lookup finds the first of such a run alone, so a
+    caller that stores distinct payloads under what may be equal keys
+    (the vocabulary: two strings of one 62-bit hash) keeps these aside."""
+    found = list(_tag_twins(t["ptr"], t["tag"], t["key_b"], int(t["ptr"][-1]),
+                            t["pw"].shape[0], True))
+    return np.unique(np.concatenate(found)) if found else np.zeros(0, np.int64)
+
+
 def lookup(t: Dict, a, b, *, probe: int = PROBE) -> Tuple:
     """Device probe: (val_or_index, found).  Negative queries never match.
 
@@ -598,6 +663,45 @@ def lookup_gathers(t: Dict) -> int:
     a round, the verify, and the payload where the table has one (the
     lowered program is held to it in ``tests/test_hashtab.py``)."""
     return 1 + t["pw"].shape[-1] + 1 + ("val" in t)
+
+
+class DeviceTable(Mapping):
+    """A built table whose columns live on the device alone.  Once a
+    table is shipped, only the device programs read it; what still reads
+    it on the host (a fold's splice, a checkpoint, a re-ship) is rare and
+    brings a column back with each access (``np.asarray``: a copy from a
+    chip, a view on the CPU backend).  The host copies of a 150M-tuple
+    graph's two tables are 5.4 GB beside the 7 GB the chip holds."""
+
+    def __init__(self, columns: Dict):
+        #: the device's arrays, for whoever ships the table again
+        self.columns = dict(columns)
+
+    def __getitem__(self, key):
+        return np.asarray(self.columns[key])
+
+    def __contains__(self, key) -> bool:
+        return key in self.columns
+
+    def __iter__(self):
+        return iter(self.columns)
+
+    def __len__(self) -> int:
+        return len(self.columns)
+
+
+#: the served tables' prefixes in the device-array dict: node table,
+#: membership table, and the delta overlay's two
+TABLES = ("nt", "mt", "ovt", "om")
+
+
+def wave_gathers(arrays: Dict) -> Dict[str, int]:
+    """:func:`lookup_gathers` of each served table, read off the shapes of
+    the device-array dict a wave ran against (no fetch, no copy)."""
+    return {
+        p: 1 + arrays[p + "_pw"].shape[-1] + 1 + (p + "_val" in arrays)
+        for p in TABLES if p + "_pw" in arrays
+    }
 
 
 def table_stats(t: Dict) -> Dict:

@@ -18,6 +18,7 @@ merge step would eat the win at this scale.
 from __future__ import annotations
 
 import os
+import threading
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Optional
 
@@ -25,6 +26,7 @@ _MIN_CHUNK = 1 << 20  # below ~1M rows the dispatch overhead dominates
 
 _pool: Optional[ThreadPoolExecutor] = None
 _pool_size = 0
+_pool_lock = threading.Lock()  # two builds may ask for the pool at once
 
 
 def pool_size() -> int:
@@ -39,14 +41,15 @@ def pool_size() -> int:
 
 def _get_pool(size: int) -> ThreadPoolExecutor:
     global _pool, _pool_size
-    if _pool is None or _pool_size != size:
-        if _pool is not None:
-            _pool.shutdown(wait=False)
-        _pool = ThreadPoolExecutor(
-            max_workers=size, thread_name_prefix="keto-build"
-        )
-        _pool_size = size
-    return _pool
+    with _pool_lock:
+        if _pool is None or _pool_size != size:
+            if _pool is not None:
+                _pool.shutdown(wait=False)
+            _pool = ThreadPoolExecutor(
+                max_workers=size, thread_name_prefix="keto-build"
+            )
+            _pool_size = size
+        return _pool
 
 
 def shard_apply(n: int, fn: Callable[[int, int], None]) -> None:

@@ -59,6 +59,96 @@ def _bucket(n: int, floor: int = 64) -> int:
     return b
 
 
+#: what an int32 column holds: ids, row offsets and ``ptr`` entries all
+#: ride in one
+INT32_CAP = _I32MAX
+
+#: the groups :func:`device_bytes` reckons, and when each reaches the
+#: device: with the projection (Check serving), at the first Expand, or
+#: on the mesh alone
+DEVICE_GROUPS = {
+    "csr": "check", "node_table": "check", "membership_table": "check",
+    "overlay": "check", "leopard": "check",
+    "membership": "expand", "expand_only": "expand", "mesh_only": "mesh",
+}
+
+
+def check_caps(**counts: int) -> None:
+    """Raise where a count passes what the projection's int32 columns and
+    row offsets hold; a wrapped id would answer for another row, silently."""
+    for what, n in counts.items():
+        if int(n) > INT32_CAP:
+            raise ValueError(
+                f"{int(n)} {what} pass the projection's cap of {INT32_CAP} "
+                "(int32 ids and row offsets)")
+
+
+def device_bytes(
+    *, tuples: int, nodes: int, edges: int, subjects: int,
+    pair_cap: int = 4096, leopard_pairs: int = 0,
+    nt_rounds: int = 8, mt_rounds: int = 8, overlay_rounds: int = 4,
+) -> Dict[str, Dict[str, int]]:
+    """What a projection of these counts takes on the device, by group of
+    arrays (:data:`DEVICE_GROUPS`): ``padded`` is the bytes of the arrays
+    as the build pads them (every length a power of two, so memory is a
+    staircase in the counts), ``live`` what the same arrays would take at
+    their exact lengths.  It mirrors the padding rules of this module and
+    of engine/delta.py (``_bucket``), engine/hashtab.py (``build_table``:
+    a lean table has the power of two at or above its entries in buckets
+    and in capacity) and leopard/device.py; ``tests/test_sizing.py`` holds
+    it to the ``nbytes`` of the arrays a build really makes.  The compiled
+    rewrite programs (op and flat tables, a few KB) are left out.  An
+    operator reads it before a load (how many tuples fit a chip), the
+    projection reports it (``/debug/projection`` ``device_bytes``)."""
+    npad, epad = _bucket(nodes), _bucket(edges)
+    mpad, spad = _bucket(tuples), _bucket(max(subjects, 1))
+
+    def table(n: int, cols: int, rounds: int) -> Dict[str, int]:
+        # ptr, then tag / key_b (/ val), meta int32[3], pw int8[rounds]
+        buckets = hashtab._bucket_pow2(max(n, 1), 128)
+        cap = hashtab._bucket_pow2(max(n, 1), 64)
+        fixed = 12 + rounds
+        return {"live": 4 * (n + 1) + 4 * cols * n + fixed,
+                "padded": 4 * (buckets + 1) + 4 * cols * cap + fixed}
+
+    pair_cap = max(1, pair_cap)
+    # a fixed-shape delta table: 4 x pair_cap buckets, three columns
+    delta_tab = 4 * (4 * pair_cap + 1) + 12 * pair_cap + 12 + overlay_rounds
+    dirty = _bucket(nodes + pair_cap + 1, 64)
+    lpad = 0
+    if leopard_pairs:
+        from ketotpu.leopard.device import _pair_bucket
+
+        lpad = _pair_bucket(leopard_pairs)
+    return {
+        # row_ptr, edge_hi, edge_obj
+        "csr": {"live": 4 * (nodes + 1) + 8 * edges,
+                "padded": 4 * (npad + 1) + 8 * epad},
+        "node_table": table(nodes, 3, nt_rounds),
+        "membership_table": table(tuples, 2, mt_rounds),
+        # ov_dirty (a bool a node and a virtual node), ov_nbase, and the
+        # two fixed-shape delta tables om_, ovt_
+        "overlay": {"live": nodes + pair_cap + 1 + 4 + 2 * delta_tab,
+                    "padded": dirty + 4 + 2 * delta_tab},
+        # sets, elts, hops (leopard/device.py ship_pairs)
+        "leopard": {"live": 12 * leopard_pairs, "padded": 12 * lpad},
+        # mem_row_ptr, mem_ord_subj
+        "membership": {"live": 4 * (nodes + 1) + 4 * tuples,
+                       "padded": 4 * (npad + 1) + 4 * mpad},
+        # sub_ns, sub_obj, sub_rel
+        "expand_only": {"live": 12 * subjects, "padded": 12 * spad},
+        # edge_node
+        "mesh_only": {"live": 4 * edges, "padded": 4 * epad},
+    }
+
+
+def resident_bytes(groups: Dict[str, Dict[str, int]], when: str = "check",
+                   kind: str = "padded") -> int:
+    """The bytes of the groups on the device for ``when``."""
+    return sum(g[kind] for name, g in groups.items()
+               if DEVICE_GROUPS[name] == when)
+
+
 @dataclass
 class Snapshot:
     """Device graph arrays (numpy here; the engine ships them to HBM)."""
@@ -117,18 +207,23 @@ class Snapshot:
         10M-tuple scale keeps ~200MB off the device upload."""
         return {
             **self.flat.arrays(),
-            **{f"nt_{k}": v for k, v in self.node_tab.items()},
-            **{f"mt_{k}": v for k, v in self.mem_tab.items()},
+            # (a table that is on the device already goes as it is there)
+            **{f"nt_{k}": v for k, v in getattr(
+                self.node_tab, "columns", self.node_tab).items()},
+            **{f"mt_{k}": v for k, v in getattr(
+                self.mem_tab, "columns", self.mem_tab).items()},
             "row_ptr": self.row_ptr,
             # (ns, rel) packed into one word (hi = ns * num_rels + rel,
             # the node-table hi formula): the edge arrays feed arena-sized
             # gathers on the hottest path, and one packed gather + a VPU
             # div/mod decode beats two HBM gathers
+            # (int32 throughout: int64 temporaries of an edge column are
+            # 1 GB each at 150M tuples, and ns * num_rels + rel is small)
             "edge_hi": np.where(
                 self.edge_ns >= 0,
-                self.edge_ns.astype(np.int64) * self.num_rels + self.edge_rel,
-                -1,
-            ).astype(np.int32),
+                self.edge_ns * np.int32(self.num_rels) + self.edge_rel,
+                np.int32(-1),
+            ),
             "edge_obj": self.edge_obj,
             "edge_node": self.edge_node,
             "mem_row_ptr": self.mem_row_ptr,

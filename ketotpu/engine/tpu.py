@@ -223,6 +223,10 @@ class DeviceCheckEngine:
         # the program ran it at: their quotient is how full the tier ran
         self.fused_general_rows = 0
         self.fused_general_lanes = 0
+        # element gathers one lookup of each served table cost the fused
+        # waves, a wave's tables' ``lookup_gathers`` added at its collect
+        # (over fused_waves: gathers a lookup, as the waves met them)
+        self.fused_probe_gathers = dict.fromkeys(hashtab.TABLES, 0)
         # per-tier row attribution for fused waves, from the returned
         # masks (keto_fused_tier_rows_total; wave-ledger tier deltas)
         self.fused_tier_rows = {
@@ -452,6 +456,7 @@ class DeviceCheckEngine:
             strict=self.strict_mode,
             version=self.store.version,
             phases=ph,
+            table_sink=self._ship_table,
         )
         self.projection_build_s = time.perf_counter() - t0
         self._snap_fingerprint = fingerprint
@@ -544,6 +549,7 @@ class DeviceCheckEngine:
         trigger a recompile.  (The mesh engine overrides this: it ships
         sharded stacks instead and builds the replicated copy lazily.)"""
         self._base_device = jax.device_put(self._snap.check_arrays())
+        self._release_host_tables(self._snap, self._base_device)
         self._expand_extra = None  # expand-only tables ship on first use
         self._device_arrays = dict(
             self._base_device,
@@ -554,6 +560,24 @@ class DeviceCheckEngine:
             ),
         )
 
+    @staticmethod
+    def _ship_table(prefix: str, table):
+        """A hash table the projection has just built goes to the device
+        at once (``build_snapshot_cols`` ``table_sink``); the snapshot keeps
+        the device's columns.  (The mesh engine overrides: its shards'
+        tables are stacked on the host first.)"""
+        shipped = jax.device_put(table)
+        jax.block_until_ready(shipped)
+        return hashtab.DeviceTable(shipped)
+
+    @staticmethod
+    def _release_host_tables(snap, base) -> None:
+        """Once shipped, the two hash tables are read by the device
+        programs alone: the snapshot keeps them as the device's columns
+        (``hashtab.DeviceTable``) and lets the host copies go."""
+        snap.node_tab = hashtab.DeviceTable(hashtab.subtables(base, "nt_"))
+        snap.mem_tab = hashtab.DeviceTable(hashtab.subtables(base, "mt_"))
+
     def _expand_arrays(self):
         """Device arrays for batch_expand: the Check dict plus the
         expand-only tables, shipped lazily — Check serving at 10M tuples
@@ -562,9 +586,8 @@ class DeviceCheckEngine:
         if self._expand_extra is None:
             from ketotpu.engine.snapshot import EXPAND_ONLY_KEYS
 
-            full = self._snap.arrays()
             self._expand_extra = jax.device_put(
-                {k: full[k] for k in EXPAND_ONLY_KEYS}
+                {k: getattr(self._snap, k) for k in EXPAND_ONLY_KEYS}
             )
         return dict(self._device_arrays, **self._expand_extra)
 
@@ -870,6 +893,7 @@ class DeviceCheckEngine:
                     self._overlay = dl.OverlayState()
                     self._overlay_active = False
                     self._base_device = base
+                    self._release_host_tables(new_snap, base)
                     self._device_arrays = dict(base, **empty_ov)
                     self._expand_extra = None
                     self._pending = list(residual)
@@ -946,6 +970,7 @@ class DeviceCheckEngine:
                     for k, v in self.last_build_phases.items()
                 },
                 "tag_rejects": dict(hashtab.TAG_REJECTS),
+                "device_bytes": self._device_bytes(),
             }
         # the served hash tables, as the device programs unroll them
         # (engine/hashtab.py): probe rounds, gathers a lookup, and the tag
@@ -953,10 +978,43 @@ class DeviceCheckEngine:
         # salt is a fetch from the device
         out["tables"] = {
             p: hashtab.table_stats(hashtab.subtables(arrays, p + "_"))
-            for p in ("nt", "mt", "ovt", "om")
+            for p in hashtab.TABLES
             if p + "_meta" in arrays
         }
         return out
+
+    def _device_bytes(self) -> dict:
+        """The served projection's device bytes by group of arrays, as
+        the sizing function reckons them from the snapshot's counts
+        (engine/snapshot.py ``device_bytes``)."""
+        if self._snap is None:
+            return {}
+        from ketotpu.engine.snapshot import device_bytes
+
+        return device_bytes(**self._sizing_counts())
+
+    def _sizing_counts(self) -> dict:
+        """What :func:`snapshot.device_bytes` takes, of the served view
+        (the mesh engine overrides: a chip holds the largest shard's
+        shapes)."""
+        snap, rounds = self._snap, self.probe_rounds
+        leo = self._leopard if self._leo_device is not None else None
+        return dict(
+            tuples=snap.n_tuples, nodes=snap.n_nodes, edges=snap.n_edges,
+            # the vocabulary grows past the build; the decode table's pad
+            # is the build's
+            subjects=min(len(snap.vocab.subjects), len(snap.sub_ns)),
+            pair_cap=self.max_overlay_pairs,
+            leopard_pairs=len(leo.elt_packed) if leo is not None else 0,
+            nt_rounds=rounds.get("nt", 8), mt_rounds=rounds.get("mt", 8),
+        )
+
+    @property
+    def probe_rounds(self) -> dict:
+        """Probe rounds a lookup of each served table unrolls."""
+        arrays = self._served_arrays() or {}
+        return {p: int(arrays[p + "_pw"].shape[-1])
+                for p in hashtab.TABLES if p + "_pw" in arrays}
 
     def _served_arrays(self):
         """The device-array dict the check programs are served from (the
@@ -1949,6 +2007,8 @@ class DeviceCheckEngine:
         self.fused_d2h_fetches += 1
         self.fused_general_rows += meta["gen_rows"]
         self.fused_general_lanes += meta["gen_lanes"]
+        for table, g in hashtab.wave_gathers(wave.arrays).items():
+            self.fused_probe_gathers[table] += g
         bits = wv.decode_fused(packed[:n])
         # occupancy EMA feeds (absent tiers ship no occupancy at all)
         f_end = wave.qpad + meta["flen"]
@@ -2230,8 +2290,8 @@ class DeviceCheckEngine:
                     )
                     sets = idx.list_sets_of(v.subject_key(subject), lo, hi)
                 if sets is not None:
-                    obj_tab = self._vocab.objects.strings()
-                    objs = sorted(obj_tab[idx.node_obj(s)] for s in sets)
+                    obj_of = self._vocab.objects.string
+                    objs = sorted(obj_of(idx.node_obj(s)) for s in sets)
             if sets is None:
                 self.leopard_list_fallbacks += 1
                 t_fb = time.perf_counter()
@@ -2267,10 +2327,9 @@ class DeviceCheckEngine:
                         v.relations.lookup(relation),
                     ))
                 if elems is not None:
-                    subj_tab = self._vocab.subjects.strings()
                     by_uid = {
-                        subj_tab[e]: leolist.subject_from_uid(subj_tab[e])
-                        for e in elems
+                        uid: leolist.subject_from_uid(uid)
+                        for uid in map(self._vocab.subjects.string, elems)
                     }
             if elems is None:
                 self.leopard_list_fallbacks += 1

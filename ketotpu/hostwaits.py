@@ -35,7 +35,9 @@ engine in a test starts no thread); a lock's waits count from the start.
 
 from __future__ import annotations
 
+import contextlib
 import gc
+import sys
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -62,6 +64,35 @@ def open_spans() -> Dict[int, List[str]]:
     """The spans open right now, by thread ident (a racy copy: for logs)."""
     return {tid: list(names)
             for tid, names in list(open_by_thread.items()) if names}
+
+
+# -- lazy builds ---------------------------------------------------------------
+
+#: seconds the host spent building an index on first use, in whatever
+#: thread met it first (scrape: ``keto_host_lazy_build_seconds_total{what}``)
+LAZY_BUILD_SECONDS: Dict[str, float] = {"vocab_index": 0.0, "store_fwd": 0.0}
+_LAZY_SPANS = {"vocab_index": "keto/vocab/index_build",
+               "store_fwd": "keto/store/fwd_build"}
+
+
+@contextlib.contextmanager
+def lazy_build(what: str, entries: int):
+    """``with lazy_build(what, entries):`` times one such build into
+    :data:`LAZY_BUILD_SECONDS` and, in a process that has loaded jax,
+    shows it in a capture as a host span with ``entries=``."""
+    name = _LAZY_SPANS[what]
+    jax = sys.modules.get("jax")
+    span = (jax.profiler.TraceAnnotation(name, entries=int(entries))
+            if jax is not None else contextlib.nullcontext())
+    names = open_by_thread.setdefault(threading.get_ident(), [])
+    names.append(name)
+    t0 = time.perf_counter()
+    try:
+        with span:
+            yield
+    finally:
+        LAZY_BUILD_SECONDS[what] += time.perf_counter() - t0
+        names.pop()
 
 
 # -- pool wait -----------------------------------------------------------------

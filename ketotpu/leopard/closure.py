@@ -222,6 +222,14 @@ class ClosureIndex:
         R = np.int64(self.R)
 
         live = np.flatnonzero(cols.alive[: cols.n])
+        if len(live) > self.max_pairs:
+            # every live row is an element pair of its own node (a store
+            # holds a tuple once), so the closure is too large before any
+            # of it is built: at 150M rows the columns below are 15 GB
+            self._reset_empty()
+            raise ClosureTooLarge(
+                f"{len(live)} tuples exceed max_pairs={self.max_pairs}"
+            )
         if len(live):
             ns = cols.ns[live].astype(np.int64)
             rel = cols.rel[live].astype(np.int64)

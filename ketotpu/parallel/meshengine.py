@@ -416,6 +416,26 @@ class MeshCheckEngine(DeviceCheckEngine):
     def _served_arrays(self):
         return self._stacked
 
+    @staticmethod
+    def _ship_table(prefix: str, table):
+        """The whole graph's tables stay on the host: what the mesh ships
+        is the shards' stacks."""
+        return table
+
+    def _sizing_counts(self) -> dict:
+        """One chip's share: every shard's arrays pad to the largest
+        shard's shapes, so the largest counts size each chip."""
+        snaps = self._shard_snaps
+        rounds = self.probe_rounds
+        return dict(
+            tuples=max(sn.n_tuples for sn in snaps),
+            nodes=max(sn.n_nodes for sn in snaps),
+            edges=max(sn.n_edges for sn in snaps),
+            subjects=max(len(sn.sub_ns) for sn in snaps),
+            pair_cap=self.shard_pair_cap,
+            nt_rounds=rounds.get("nt", 8), mt_rounds=rounds.get("mt", 8),
+        )
+
     def _sync_view(self):
         """The base's atomic view, with the sharded stacks for device
         arrays.  The stamp is the DRAIN cursor where the base takes the

@@ -35,7 +35,6 @@ recommended); no pickle.
 
 from __future__ import annotations
 
-import itertools
 import os
 import random
 import socket
@@ -76,36 +75,6 @@ def _decode_subject(u: str) -> Subject:
     if u.startswith("set:"):
         return SubjectSet.from_string(u[4:])
     return SubjectID(u[3:] if u.startswith("id:") else u)
-
-
-class _Reverse:
-    """Incremental id -> string view over an append-only Interner.
-
-    ``Interner.strings()`` copies the whole table; at 10M subjects that
-    is milliseconds per call.  Insertion order is id order, so the view
-    only ever EXTENDS from the interner's dict."""
-
-    def __init__(self, interner):
-        self._interner = interner
-        self._rev: List[str] = []
-
-    def get(self, i: int) -> Optional[str]:
-        if i < 0:
-            return None
-        if i >= len(self._rev):
-            ids = self._interner._ids
-            if len(ids) > len(self._rev):
-                try:
-                    self._rev.extend(
-                        itertools.islice(ids.keys(), len(self._rev), None)
-                    )
-                except RuntimeError:
-                    # the engine thread interned mid-iteration; fall back
-                    # to a consistent full copy
-                    self._rev = self._interner.strings()
-        if i >= len(self._rev):
-            return None
-        return self._rev[i]
 
 
 class EngineHostServer:
@@ -235,11 +204,13 @@ class EngineHostServer:
         if vocab is not self._vocab_obj:
             self._vocab_obj = vocab
             self._vepoch += 1
+            # id -> string: ``Interner.string`` extends its own view as
+            # the interner grows (``strings()`` would copy the whole table)
             self._rev = {
-                "ns": _Reverse(vocab.namespaces),
-                "obj": _Reverse(vocab.objects),
-                "rel": _Reverse(vocab.relations),
-                "subj": _Reverse(vocab.subjects),
+                "ns": vocab.namespaces.string,
+                "obj": vocab.objects.string,
+                "rel": vocab.relations.string,
+                "subj": vocab.subjects.string,
             }
         return vocab, self._vepoch
 
@@ -286,10 +257,10 @@ class EngineHostServer:
         if pos_ids:
             rev = self._rev
             for row, pos in zip(np.asarray(ids, dtype=np.int64), pos_ids):
-                ns = rev["ns"].get(int(row[0]))
-                obj = rev["obj"].get(int(row[1]))
-                rel = rev["rel"].get(int(row[2]))
-                subj = rev["subj"].get(int(row[3]))
+                ns = rev["ns"](int(row[0]))
+                obj = rev["obj"](int(row[1]))
+                rel = rev["rel"](int(row[2]))
+                subj = rev["subj"](int(row[3]))
                 if ns is None or obj is None or rel is None or subj is None:
                     raise ValueError("id row outside the owner vocabulary")
                 tuples[int(pos)] = RelationTuple(

@@ -126,14 +126,14 @@ class TestVocabEncodeParity:
         ]
         for t in tuples:
             voc.intern_tuple(t)
-        # force a table build, then intern more WITHOUT doubling
+        # force a pack, then intern more WITHOUT doubling
         voc.subjects.lookup_many([t.subject.unique_id() for t in tuples])
-        assert voc.subjects._tab is not None
+        assert voc.subjects._base is not None
         late = [_mk_tuple("n0", f"late{i}", "r0",
                           SubjectID(id=f"late-u{i}")) for i in range(16)]
         for t in late:
             voc.intern_tuple(t)
-        assert len(voc.subjects) < 2 * voc.subjects._tab_n  # no rebuild yet
+        assert len(voc.subjects) < 2 * voc.subjects._base.n  # no repack yet
         self._assert_parity(voc, tuples + late)
 
     def test_property_randomized_tuple_strings(self):

@@ -368,3 +368,24 @@ def test_config_defaults_and_env_override():
         Provider({"engine": {"fused_retry_lanes": -1}}, env={})
     with pytest.raises(ConfigError):
         Provider({"engine": {"fused_dispatch": "yes"}}, env={})
+
+
+def test_probe_gather_counter_moves_by_lookup_gathers_a_wave(eager):
+    """``keto_fused_probe_gathers_total{table}``: every collected fused
+    wave adds each served table's ``lookup_gathers`` (engine/hashtab.py),
+    so the counter over the waves is the gathers a lookup cost them."""
+    from ketotpu.engine import hashtab
+
+    oracle, fused, plain, _ = make_pair(None, MIXED_TUPLES, opl=OPL_MIXED)
+    fused.snapshot()
+    per_wave = {p: hashtab.lookup_gathers(hashtab.subtables(
+        fused._device_arrays, p + "_")) for p in hashtab.TABLES}
+    assert per_wave["nt"] == fused.probe_rounds["nt"] + 3
+    assert per_wave["mt"] == fused.probe_rounds["mt"] + 2
+    assert set(fused.fused_probe_gathers.values()) == {0}
+    for waves in (1, 2, 3):
+        fused.batch_check([T(q) for q in mixed_queries()[:6]])
+        assert fused.fused_waves == waves
+        assert fused.fused_probe_gathers == {
+            p: waves * g for p, g in per_wave.items()}
+    assert plain.fused_probe_gathers == dict.fromkeys(hashtab.TABLES, 0)

@@ -331,3 +331,100 @@ def test_projection_stats_show_the_served_tables():
         assert st["tag_salt"] == 0
     assert ps["tables"]["ovt"]["rounds"] == H.PROBE_SHALLOW
     assert set(ps["tag_rejects"]) == {"build", "splice", "overlay"}
+
+
+# -- a table filled to its pad (PR 35) ----------------------------------------
+
+
+@pytest.fixture(scope="module")
+def full_table():
+    """A lean table at a load of 0.99, as the node table of a 150M-tuple
+    graph runs (132.7M keys in 134.2M buckets, rounds 11): 2.07M random
+    keys over the whole int32 range in 2^21 buckets under the one salt a
+    big table takes, plus twelve keys dealt into one bucket, so the
+    deepest bucket (the ``pw`` shape) is 12 at a size a test can build."""
+    n, buckets = 2_076_000, 1 << 21
+    rng = np.random.default_rng(35)
+    a = rng.integers(0, 2**31, n, dtype=np.int64).astype(np.int32)
+    b = rng.integers(0, 2**31, n, dtype=np.int64).astype(np.int32)
+    a[:3], b[:3] = [2**31 - 1, 0, 2**31 - 1], [2**31 - 1, 2**31 - 1, 0]
+    h = H._mix_np(a, b, H._SALTS[0]) & np.uint32(buckets - 1)
+    deep = np.flatnonzero(h == h[5])
+    extra_a = rng.integers(0, 2**31, 1 << 25, dtype=np.int64).astype(np.int32)
+    extra_b = rng.integers(0, 2**31, 1 << 25, dtype=np.int64).astype(np.int32)
+    at = np.flatnonzero(
+        (H._mix_np(extra_a, extra_b, H._SALTS[0]) & np.uint32(buckets - 1))
+        == h[5])[: 12 - len(deep)]
+    a, b = np.concatenate([a, extra_a[at]]), np.concatenate([b, extra_b[at]])
+    t = H.build_table(a, b, np.arange(len(a), dtype=np.int32), lean=True,
+                      probe=2 * H.SNAPSHOT_PROBE)
+    assert len(t["ptr"]) == buckets + 1 and len(a) / buckets > 0.989
+    return t, a, b
+
+
+def test_a_full_table_unrolls_its_deepest_bucket(full_table):
+    t, a, b = full_table
+    assert t["pw"].shape[0] >= 11
+    assert t["pw"].shape[0] == int(np.diff(t["ptr"]).max())
+    assert H.lookup_gathers(t) == t["pw"].shape[0] + 3
+    assert int(t["meta"][2]) == 0 and H.TAG_REJECTS["build"] >= 0
+
+
+def test_full_table_host_lookups_find_every_key(full_table):
+    t, a, b = full_table
+    val, found = H.lookup_np(t, a, b)
+    assert found.all()
+    # a duplicate random key may answer with its twin's payload
+    same = val == np.arange(len(a))
+    assert same.mean() > 0.999 and (a[val[~same]] == a[~same]).all()
+    miss_v, miss = H.lookup_np(t, a[:4096] ^ 1, b[:4096])
+    present = {(int(x), int(y)) for x, y in zip(a[:4096] ^ 1, b[:4096])} & {
+        (int(x), int(y)) for x, y in zip(a, b)}
+    assert int(miss.sum()) == len(present) and (miss_v[~miss] == -1).all()
+    for i in list(range(0, len(a), 70_001)) + [0, 1, 2, len(a) - 1]:
+        assert H.lookup_one(t, int(a[i]), int(b[i])) == val[i]
+    assert H.lookup_one(t, int(a[9]) ^ 1, int(b[9])) == -1
+    assert H.lookup_one(t, -1, 5) == -1
+    assert len(H.repeated_keys(t)) == int((~same).sum())
+
+
+def test_full_table_device_lookup_is_the_hosts(full_table):
+    t, a, b = full_table
+    at = np.r_[0:3, len(a) - 12:len(a), 1000:1049]  # the edges, the deep bucket
+    qa = np.concatenate([a[at], a[at] ^ 1, [-1]]).astype(np.int32)
+    qb = np.concatenate([b[at], b[at], [7]]).astype(np.int32)
+    fn = jax.jit(lambda t, x, y: H.lookup(t, x, y))
+    val, found = fn(t, qa, qb)
+    want_v, want_f = H.lookup_np(t, qa, qb)
+    np.testing.assert_array_equal(np.asarray(found), want_f)
+    np.testing.assert_array_equal(np.asarray(val), want_v)
+    assert want_f[: len(at)].all() and not want_f[-1]
+    text = fn.lower(t, qa, qb).as_text()
+    gathers = len(re.findall(r'= "?stablehlo\.gather\b', text))
+    assert gathers == H.lookup_gathers(t) >= 14
+
+
+def test_wave_gathers_reads_the_served_tables_shapes():
+    a, b = _keys(30, 21)
+    arrays = {}
+    for prefix, val, rounds in (("nt", True, 9), ("mt", False, 8),
+                                ("ovt", True, 4)):
+        t = H.build_table(a, b, np.arange(30, dtype=np.int32) if val else None,
+                          probe=rounds, fixed_shape=(256, 64))
+        arrays.update({f"{prefix}_{k}": v for k, v in t.items()})
+    assert H.wave_gathers(arrays) == {"nt": 12, "mt": 10, "ovt": 7}
+    assert H.wave_gathers(arrays) == {
+        p: H.lookup_gathers(H.subtables(arrays, p + "_"))
+        for p in ("nt", "mt", "ovt")}
+
+
+def test_grouped_order_groups_every_bucket_on_a_big_table():
+    """Above 2^21 entries the build deals the entries into 256 ranges and
+    sorts each on the pool: every bucket's entries stay contiguous."""
+    rng = np.random.default_rng(36)
+    n, buckets = (1 << 21) + 12345, 1 << 20
+    h = rng.integers(0, buckets, n).astype(np.uint32)
+    order = H._grouped_order(h, buckets)
+    hs = h[order]
+    assert (np.diff(hs.astype(np.int64)) >= 0).all()
+    assert np.array_equal(np.sort(order), np.arange(n))

@@ -290,3 +290,28 @@ def test_cli_list_against_worker_topology(tmp_path, capsys):
             except ProcessLookupError:
                 pass
             proc.wait(timeout=5)
+
+
+@pytest.mark.parametrize("rows,too_large", [(40, False), (41, True)])
+def test_closure_larger_than_max_pairs_is_refused_before_it_is_built(
+        rows, too_large):
+    """Every live tuple is an element pair of its own node, so a store of
+    more rows than ``max_pairs`` cannot be indexed: the build says so
+    before it gathers a column (at 150M rows those are 15 GB), and the
+    engine serves without the index."""
+    from ketotpu.engine import delta as dl
+    from ketotpu.engine.vocab import Vocab
+    from ketotpu.leopard import closure as leo
+
+    cols = dl.TupleColumns(Vocab())
+    for i in range(rows):
+        cols.apply(1, RelationTuple("Group", f"g{i % 7}", "members",
+                                    SubjectID(f"u{i}")))
+    idx = leo.ClosureIndex(max_pairs=40)
+    if too_large:
+        with pytest.raises(leo.ClosureTooLarge, match="41 tuples exceed"):
+            idx.build_from_cols(cols, None)
+        assert idx.pairs == 0 and idx.n_nodes == 0
+    else:
+        idx.build_from_cols(cols, None)
+        assert idx.pairs == rows and idx.n_nodes == 7

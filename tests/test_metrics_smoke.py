@@ -243,9 +243,22 @@ def test_projection_metric_vocabulary(scrape):
         'keto_projection_table_lookup_gathers{table="mt"}',
         'keto_projection_table_tag_salt{table="ovt"}',
         'keto_projection_tag_rejects_total{op="splice"}',
+        # PR 35: what the served projection takes on the device, the
+        # gathers a lookup cost the fused waves, the host's lazy builds
+        'keto_projection_device_bytes{group="node_table",kind="padded"}',
+        'keto_projection_device_bytes{group="csr",kind="live"}',
+        'keto_fused_probe_gathers_total{table="nt"}',
+        'keto_fused_probe_gathers_total{table="om"}',
+        'keto_host_lazy_build_seconds_total{what="vocab_index"}',
+        'keto_host_lazy_build_seconds_total{what="store_fwd"}',
     ):
         assert g in text, g
     proj = scrape["projection"]
+    assert {"csr", "node_table", "membership_table", "overlay", "leopard",
+            "membership", "expand_only", "mesh_only"} == set(
+                proj["device_bytes"])
+    assert all(0 <= g["live"] <= g["padded"]
+               for g in proj["device_bytes"].values())
     assert set(proj["tables"]) == {"nt", "mt", "ovt", "om"}
     assert all(
         t["lookup_gathers"] <= t["rounds"] + 3 and t["tag_salt"] == 0
