@@ -1638,6 +1638,21 @@ class Registry:
                              "largest shard's); above 0 the tag invariant "
                              "walked it",
                         table=table)
+                m.gauge("keto_projection_table_split_buckets",
+                        st["split_buckets"],
+                        help="buckets deeper than the probe rounds, split "
+                             "in place so that every lookup probes the "
+                             "same rounds (0 for the overlay's tables)",
+                        table=table)
+                m.gauge("keto_projection_table_split_level_max",
+                        st["split_level_max"],
+                        help="deepest split level of the table's buckets "
+                             "(a bucket of level s has 2^s parts)",
+                        table=table)
+                m.gauge("keto_projection_table_pad_slots", st["pad_slots"],
+                        help="slots left empty between the parts of split "
+                             "buckets",
+                        table=table)
             for group, sizes in ps["device_bytes"].items():
                 for kind, nbytes in sizes.items():
                     m.gauge("keto_projection_device_bytes", nbytes,
@@ -1647,9 +1662,11 @@ class Registry:
                             group=group, kind=kind)
             for op, times in ps["tag_rejects"].items():
                 m.gauge("keto_projection_tag_rejects_total", times,
-                        help="times two keys of one bucket shared a tag: a "
-                             "build or an overlay build took another tag "
-                             "salt, a splice fell back to a full build",
+                        help="times a table's layout refused its keys: two "
+                             "of one bucket shared a tag (a build or an "
+                             "overlay build took another tag salt, a splice "
+                             "fell back to a full build), or no split level "
+                             "separated a bucket (another split salt)",
                         op=op)
         # demand-adaptive scheduling state: EMA frontier occupancy per BFS
         # level (units of active roots), for the fast path and the general

@@ -39,8 +39,11 @@ from ketotpu.engine.vocab import Interner, Vocab
 #: checkpoint's padded columns would break the fold path's exact-length
 #: merges; v6: the hash tables store ``tag``/``key_b`` and a tag salt in
 #: ``meta`` (engine/hashtab.py) — a v5 table has ``key_a``/``key_b`` and
-#: no tag column for the lookups to gather)
-SNAPSHOT_FORMAT = 6
+#: no tag column for the lookups to gather; v7: ``ptr`` packs a bucket's
+#: split level over its offset and ``meta`` grew the split salt and the
+#: build's counts — a v6 table's deeper buckets would be missed by the
+#: four rounds every lookup probes now)
+SNAPSHOT_FORMAT = 7
 
 _SCALARS = ("num_rels", "n_nodes", "n_edges", "n_tuples", "version")
 _ARRAYS = (

@@ -884,8 +884,8 @@ class CoalescingEngine:
         fused["tiers"] = {
             t: int(d) for t, d in _gained(tiers_now, ftiers).items()
         }
-        # probe rounds a lookup of the served tables unrolls (a deeper
-        # node table is a longer wave: engine/hashtab.py)
+        # probe rounds a lookup of the served tables unrolls (their
+        # builders' constant, whatever they hold: engine/hashtab.py)
         fused["rounds"] = dict(getattr(self.inner, "probe_rounds", None) or {})
         self.ledger.record({
             "wave": wave_id,

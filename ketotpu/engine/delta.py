@@ -437,13 +437,13 @@ def build_snapshot_cols(
         node_hi,
         node_lo,
         np.arange(n_nodes, dtype=np.int32),
-        lean=True, probe=2 * hashtab.SNAPSHOT_PROBE,
+        lean=True, probe=hashtab.SNAPSHOT_PROBE,
     )
     if table_sink is not None:
         node_tab = table_sink("nt", node_tab)
     mem_tab = hashtab.build_table(
         mem_node_v, mem_subj_v,
-        lean=True, probe=2 * hashtab.SNAPSHOT_PROBE,
+        lean=True, probe=hashtab.SNAPSHOT_PROBE,
     )
     if table_sink is not None:
         mem_tab = table_sink("mt", mem_tab)
@@ -1193,7 +1193,7 @@ def fold_snapshot_cols(
         node_tab = hashtab.build_table(
             node_hi1, node_lo1,
             np.arange(n_nodes1, dtype=np.int32),
-            lean=True, probe=2 * hashtab.SNAPSHOT_PROBE,
+            lean=True, probe=hashtab.SNAPSHOT_PROBE,
         )
     mem_tab = None
     if not renumbered:
@@ -1215,7 +1215,7 @@ def fold_snapshot_cols(
     if mem_tab is None:
         mem_tab = hashtab.build_table(
             mem_node1, mem_subj1,
-            lean=True, probe=2 * hashtab.SNAPSHOT_PROBE,
+            lean=True, probe=hashtab.SNAPSHOT_PROBE,
         )
     t0 = _mark("fold_hashtab", t0)
 

@@ -51,8 +51,9 @@ of the child's object, so only the owner can probe it.
 Kernel strategy (SURVEY §7 step 6, measured on a v5 lite chip): the
 per-level cost is bounded by random 1-D gathers from HBM tables, and the
 hash probes are most of them: `probe/node_table` and `probe/mem_table`
-hold 76 % of the 1024-row mixed wave's device time and 82 % of the
-singles' (PERF.md §5, traces of PR 30 and PR 32).  A gather costs 12-13 ns
+held 66 % of the 1024-row mixed wave's device time and 75 % of the
+singles' before a lookup's rounds were bounded at four (PERF.md §5,
+traces of PR 34 and PR 35).  A gather costs 12-13 ns
 an element whatever it reads (micro-run, PR 34: 80 us at 6,144 slots, 18
 us at 1,536), so a lookup's time is its gather count times its slots
 (`hashtab.lookup_gathers`).  Pallas/Mosaic
@@ -122,17 +123,11 @@ def _node_lookup(g: Dict[str, jax.Array], ns, obj, rel):
     hi = ns * num_rels + rel
     ok = (ns >= 0) & (obj >= 0) & (rel >= 0)
     with jax.named_scope("probe/node_table"):
-        idx, found = hashtab.lookup(
-            hashtab.subtables(g, "nt_"), hi, obj,
-            probe=hashtab.SNAPSHOT_PROBE,
-        )
+        idx, found = hashtab.lookup(hashtab.subtables(g, "nt_"), hi, obj)
         found = found & ok
         res = jnp.where(found, idx, -1)
         if "ovt_ptr" in g:
-            vid, vfound = hashtab.lookup(
-                hashtab.subtables(g, "ovt_"), hi, obj,
-                probe=hashtab.PROBE_SHALLOW,
-            )
+            vid, vfound = hashtab.lookup(hashtab.subtables(g, "ovt_"), hi, obj)
             res = jnp.where(ok & vfound & ~found, vid, res)
     return res.astype(jnp.int32)
 
@@ -142,15 +137,9 @@ def _member(g: Dict[str, jax.Array], node, subj):
     Overlay-exact: base OR added-since-base AND NOT deleted-since-base, so
     probe verdicts always reflect the latest write."""
     with jax.named_scope("probe/mem_table"):
-        _, found = hashtab.lookup(
-            hashtab.subtables(g, "mt_"), node, subj,
-            probe=hashtab.SNAPSHOT_PROBE,
-        )
+        _, found = hashtab.lookup(hashtab.subtables(g, "mt_"), node, subj)
         if "om_ptr" in g:
-            v, vf = hashtab.lookup(
-                hashtab.subtables(g, "om_"), node, subj,
-                probe=hashtab.PROBE_SHALLOW,
-            )
+            v, vf = hashtab.lookup(hashtab.subtables(g, "om_"), node, subj)
             found = (
                 (found | (vf & (v == OV_ADDED))) & ~(vf & (v == OV_DELETED))
             )

@@ -86,7 +86,6 @@ def check_caps(**counts: int) -> None:
 def device_bytes(
     *, tuples: int, nodes: int, edges: int, subjects: int,
     pair_cap: int = 4096, leopard_pairs: int = 0,
-    nt_rounds: int = 8, mt_rounds: int = 8, overlay_rounds: int = 4,
 ) -> Dict[str, Dict[str, int]]:
     """What a projection of these counts takes on the device, by group of
     arrays (:data:`DEVICE_GROUPS`): ``padded`` is the bytes of the arrays
@@ -95,7 +94,8 @@ def device_bytes(
     their exact lengths.  It mirrors the padding rules of this module and
     of engine/delta.py (``_bucket``), engine/hashtab.py (``build_table``:
     a lean table has the power of two at or above its entries in buckets
-    and in capacity) and leopard/device.py; ``tests/test_sizing.py`` holds
+    and in capacity; the empty slots of its split buckets, a thousandth of
+    the entries, are reckoned as none) and leopard/device.py; ``tests/test_sizing.py`` holds
     it to the ``nbytes`` of the arrays a build really makes.  The compiled
     rewrite programs (op and flat tables, a few KB) are left out.  An
     operator reads it before a load (how many tuples fit a chip), the
@@ -103,17 +103,18 @@ def device_bytes(
     npad, epad = _bucket(nodes), _bucket(edges)
     mpad, spad = _bucket(tuples), _bucket(max(subjects, 1))
 
-    def table(n: int, cols: int, rounds: int) -> Dict[str, int]:
-        # ptr, then tag / key_b (/ val), meta int32[3], pw int8[rounds]
+    def table(n: int, cols: int) -> Dict[str, int]:
+        # ptr, then tag / key_b (/ val), meta int32[7], pw int8[4]: the
+        # rounds are the builders' constants, whatever a table holds
         buckets = hashtab._bucket_pow2(max(n, 1), 128)
         cap = hashtab._bucket_pow2(max(n, 1), 64)
-        fixed = 12 + rounds
+        fixed = 28 + hashtab.SNAPSHOT_PROBE
         return {"live": 4 * (n + 1) + 4 * cols * n + fixed,
                 "padded": 4 * (buckets + 1) + 4 * cols * cap + fixed}
 
     pair_cap = max(1, pair_cap)
     # a fixed-shape delta table: 4 x pair_cap buckets, three columns
-    delta_tab = 4 * (4 * pair_cap + 1) + 12 * pair_cap + 12 + overlay_rounds
+    delta_tab = 4 * (4 * pair_cap + 1) + 12 * pair_cap + 28 + hashtab.PROBE_SHALLOW
     dirty = _bucket(nodes + pair_cap + 1, 64)
     lpad = 0
     if leopard_pairs:
@@ -124,8 +125,8 @@ def device_bytes(
         # row_ptr, edge_hi, edge_obj
         "csr": {"live": 4 * (nodes + 1) + 8 * edges,
                 "padded": 4 * (npad + 1) + 8 * epad},
-        "node_table": table(nodes, 3, nt_rounds),
-        "membership_table": table(tuples, 2, mt_rounds),
+        "node_table": table(nodes, 3),
+        "membership_table": table(tuples, 2),
         # ov_dirty (a bool a node and a virtual node), ov_nbase, and the
         # two fixed-shape delta tables om_, ovt_
         "overlay": {"live": nodes + pair_cap + 1 + 4 + 2 * delta_tab,
@@ -444,12 +445,12 @@ def build_snapshot(
         np.fromiter((k[0] for k in uniq), np.int64, n_nodes),
         np.fromiter((k[1] for k in uniq), np.int64, n_nodes),
         np.arange(n_nodes, dtype=np.int32),
-        lean=True, probe=2 * hashtab.SNAPSHOT_PROBE,
+        lean=True, probe=hashtab.SNAPSHOT_PROBE,
     )
     mem_tab = build_table(
         np.fromiter((p[0] for p in pairs), np.int64, n_tuples),
         np.fromiter((p[1] for p in pairs), np.int64, n_tuples),
-        lean=True, probe=2 * hashtab.SNAPSHOT_PROBE,
+        lean=True, probe=hashtab.SNAPSHOT_PROBE,
     )
 
     snap = Snapshot(

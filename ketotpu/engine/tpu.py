@@ -973,9 +973,10 @@ class DeviceCheckEngine:
                 "device_bytes": self._device_bytes(),
             }
         # the served hash tables, as the device programs unroll them
-        # (engine/hashtab.py): probe rounds, gathers a lookup, and the tag
-        # salt (above 0: the invariant walked it).  Read off the lock: the
-        # salt is a fetch from the device
+        # (engine/hashtab.py): probe rounds, gathers a lookup, the tag
+        # salt (above 0: the invariant walked it) and what the build split
+        # to keep the rounds (buckets, deepest level, empty slots).  Read
+        # off the lock: ``meta`` is a fetch from the device
         out["tables"] = {
             p: hashtab.table_stats(hashtab.subtables(arrays, p + "_"))
             for p in hashtab.TABLES
@@ -997,7 +998,7 @@ class DeviceCheckEngine:
         """What :func:`snapshot.device_bytes` takes, of the served view
         (the mesh engine overrides: a chip holds the largest shard's
         shapes)."""
-        snap, rounds = self._snap, self.probe_rounds
+        snap = self._snap
         leo = self._leopard if self._leo_device is not None else None
         return dict(
             tuples=snap.n_tuples, nodes=snap.n_nodes, edges=snap.n_edges,
@@ -1006,7 +1007,6 @@ class DeviceCheckEngine:
             subjects=min(len(snap.vocab.subjects), len(snap.sub_ns)),
             pair_cap=self.max_overlay_pairs,
             leopard_pairs=len(leo.elt_packed) if leo is not None else 0,
-            nt_rounds=rounds.get("nt", 8), mt_rounds=rounds.get("mt", 8),
         )
 
     @property

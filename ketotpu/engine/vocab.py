@@ -198,8 +198,9 @@ class _Packed:
         del a, b
         # the entry columns at their exact length: the pad to a power of
         # two is for device shapes, and this table never leaves the host
+        used = max(hashtab.slots_in_use(self.tab), 1)
         for col in ("tag", "key_b", "val"):
-            self.tab[col] = self.tab[col][:max(n, 1)].copy()
+            self.tab[col] = self.tab[col][:used].copy()
         # strings whose masked hash an earlier string has too: the table
         # finds only the first of such a pair
         self.extra: Dict[str, int] = {}

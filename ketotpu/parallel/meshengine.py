@@ -426,14 +426,12 @@ class MeshCheckEngine(DeviceCheckEngine):
         """One chip's share: every shard's arrays pad to the largest
         shard's shapes, so the largest counts size each chip."""
         snaps = self._shard_snaps
-        rounds = self.probe_rounds
         return dict(
             tuples=max(sn.n_tuples for sn in snaps),
             nodes=max(sn.n_nodes for sn in snaps),
             edges=max(sn.n_edges for sn in snaps),
             subjects=max(len(sn.sub_ns) for sn in snaps),
             pair_cap=self.shard_pair_cap,
-            nt_rounds=rounds.get("nt", 8), mt_rounds=rounds.get("mt", 8),
         )
 
     def _sync_view(self):

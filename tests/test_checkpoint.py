@@ -103,6 +103,26 @@ def test_format_mismatch_is_refused(graph, tmp_path, monkeypatch):
     assert fresh.load_checkpoint(path) is False  # graceful refusal
 
 
+def test_a_format_6_checkpoint_is_refused(graph, tmp_path):
+    """A file of the format before the split layout: its tables' deeper
+    buckets would be missed by four rounds, so it is refused by its
+    number (the loader never looks at the columns)."""
+    assert ckpt.SNAPSHOT_FORMAT == 7
+    eng = _engine(graph)
+    path = str(tmp_path / "snap.npz")
+    eng.save_checkpoint(path)
+    with np.load(path, allow_pickle=False) as z:
+        files = {k: z[k] for k in z.files}
+    assert int(files["format"]) == 7 and files["nt_meta"].shape == (7,)
+    files["format"] = np.int64(6)
+    old = str(tmp_path / "v6.npz")
+    np.savez(old, **files)
+    with pytest.raises(ckpt.SnapshotFormatError, match="format 6"):
+        ckpt.load_snapshot(old)
+    assert _engine(graph).load_checkpoint(old) is False
+    assert _engine(graph).load_checkpoint(path) is True
+
+
 def test_registry_boot_checkpoint_cycle(tmp_path):
     """engine.checkpoint config: first boot saves, second boot resumes."""
     from ketotpu.driver import Provider, Registry

@@ -456,7 +456,17 @@ def test_scale_parity_low_fallback():
     graph that is NOT toy-sized, with the device excusing <5% of queries.
     The bench's 1M-tuple figure runs on real hardware; this is the
     CPU-suite guard that correctness and capacity hold beyond toys."""
+    import jax
+
     from ketotpu.utils.synth import build_synth, synth_queries
+
+    # this file's largest program comes last, after some forty others in
+    # the same process: XLA:CPU's codegen aborts or segfaults in
+    # backend_compile_and_load once a process holds enough of them
+    # (tests/conftest.py; each program is fine in a fresh process, and the
+    # split decode of engine/hashtab.py made every lookup a dozen integer
+    # operations longer, which crossed that edge here).  Let them go first.
+    jax.clear_caches()
 
     g = build_synth(
         n_users=2000, n_groups=100, n_folders=2000, n_docs=15000, seed=5

@@ -1,9 +1,12 @@
 """The bucketed hash tables (engine/hashtab.py): a probe round gathers the
 tag column alone, the key is verified once at the first tag hit, and the
 answers stay exact because no bucket holds two different keys of one tag.
+A lookup probes the rounds its builder asked for whatever the table holds:
+a bucket of more keys is split in place, its level in the pointer.
 
-Every table here has one shape (128 buckets, 64 entries, 8 rounds, a
-payload), so the cases share ONE jitted ``lookup`` on XLA:CPU.
+The small tables here have one shape (128 buckets, 64 entries, 8 rounds,
+a payload) and the loaded ones another (65,536 buckets and entries, 4
+rounds), so the cases share TWO jitted ``lookup`` programs on XLA:CPU.
 """
 
 import re
@@ -139,23 +142,65 @@ CASES = {
 
 def _entries(t):
     """{key: payloads} of a table, from its own columns: ``key_a`` is the
-    tag XOR ``f(key_b)`` (stored nowhere)."""
-    n = int(t["ptr"][-1])
+    tag XOR ``f(key_b)`` (stored nowhere); a slot whose ``key_b`` is -1 is
+    empty."""
+    n = H.slots_in_use(t)
     b = t["key_b"][:n]
     a = H._tag_np(t["tag"][:n], b, H._SALTS[int(t["meta"][2])])
+    assert (t["key_b"][n:] == -1).all() and (t["tag"][n:] == -1).all()
     out = {}
     for ka, kb, v in zip(a.tolist(), b.tolist(), t["val"][:n].tolist()):
-        out.setdefault((ka, kb), set()).add(v)
+        if kb >= 0:
+            out.setdefault((ka, kb), set()).add(v)
     return out
+
+
+def _hold_the_layout(t):
+    """The module docstring's invariants, bucket by bucket: a first entry
+    lies in its part's window (1), parts lie in order, an empty slot lies
+    behind the keys of every window that covers it (2), no two keys of a
+    bucket share a tag (3), the other entries of a run of equal keys lie
+    behind the last part (4); and ``meta`` counts what is there."""
+    probe = t["pw"].shape[0]
+    stride = max(probe // 2, 1)
+    off = H._offsets(t["ptr"]).astype(np.int64)
+    lev = H._levels(t["ptr"])
+    meta, kb, tg = t["meta"], t["key_b"], t["tag"]
+    assert (np.diff(off) >= 0).all() and lev[-1] == 0
+    assert not H._tag_clash(off, tg, kb, probe)
+    assert int(meta[4]) == int((lev > 0).sum())
+    assert int(meta[5]) == int(lev.max())
+    assert int(meta[6]) == int((kb[:off[-1]] < 0).sum())
+    for bkt in np.flatnonzero((np.diff(off) > probe) | (lev[:-1] > 0)):
+        lo, hi = off[bkt], off[bkt + 1]
+        b = kb[lo:hi]
+        a = H._tag_np(tg[lo:hi], b, H._SALTS[int(meta[2])])
+        part = (H._split_np(a, b, H._SALTS[int(meta[3])])
+                & np.uint32((1 << int(lev[bkt])) - 1)).tolist()
+        firsts, seen, behind = [], set(), False
+        for i, key in enumerate(zip(a.tolist(), b.tolist())):
+            if key[1] < 0:
+                assert not behind, "an empty slot among the repeated entries"
+            elif key in seen:
+                behind = True
+            else:
+                assert not behind, "a key's first entry behind the last part"
+                seen.add(key)
+                assert not firsts or part[i] >= part[firsts[-1]]
+                assert part[i] * stride <= i < part[i] * stride + probe
+                firsts.append(i)
+        for i in np.flatnonzero(b < 0).tolist():
+            assert all(part[j] * stride > i for j in firsts if j > i)
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_lookup_is_lookup_np_is_a_dict(case):
     t, qa, qb = CASES[case]()
     assert {k: v.shape for k, v in t.items()} == {
-        "ptr": (129,), "tag": (64,), "key_b": (64,), "meta": (3,),
+        "ptr": (129,), "tag": (64,), "key_b": (64,), "meta": (7,),
         "pw": (8,), "val": (64,),
     }
+    _hold_the_layout(t)
     want = _entries(t)
     val, found = _answers(t, qa, qb)
     for a, b, v, f in zip(np.asarray(qa).tolist(), np.asarray(qb).tolist(),
@@ -193,10 +238,12 @@ def test_two_keys_of_one_bucket_and_tag_take_another_tag_salt():
     t = H.build_table(a, b, np.arange(21, dtype=np.int32))
     assert int(t["meta"][0]) == 0, "the twin was made for bucket salt 0"
     assert int(t["meta"][2]) == 1 and H.TAG_REJECTS["build"] == before + 1
-    assert not H._tag_clash(t["ptr"], t["tag"], t["key_b"], 21, 8)
+    assert not H._tag_clash(H._offsets(t["ptr"]), t["tag"], t["key_b"], 8)
     val, found = _answers(t, a, b)
     assert found.all() and (val == np.arange(21)).all()
-    assert H.table_stats(t) == {"rounds": 8, "lookup_gathers": 11, "tag_salt": 1}
+    assert H.table_stats(t) == {
+        "rounds": 8, "lookup_gathers": 11, "tag_salt": 1,
+        "split_buckets": 0, "split_level_max": 0, "pad_slots": 0}
 
 
 @pytest.mark.parametrize("fixed", [SHAPE, None], ids=["fixed_shape", "grown"])
@@ -255,8 +302,7 @@ def test_spliced_table_answers_like_a_rebuilt_one(case):
     got = H.splice_table(t, ra, rb, aa, ab, av, val_remap=remap)
     assert got is not None
     assert {k: x.shape for k, x in got.items()} == {k: x.shape for k, x in t.items()}
-    assert not H._tag_clash(got["ptr"], got["tag"], got["key_b"],
-                            int(got["ptr"][-1]), 8)
+    _hold_the_layout(got)
     want = _entries(t)
     for key in zip(np.asarray(ra).tolist(), np.asarray(rb).tolist()):
         if len(want[key]) == 1:
@@ -330,19 +376,22 @@ def test_projection_stats_show_the_served_tables():
         assert st["lookup_gathers"] == 1 + st["rounds"] + 1 + (name != "mt")
         assert st["tag_salt"] == 0
     assert ps["tables"]["ovt"]["rounds"] == H.PROBE_SHALLOW
-    assert set(ps["tag_rejects"]) == {"build", "splice", "overlay"}
+    assert ps["tables"]["nt"]["rounds"] == H.SNAPSHOT_PROBE
+    assert ps["tables"]["mt"]["rounds"] == H.SNAPSHOT_PROBE
+    assert all(ps["tables"][p]["split_buckets"] == 0 for p in ("ovt", "om"))
+    assert set(ps["tag_rejects"]) == {"build", "splice", "overlay", "split"}
 
 
-# -- a table filled to its pad (PR 35) ----------------------------------------
+# -- a table filled to its pad (PR 35), probed four rounds (PR 36) ------------
 
 
 @pytest.fixture(scope="module")
 def full_table():
     """A lean table at a load of 0.99, as the node table of a 150M-tuple
-    graph runs (132.7M keys in 134.2M buckets, rounds 11): 2.07M random
-    keys over the whole int32 range in 2^21 buckets under the one salt a
-    big table takes, plus twelve keys dealt into one bucket, so the
-    deepest bucket (the ``pw`` shape) is 12 at a size a test can build."""
+    graph runs (132.7M keys in 134.2M buckets, whose deepest bucket holds
+    11): 2.07M random keys over the whole int32 range in 2^21 buckets,
+    plus twelve keys dealt into one bucket; built at the served tables'
+    probe of four, so what is deeper is split."""
     n, buckets = 2_076_000, 1 << 21
     rng = np.random.default_rng(35)
     a = rng.integers(0, 2**31, n, dtype=np.int64).astype(np.int32)
@@ -357,17 +406,30 @@ def full_table():
         == h[5])[: 12 - len(deep)]
     a, b = np.concatenate([a, extra_a[at]]), np.concatenate([b, extra_b[at]])
     t = H.build_table(a, b, np.arange(len(a), dtype=np.int32), lean=True,
-                      probe=2 * H.SNAPSHOT_PROBE)
+                      probe=H.SNAPSHOT_PROBE)
     assert len(t["ptr"]) == buckets + 1 and len(a) / buckets > 0.989
     return t, a, b
 
 
-def test_a_full_table_unrolls_its_deepest_bucket(full_table):
+def test_a_full_table_probes_four_rounds_whatever_it_holds(full_table):
+    """What PR 35 unrolled as 12 rounds: the rounds are the builder's
+    constant, the capacity is what it was, and the twelve keys of one
+    bucket are parted."""
     t, a, b = full_table
-    assert t["pw"].shape[0] >= 11
-    assert t["pw"].shape[0] == int(np.diff(t["ptr"]).max())
-    assert H.lookup_gathers(t) == t["pw"].shape[0] + 3
-    assert int(t["meta"][2]) == 0 and H.TAG_REJECTS["build"] >= 0
+    assert t["pw"].shape == (H.SNAPSHOT_PROBE,)
+    assert H.lookup_gathers(t) == 7
+    assert len(t["tag"]) == H._bucket_pow2(len(a), 64) == 1 << 21
+    assert int(t["meta"][0]) == int(t["meta"][2]) == int(t["meta"][3]) == 0
+    st = H.table_stats(t)
+    # uniform hashing at this load: 0.35 % of the buckets hold over four
+    assert 0.002 < st["split_buckets"] / (1 << 21) < 0.005
+    assert 2 <= st["split_level_max"] <= 5
+    assert st["pad_slots"] < 0.003 * len(a)
+    assert H.slots_in_use(t) == len(a) + st["pad_slots"]
+    h = int(H._mix_np(a[5:6], b[5:6], H._SALTS[0])[0]) & ((1 << 21) - 1)
+    off = H._offsets(t["ptr"])
+    assert H._levels(t["ptr"])[h] >= 2 and off[h + 1] - off[h] >= 12
+    _hold_the_layout(t)
 
 
 def test_full_table_host_lookups_find_every_key(full_table):
@@ -381,7 +443,8 @@ def test_full_table_host_lookups_find_every_key(full_table):
     present = {(int(x), int(y)) for x, y in zip(a[:4096] ^ 1, b[:4096])} & {
         (int(x), int(y)) for x, y in zip(a, b)}
     assert int(miss.sum()) == len(present) and (miss_v[~miss] == -1).all()
-    for i in list(range(0, len(a), 70_001)) + [0, 1, 2, len(a) - 1]:
+    for i in list(range(0, len(a), 70_001)) + list(range(len(a) - 12, len(a))) \
+            + [0, 1, 2]:
         assert H.lookup_one(t, int(a[i]), int(b[i])) == val[i]
     assert H.lookup_one(t, int(a[9]) ^ 1, int(b[9])) == -1
     assert H.lookup_one(t, -1, 5) == -1
@@ -401,7 +464,7 @@ def test_full_table_device_lookup_is_the_hosts(full_table):
     assert want_f[: len(at)].all() and not want_f[-1]
     text = fn.lower(t, qa, qb).as_text()
     gathers = len(re.findall(r'= "?stablehlo\.gather\b', text))
-    assert gathers == H.lookup_gathers(t) >= 14
+    assert gathers == H.lookup_gathers(t) == 7
 
 
 def test_wave_gathers_reads_the_served_tables_shapes():
@@ -428,3 +491,224 @@ def test_grouped_order_groups_every_bucket_on_a_big_table():
     hs = h[order]
     assert (np.diff(hs.astype(np.int64)) >= 0).all()
     assert np.array_equal(np.sort(order), np.arange(n))
+
+
+# -- the probe depth is a constant of the code (PR 36) ------------------------
+
+LOADED = 1 << 16  # buckets and capacity of every loaded table below
+_LOOKUP4 = jax.jit(lambda t, a, b: H.lookup(t, a, b))
+
+
+def _loaded(load, seed, payload=True):
+    """A lean table of ``load * 65,536`` keys at the served tables' probe,
+    every twentieth key stored twice (the membership table admits that)."""
+    n = int(load * LOADED) - 200
+    rng = np.random.default_rng(seed)
+    a = rng.integers(0, 2**31, n, dtype=np.int64).astype(np.int32)
+    b = rng.integers(0, 2**31, n, dtype=np.int64).astype(np.int32)
+    a, b = np.concatenate([a, a[::20][:200]]), np.concatenate([b, b[::20][:200]])
+    val = np.arange(len(a), dtype=np.int32) if payload else None
+    return H.build_table(a, b, val, lean=True, probe=H.SNAPSHOT_PROBE), a, b
+
+
+@pytest.mark.parametrize("load", [0.56, 0.63, 0.989])
+def test_loaded_table_probes_four_rounds_and_answers_like_a_dict(load):
+    t, a, b = _loaded(load, int(load * 1000))
+    assert len(a) < LOADED and len(a) / LOADED > load - 0.001
+    assert {k: v.shape for k, v in t.items()} == {
+        "ptr": (LOADED + 1,), "tag": (LOADED,), "key_b": (LOADED,),
+        "meta": (7,), "pw": (4,), "val": (LOADED,)}
+    assert len(t["tag"]) == H._bucket_pow2(len(a), 64)
+    st = H.table_stats(t)
+    assert st["rounds"] == 4 and st["lookup_gathers"] == 7
+    assert st["split_buckets"] > 0 and 1 <= st["split_level_max"] <= 5
+    assert H.slots_in_use(t) == len(a) + st["pad_slots"] <= LOADED
+    _hold_the_layout(t)
+    want = _entries(t)
+    assert sum(len(v) for v in want.values()) == len(a)
+    # present (the repeated keys among them), absent, negative
+    qa = np.concatenate([a[:40], a[-200:], a[:200] ^ 1, -a[:20] - 1, a[:20]])
+    qb = np.concatenate([b[:40], b[-200:], b[:200], b[:20], -b[:20] - 1])
+    deep = np.flatnonzero(H._levels(t["ptr"])[:-1] > 0)
+    off = H._offsets(t["ptr"])
+    at = np.concatenate([np.arange(off[d], off[d + 1]) for d in deep[:40]])
+    at = at[t["key_b"][at] >= 0]  # every key of forty split buckets
+    qa = np.concatenate([qa, H._tag_np(t["tag"][at], t["key_b"][at], H._SALTS[0])])
+    qb = np.concatenate([qb, t["key_b"][at]]).astype(np.int32)
+    qa = qa.astype(np.int32)
+    dv, df = (np.asarray(x) for x in _LOOKUP4(t, qa, qb))
+    hv, hf = H.lookup_np(t, qa, qb)
+    np.testing.assert_array_equal(df, hf)
+    np.testing.assert_array_equal(dv, hv)
+    for x, y, v, f in zip(qa.tolist(), qb.tolist(), hv.tolist(), hf.tolist()):
+        one = H.lookup_one(t, x, y)
+        if (x, y) in want:
+            assert f and v in want[(x, y)] and one in want[(x, y)]
+        else:
+            assert not f and v == -1 and one == -1
+    assert hf[:240].all() and hf[480:].all() and not hf[440:480].any()
+    # a key stored twice is found once, and its other entry is set aside
+    rep = H.repeated_keys(t)
+    assert len(rep) >= 200 and (t["key_b"][rep] >= 0).all()
+
+
+def test_graphs_of_different_seeds_share_every_column_shape():
+    """The parent unrolled the deepest bucket, so 5 of graph seeds 0-13
+    were another program: the shapes are the counts' alone now."""
+    shapes = set()
+    for seed in range(6):
+        for load, payload in ((0.56, True), (0.63, False)):
+            t, _, _ = _loaded(load, seed, payload)
+            shapes.add((payload, tuple(sorted(
+                (k, v.shape, str(v.dtype)) for k, v in t.items()))))
+    assert len(shapes) == 2
+
+
+def _one_bucket(n, seed, want_part=None):
+    """``n`` distinct keys of bucket 5 of a 128-bucket table under salt 0
+    (all with ``want_part`` in the split hash's low seven bits, if given),
+    and forty keys of other buckets."""
+    rng = np.random.default_rng(seed)
+    a = rng.integers(0, 2**31, 1 << 20, dtype=np.int64).astype(np.int32)
+    b = rng.permutation(1 << 20).astype(np.int32)
+    keep = _bucket(a, b) == 5
+    if want_part is not None:
+        keep &= (H._split_np(a, b, H._SALTS[0]) & np.uint32(127)) == want_part
+    at = np.flatnonzero(keep)[:n + 1]
+    assert len(at) == n + 1
+    oa, ob = _keys(40, seed + 1)
+    ob = ob + 2**20
+    away = _bucket(oa, ob) != 5
+    return (np.concatenate([a[at[:n]], oa[away]]),
+            np.concatenate([b[at[:n]], ob[away]]), (int(a[at[n]]), int(b[at[n]])))
+
+
+def test_nine_keys_of_one_bucket_are_split_and_all_found():
+    a, b, (xa, xb) = _one_bucket(9, 40)
+    t = H.build_table(a, b, np.arange(len(a), dtype=np.int32),
+                      probe=H.SNAPSHOT_PROBE)
+    assert t["pw"].shape == (4,) and t["tag"].shape == (64,)
+    lev, off = H._levels(t["ptr"]), H._offsets(t["ptr"])
+    assert lev[5] >= 2 and off[6] - off[5] >= 9  # 9 keys: four parts at least
+    assert H.table_stats(t)["split_buckets"] >= 1
+    _hold_the_layout(t)
+    qa, qb = np.append(a[:9], xa).astype(np.int32), np.append(b[:9], xb).astype(np.int32)
+    pa, pb = np.full(Q, -1, np.int32), np.full(Q, -1, np.int32)
+    pa[:10], pb[:10] = qa, qb
+    fn = jax.jit(lambda t, x, y: H.lookup(t, x, y))
+    dv, df = (np.asarray(x) for x in fn(t, pa, pb))
+    hv, hf = H.lookup_np(t, pa, pb)
+    np.testing.assert_array_equal(df, hf)
+    np.testing.assert_array_equal(dv, hv)
+    assert hf[:9].all() and (hv[:9] == np.arange(9)).all()
+    assert not hf[9:].any()  # the tenth key of the bucket was never stored
+    assert [H.lookup_one(t, int(x), int(y)) for x, y in zip(qa, qb)] == [
+        *range(9), -1]
+    # the mechanism, read off the lowered program: the pointer, FOUR tags,
+    # the verify, the payload; one less without a payload
+    text = fn.lower(t, pa, pb).as_text()
+    assert len(re.findall(r'= "?stablehlo\.gather\b', text)) == 7
+    t2 = H.build_table(a, b, probe=H.SNAPSHOT_PROBE)
+    text = fn.lower(t2, pa, pb).as_text()
+    assert len(re.findall(r'= "?stablehlo\.gather\b', text)) == 6
+    assert H.lookup_np(t2, qa, qb)[1].tolist() == [True] * 9 + [False]
+
+
+def test_a_bucket_no_three_bits_separate_walks_the_split_salt():
+    a, b, _ = _one_bucket(5, 41, want_part=77)
+    before = H.TAG_REJECTS["split"]
+    t = H.build_table(a, b, np.arange(len(a), dtype=np.int32),
+                      probe=H.SNAPSHOT_PROBE)
+    assert int(t["meta"][3]) == 1 and H.TAG_REJECTS["split"] == before + 1
+    assert int(t["meta"][0]) == 0 and int(t["meta"][2]) == 0
+    assert H._levels(t["ptr"])[5] >= 1
+    _hold_the_layout(t)
+    val, found = H.lookup_np(t, a, b)
+    assert found.all() and (val == np.arange(len(a))).all()
+    # a fixed-shape table is never split: it keeps its contract
+    with pytest.raises(ValueError, match="no salt fits"):
+        H.build_table(a[:5], b[:5], np.arange(5, dtype=np.int32),
+                      probe=H.SNAPSHOT_PROBE, fixed_shape=(1, 64))
+
+
+def test_no_split_salt_left_raises(monkeypatch):
+    monkeypatch.setattr(
+        H, "_split_np", lambda a, b, salt: np.zeros(np.shape(a), np.uint32))
+    a, b, _ = _one_bucket(5, 42)
+    before = H.TAG_REJECTS["split"]
+    with pytest.raises(ValueError, match="split salt"):
+        H.build_table(a, b, probe=H.SNAPSHOT_PROBE)
+    assert H.TAG_REJECTS["split"] == before + len(H._SALTS)
+
+
+@pytest.mark.parametrize("into", ["full_bucket", "split_bucket", "emptied"])
+def test_splice_into_a_full_or_a_split_bucket_lays_it_anew(into):
+    """What made the parent's splice return ``None`` (a bucket growing past
+    the recorded rounds) and the fold build in full: the splice lays the
+    buckets it touches with the build's own routine."""
+    n = {"full_bucket": 4, "split_bucket": 7, "emptied": 6}[into]
+    a, b, (xa, xb) = _one_bucket(n, 43)
+    v = np.arange(len(a), dtype=np.int32)
+    t = H.build_table(a, b, v, probe=H.SNAPSHOT_PROBE)
+    assert (H._levels(t["ptr"])[5] > 0) == (n > 4)
+    none = np.zeros(0, np.int32)
+    if into == "emptied":  # a split bucket whose keys leave joins its parts
+        got = H.splice_table(t, a[:4], b[:4], none, none, none)
+        a2, b2, v2 = a[4:], b[4:], v[4:]
+        assert H._levels(got["ptr"])[5] == 0
+    else:
+        got = H.splice_table(t, none, none, np.array([xa], np.int32),
+                             np.array([xb], np.int32), np.array([99], np.int32))
+        a2, b2, v2 = np.append(a, xa), np.append(b, xb), np.append(v, 99)
+        assert H._levels(got["ptr"])[5] > 0
+    assert got is not None
+    assert {k: x.shape for k, x in got.items()} == {k: x.shape for k, x in t.items()}
+    _hold_the_layout(got)
+    built = H.build_table(a2, b2, v2.astype(np.int32), probe=H.SNAPSHOT_PROBE)
+    assert _entries(got) == _entries(built)
+    assert H.table_stats(got) == H.table_stats(built)
+    qa = np.concatenate([a, [xa]]).astype(np.int32)
+    qb = np.concatenate([b, [xb]]).astype(np.int32)
+    for x in (H.lookup_np(got, qa, qb), H.lookup_np(built, qa, qb)):
+        np.testing.assert_array_equal(x[1], [(p, q) in _entries(built)
+                                             for p, q in zip(qa.tolist(), qb.tolist())])
+    np.testing.assert_array_equal(H.lookup_np(got, qa, qb)[0],
+                                  H.lookup_np(built, qa, qb)[0])
+
+
+def test_splice_of_a_loaded_table_answers_like_a_rebuilt_one():
+    """A fold's worth of edits on a table at a load of 0.63: 200 inserts
+    and 100 removals, some into split buckets; never ``None``."""
+    t, a, b = _loaded(0.63, 44)
+    rng = np.random.default_rng(45)
+    deep = np.flatnonzero(H._levels(t["ptr"])[:-1] > 0)
+    ia = rng.integers(0, 2**31, 1 << 18, dtype=np.int64).astype(np.int32)
+    ib = rng.integers(0, 2**31, 1 << 18, dtype=np.int64).astype(np.int32)
+    hit = np.isin(H._mix_np(ia, ib, H._SALTS[0]) & np.uint32(LOADED - 1), deep)
+    at = np.r_[np.flatnonzero(hit)[:60], np.flatnonzero(~hit)[:140]]
+    assert hit[at].sum() == 60
+    rm = rng.permutation(len(a) - 200)[:100]
+    got = H.splice_table(t, a[rm], b[rm], ia[at], ib[at],
+                         (100_000 + np.arange(200)).astype(np.int32))
+    assert got is not None
+    assert {k: x.shape for k, x in got.items()} == {k: x.shape for k, x in t.items()}
+    _hold_the_layout(got)
+    want = _entries(t)
+    for i in rm.tolist():
+        key = (int(a[i]), int(b[i]))
+        want[key] = None if len(want[key]) > 1 else want.pop(key) and None
+    want = {k: v for k, v in want.items() if v is not None or k in _entries(got)}
+    for k, x in zip(zip(ia[at].tolist(), ib[at].tolist()), range(100_000, 100_200)):
+        want.setdefault(k, set())
+        if want[k] is not None:
+            want[k].add(x)
+    have = _entries(got)
+    assert have.keys() == want.keys()
+    assert all(v is None or have[k] == v for k, v in want.items())
+    qa, qb = np.concatenate([a, ia[at]]), np.concatenate([b, ib[at]])
+    dv, df = (np.asarray(x) for x in _LOOKUP4(got, qa[-LOADED // 64:], qb[-LOADED // 64:]))
+    hv, hf = H.lookup_np(got, qa, qb)
+    np.testing.assert_array_equal(df, hf[-LOADED // 64:])
+    np.testing.assert_array_equal(dv, hv[-LOADED // 64:])
+    for x, y, vv, f in zip(qa.tolist(), qb.tolist(), hv.tolist(), hf.tolist()):
+        assert f == ((x, y) in have) and (not f or vv in have[(x, y)])

@@ -243,6 +243,11 @@ def test_projection_metric_vocabulary(scrape):
         'keto_projection_table_lookup_gathers{table="mt"}',
         'keto_projection_table_tag_salt{table="ovt"}',
         'keto_projection_tag_rejects_total{op="splice"}',
+        # PR 36: what the build split to keep a lookup at four rounds
+        'keto_projection_table_split_buckets{table="nt"}',
+        'keto_projection_table_split_level_max{table="mt"}',
+        'keto_projection_table_pad_slots{table="om"}',
+        'keto_projection_tag_rejects_total{op="split"}',
         # PR 35: what the served projection takes on the device, the
         # gathers a lookup cost the fused waves, the host's lazy builds
         'keto_projection_device_bytes{group="node_table",kind="padded"}',
@@ -262,9 +267,17 @@ def test_projection_metric_vocabulary(scrape):
     assert set(proj["tables"]) == {"nt", "mt", "ovt", "om"}
     assert all(
         t["lookup_gathers"] <= t["rounds"] + 3 and t["tag_salt"] == 0
+        and t["rounds"] == 4
         for t in proj["tables"].values()
     )
-    assert set(proj["tag_rejects"]) == {"build", "splice", "overlay"}
+    assert all(
+        {"split_buckets", "split_level_max", "pad_slots"} <= set(t)
+        and t["split_level_max"] <= 7 and (t["split_buckets"] > 0) == (
+            t["split_level_max"] > 0)
+        for t in proj["tables"].values()
+    )
+    assert all(proj["tables"][p]["split_buckets"] == 0 for p in ("ovt", "om"))
+    assert set(proj["tag_rejects"]) == {"build", "splice", "overlay", "split"}
     assert proj["generation"] >= 1
     assert proj["rebuilds"] >= 1  # the boot projection
     assert proj["served_cursor"] == proj["log_cursor"]

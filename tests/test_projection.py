@@ -41,20 +41,34 @@ CMP = (
 
 def _host_lookup(t, a, b):
     """Host-side replica of the device probe: same salt/mask bucketing,
-    linear scan within the bucket (an entry's key is its tag and its
-    second half: ``tag = key_a ^ f(key_b)``)."""
-    salt = hashtab._SALTS[int(t["meta"][0])]
-    mask = np.uint32(int(t["meta"][1]))
-    h = int(hashtab._mix_np(np.array([a]), np.array([b]), salt)[0] & mask)
-    tag = int(hashtab._tag_np(
-        np.array([a]), np.array([b]), hashtab._SALTS[int(t["meta"][2])]
-    )[0])
-    lo, hi = int(t["ptr"][h]), int(t["ptr"][h + 1])
-    assert hi - lo <= t["pw"].shape[0], "bucket deeper than probe depth"
-    for j in range(lo, hi):
-        if t["tag"][j] == tag and t["key_b"][j] == b:
+    the pointer decoded into the bucket's offset and its split level, the
+    window of the key's part scanned for its rounds (an entry's key is its
+    tag and its second half: ``tag = key_a ^ f(key_b)``)."""
+    meta = t["meta"]
+    ka, kb = np.array([a]), np.array([b])
+    mask = np.uint32(int(meta[1]))
+    h = int(hashtab._mix_np(ka, kb, hashtab._SALTS[int(meta[0])])[0] & mask)
+    tag = int(hashtab._tag_np(ka, kb, hashtab._SALTS[int(meta[2])])[0])
+    u = int(t["ptr"][h]) & 0xFFFFFFFF
+    probe = t["pw"].shape[0]
+    part = int(hashtab._split_np(ka, kb, hashtab._SALTS[int(meta[3])])[0]) & (
+        (1 << (u >> 29)) - 1)
+    lo = (u & ((1 << 29) - 1)) + part * (probe // 2)
+    assert lo < (int(t["ptr"][h + 1]) & ((1 << 29) - 1)) or u >> 29 == 0
+    for j in range(lo, min(lo + probe, len(t["tag"]))):
+        if t["tag"][j] == tag:  # the first tag hit, verified once
+            if t["key_b"][j] != b:
+                return False, -1
             return True, int(t["val"][j]) if "val" in t else -1
     return False, -1
+
+
+def _table_entries(t):
+    """Entries of a table: its slots in use less the slots left empty
+    between the parts of its split buckets (``meta`` counts them)."""
+    used = hashtab.slots_in_use(t)
+    assert int((t["key_b"][:used] < 0).sum()) == int(t["meta"][6])
+    return used - int(t["meta"][6])
 
 
 def _check_tables(snap):
@@ -63,13 +77,13 @@ def _check_tables(snap):
             snap.node_tab, int(snap.node_hi[i]), int(snap.node_lo[i])
         )
         assert ok and v == i, f"node_tab wrong at {i}: {ok}, {v}"
-    assert int(snap.node_tab["ptr"][-1]) == snap.n_nodes
+    assert _table_entries(snap.node_tab) == snap.n_nodes
     for i in range(0, snap.n_tuples, max(1, snap.n_tuples // 200)):
         ok, _ = _host_lookup(
             snap.mem_tab, int(snap.mem_node[i]), int(snap.mem_subj[i])
         )
         assert ok, f"mem_tab miss at row {i}"
-    assert int(snap.mem_tab["ptr"][-1]) == snap.n_tuples
+    assert _table_entries(snap.mem_tab) == snap.n_tuples
     for _ in range(50):
         a = random.randrange(snap.n_nodes + 5)
         b = random.randrange(1 << 20)
@@ -181,6 +195,88 @@ def test_fold_parity_randomized_storms():
     # classes, pad crossings); the point is every non-rejected fold was
     # array-identical — and enough folds succeed for that to mean something
     assert results["ok"] >= 5, results
+
+
+def _full_bucket(t, a, b):
+    """Does key (a, b) hash into a bucket of ``t`` that holds four keys or
+    more already (a full level-0 bucket, or a split one)?"""
+    h = int(hashtab._mix_np(np.array([a]), np.array([b]), hashtab._SALTS[0])[0]
+            & np.uint32(int(t["meta"][1])))
+    off = hashtab._offsets(t["ptr"])
+    return int(off[h + 1] - off[h]) >= 4
+
+
+def test_fold_into_full_and_split_buckets_splices_without_a_build(monkeypatch):
+    """A bound of four rounds makes a full bucket common (an insert meets
+    one 0.34 % of the time at a load of 0.6, 1.8 % at 0.99): the fold's
+    splice lays such a bucket anew, splitting it, where the parent's gave
+    up and the fold built both tables in full (12 s at 10M tuples)."""
+    g = build_synth(n_users=120, n_groups=10, n_folders=40, n_docs=500)
+    cols = dl.TupleColumns(Vocab())
+    have = set(g.store.all_tuples())
+    for t in have:
+        cols.apply(1, t)
+    base = dl.build_snapshot_cols(cols, g.manager, version=0)
+    assert base.node_tab["pw"].shape == base.mem_tab["pw"].shape == (4,)
+    v = cols.vocab
+    ns = v.namespaces.lookup("Doc")
+    users = sorted({t.subject.id for t in have
+                    if isinstance(t.subject, SubjectID)})
+    # tuples whose node key, or whose (node, subject) key, falls into a
+    # bucket that is full already: new nodes and new members alike
+    new_nodes, new_pairs = [], []
+    for d in sorted({t.object for t in have if t.namespace == "Doc"}):
+        for rel in ("viewers", "owners"):
+            hi = ns * base.num_rels + v.relations.lookup(rel)
+            obj = v.objects.lookup(d)
+            node = hashtab.lookup_one(base.node_tab, hi, obj)
+            if node < 0:
+                if _full_bucket(base.node_tab, hi, obj):
+                    new_nodes.append(RelationTuple("Doc", d, rel, SubjectID(users[0])))
+                continue
+            assert (base.node_hi[node], base.node_lo[node]) == (hi, obj)
+            for u in users[:40]:
+                t = RelationTuple("Doc", d, rel, SubjectID(u))
+                if t not in have and _full_bucket(
+                        base.mem_tab, node, v.subjects.lookup(SubjectID(u).unique_id())):
+                    new_pairs.append(t)
+    assert new_nodes and new_pairs, (len(new_nodes), len(new_pairs))
+    built = []
+    real_build = hashtab.build_table
+
+    def counted(key_a, key_b, val=None, **kw):
+        built.append("nt" if val is not None else "mt")
+        return real_build(key_a, key_b, val, **kw)
+
+    monkeypatch.setattr(hashtab, "build_table", counted)
+    # new members of resident nodes: both tables spliced; then new nodes,
+    # which renumber the node ids the membership table is keyed by: that
+    # table is built as before, the node table spliced
+    snap, version = base, 0
+    for adds, may_build in ((new_pairs[:40], []), (new_nodes[:8], ["mt"])):
+        changes = [(1, t) for t in adds]
+        for op_, t in changes:
+            cols.apply(op_, t)
+        version += 1
+        del built[:]
+        folded = dl.fold_snapshot_cols(snap, cols.vocab, changes, version=version)
+        assert built == may_build
+        for old, new in ((snap.node_tab, folded.node_tab),
+                         (snap.mem_tab, folded.mem_tab)):
+            assert {k: x.shape for k, x in old.items()} == {
+                k: x.shape for k, x in new.items()}
+        spliced = ("mem_tab", "node_tab")[len(may_build)]
+        assert hashtab.table_stats(getattr(folded, spliced))[
+            "split_buckets"] > hashtab.table_stats(getattr(snap, spliced))[
+                "split_buckets"]
+        _check_tables(folded)
+        scratch = dl.build_snapshot_cols(cols, g.manager, version=version)
+        for f in CMP:
+            assert (getattr(folded, f) == getattr(scratch, f)).all(), f
+        for tab in ("node_tab", "mem_tab"):
+            assert hashtab.table_stats(getattr(folded, tab)) == hashtab.table_stats(
+                getattr(scratch, tab))
+        snap = folded
 
 
 def test_fold_rejects_new_edge_class():

@@ -62,10 +62,8 @@ def _reckoned(snap, leopard=None) -> dict:
         tuples=snap.n_tuples, nodes=snap.n_nodes, edges=snap.n_edges,
         subjects=len(snap.vocab.subjects), pair_cap=PAIR_CAP,
         leopard_pairs=len(leopard.elt_packed) if leopard is not None else 0,
-        nt_rounds=snap.node_tab["pw"].shape[0],
-        mt_rounds=snap.mem_tab["pw"].shape[0],
-        overlay_rounds=dl.OVERLAY_PROBE,
     )
+    assert snap.node_tab["pw"].shape == snap.mem_tab["pw"].shape == (4,)
     assert set(groups) == set(sn.DEVICE_GROUPS)
     assert all(g["live"] <= g["padded"] for g in groups.values())
     return {name: g["padded"] for name, g in groups.items()}
@@ -135,12 +133,12 @@ def test_the_150m_drive_graph_is_reckoned_at_seven_gigabytes():
     """The staircase at the counts of ``drive-150m`` (CPU survey, PR 35):
     tuple-sized arrays pad to 2^28, edge- and node-sized to 2^27."""
     g = sn.device_bytes(tuples=150_000_162, nodes=132_697_037,
-                        edges=100_758_332, subjects=24_365_625,
-                        nt_rounds=11, mt_rounds=9)
-    assert g["node_table"]["padded"] == 4 * ((1 << 27) + 1) + 12 * (1 << 27) + 23
+                        edges=100_758_332, subjects=24_365_625)
+    # meta int32[7] and four rounds a table, whatever it holds (PR 36)
+    assert g["node_table"]["padded"] == 4 * ((1 << 27) + 1) + 12 * (1 << 27) + 32
     assert g["membership_table"]["padded"] == (
-        4 * ((1 << 28) + 1) + 8 * (1 << 28) + 21)
-    assert sn.resident_bytes(g) == 7_113_769_060
+        4 * ((1 << 28) + 1) + 8 * (1 << 28) + 32)
+    assert sn.resident_bytes(g) == 7_113_769_112
 
 
 # -- loud caps ---------------------------------------------------------------
@@ -156,6 +154,7 @@ def test_projection_caps_raise_with_the_count_and_the_cap(what):
 @pytest.mark.parametrize("lean", [True, False])
 def test_table_caps_raise_with_the_count_and_the_cap(lean, monkeypatch):
     monkeypatch.setattr(hashtab, "_I32MAX", 1023)
+    monkeypatch.setattr(hashtab, "_SLOT_CAP", 1023)  # 2^29 - 1 as served
     a = np.arange(1024, dtype=np.int32)
     hashtab.build_table(a[:512], a[:512], lean=lean)  # 512 in 512 / 1024
     with pytest.raises(ValueError, match=r"1024 entries in \d+ buckets .* 1023"):
