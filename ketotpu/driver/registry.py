@@ -1588,6 +1588,12 @@ class Registry:
                          "(the vocabulary's bulk form, the store's forward "
                          "index), in whatever thread met it first",
                     what=what)
+        m.gauge("keto_host_sched_lag_seconds_total",
+                hostwaits.SCHED_LAG_SECONDS,
+                help="seconds the scheduling probe's 20 ms sleeps woke "
+                     "late, every tick, collections taken out")
+        m.gauge("keto_host_sched_ticks_total", hostwaits.SCHED_TICKS,
+                help="ticks of the scheduling probe")
         m.gauge("keto_engine_projection_build_seconds",
                 eng.projection_build_s,
                 help="host-side snapshot projection build wall time")

@@ -120,7 +120,8 @@ def _collect_side(inner) -> tuple:
 
 class _Slot:
     __slots__ = ("tuple", "depth", "bypass", "event", "result", "error",
-                 "t_enq", "t_dispatch", "wave", "traceparent", "followers")
+                 "t_enq", "t_dispatch", "t_set", "wave", "traceparent",
+                 "followers")
 
     def __init__(self, t: RelationTuple, depth: int, bypass: bool = False):
         self.tuple = t
@@ -131,6 +132,7 @@ class _Slot:
         self.error: Optional[BaseException] = None
         self.t_enq = time.perf_counter()
         self.t_dispatch: Optional[float] = None  # _Group.stamp
+        self.t_set: Optional[float] = None  # _Group.wake
         self.wave: Optional[int] = None
         # wave-ledger cross-link: the enqueuing RPC's trace id, and how
         # many identical pending checks singleflight-parked on this slot
@@ -145,8 +147,8 @@ class _ColumnGroup:
     no per-item futures, no per-item Python objects."""
 
     __slots__ = ("block", "depth", "bypass", "event", "verdicts", "errors",
-                 "error", "t_enq", "t_dispatch", "wave", "traceparent",
-                 "followers")
+                 "error", "t_enq", "t_dispatch", "t_set", "wave",
+                 "traceparent", "followers")
 
     def __init__(self, block, depth: int, bypass: bool = False):
         self.block = block
@@ -158,6 +160,7 @@ class _ColumnGroup:
         self.error: Optional[BaseException] = None
         self.t_enq = time.perf_counter()
         self.t_dispatch: Optional[float] = None
+        self.t_set: Optional[float] = None
         self.wave: Optional[int] = None
         self.traceparent: Optional[str] = None
         self.followers = 0  # groups never singleflight; ledger parity
@@ -197,6 +200,28 @@ class _Group:
         for m in (*self.slots, *self.cgroups):
             m.t_dispatch = self.t_dispatch
             m.wave = wave_id
+
+    def wake(self) -> None:
+        """The members are answered: wake their callers, each member
+        stamped with the moment the first of them is woken."""
+        t_set = time.perf_counter()
+        for m in (*self.slots, *self.cgroups):
+            m.t_set = t_set
+            m.event.set()
+
+
+def _note_wait(t_enq: float, m, done: float) -> None:
+    """A caller's wait on member ``m`` (enqueued at ``t_enq``, returned at
+    ``done``) as three stages that add up to it: ``coalesce_wait`` to the
+    start of the wave's dispatch (its submit), ``device_compute`` from
+    there to the scatter, the waves launched before it included (the
+    wave is in the device's queue), ``wake`` from the scatter until the
+    caller runs again; no-ops when this thread serves no instrumented
+    RPC."""
+    flightrec.note_stage("coalesce_wait", m.t_dispatch - t_enq)
+    flightrec.note_stage("device_compute", m.t_set - m.t_dispatch)
+    flightrec.note_stage("wake", done - m.t_set)
+    flightrec.note(wave=m.wave)
 
 
 class _Cut:
@@ -378,16 +403,8 @@ class CoalescingEngine:
                 f"check did not complete within {budget:.3f}s "
                 f"(waited {waited:.3f}s)"
             )
-        # stage decomposition for the RPC that enqueued us: queue wait is
-        # enqueue -> the start of the wave's dispatch (its submit), device
-        # compute is from there to the wakeup, the wait behind the waves
-        # launched before it included: the wave is in the device's queue
-        # (both no-ops when this thread isn't serving an instrumented RPC)
-        done = time.perf_counter()
         if slot.t_dispatch is not None:
-            flightrec.note_stage("coalesce_wait", slot.t_dispatch - slot.t_enq)
-            flightrec.note_stage("device_compute", done - slot.t_dispatch)
-            flightrec.note(wave=slot.wave)
+            _note_wait(slot.t_enq, slot, time.perf_counter())
         if slot.error is not None:
             raise slot.error
         return bool(slot.result)
@@ -466,8 +483,7 @@ class CoalescingEngine:
                 results[i] = bool(v)
             return [bool(v) for v in results]
         waited: set = set()
-        last_dispatch = None
-        wave_id = None
+        last = None  # the member whose wave answered last
         for i, slot in entries:
             if id(slot) not in waited:
                 waited.add(id(slot))
@@ -484,18 +500,15 @@ class CoalescingEngine:
                         )
                 else:
                     slot.event.wait()
-                if slot.t_dispatch is not None:
-                    last_dispatch = slot.t_dispatch
-                    wave_id = slot.wave
+                if slot.t_dispatch is not None and (
+                        last is None or slot.t_set > last.t_set):
+                    last = slot
             if slot.error is not None:
                 # typed per-query error: raise like the inner engine would
                 raise slot.error
             results[i] = bool(slot.result)
-        done = time.perf_counter()
-        if last_dispatch is not None:
-            flightrec.note_stage("coalesce_wait", last_dispatch - t0)
-            flightrec.note_stage("device_compute", done - last_dispatch)
-            flightrec.note(wave=wave_id)
+        if last is not None:
+            _note_wait(t0, last, time.perf_counter())
         return [bool(v) for v in results]
 
     def check_block(self, block, rest_depth: int = 0):
@@ -540,11 +553,8 @@ class CoalescingEngine:
                 f"batch did not complete within {budget:.3f}s "
                 f"(waited {waited:.3f}s)"
             )
-        done = time.perf_counter()
         if grp.t_dispatch is not None:
-            flightrec.note_stage("coalesce_wait", grp.t_dispatch - grp.t_enq)
-            flightrec.note_stage("device_compute", done - grp.t_dispatch)
-            flightrec.note(wave=grp.wave)
+            _note_wait(grp.t_enq, grp, time.perf_counter())
         if grp.error is not None:
             raise grp.error
         return grp.verdicts, grp.errors
@@ -726,8 +736,7 @@ class CoalescingEngine:
             finally:
                 device_s += time.perf_counter() - g.t_dispatch
                 states.enter("file", wave=cut.wave_id)
-                for m in (*g.slots, *g.cgroups):
-                    m.event.set()
+                g.wake()
         with self._lock:
             self._uncollected -= 1
         if self.ledger is not None:

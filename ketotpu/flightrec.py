@@ -28,11 +28,15 @@ import os
 import secrets
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Dict, List, Optional
 
 from ketotpu import hostwaits
-from ketotpu.observability import format_traceparent, parse_traceparent
+from ketotpu.observability import (
+    Tracer,
+    format_traceparent,
+    parse_traceparent,
+)
 
 _local = threading.local()
 
@@ -237,20 +241,52 @@ def current_traceparent() -> Optional[str]:
     return tp or ctx.info.get("traceparent")
 
 
+def await_send(call) -> None:
+    """Bind the gRPC call (its servicer context) this thread serves now,
+    or unbind it with ``None`` (``AccessLogInterceptor``, around every
+    unary handler): the first request context that closes under it times
+    stage ``send``."""
+    _local.call = call
+
+
+def _time_send(metrics, op: str, t_end: float) -> None:
+    """Stage ``send`` of the bound gRPC call, once: from ``t_end``, the
+    close of its request context, until the server ends the RPC —
+    ``add_callback`` runs on gRPC's poller thread once the status and the
+    message are out and the call is closed (serialization, the batch that
+    sends them, the poller's turn).  The context has closed by then, so
+    the stage is observed into ``keto_rpc_stage_seconds`` alone: it is
+    not part of the request's total nor of its span buffer."""
+    call = getattr(_local, "call", None)
+    if call is None or metrics is None:
+        return
+    _local.call = None
+    call.add_callback(lambda: metrics.observe(
+        STAGE_METRIC, time.perf_counter() - t_end, help=_STAGE_HELP,
+        op=op, stage="send",
+    ))
+
+
 @contextmanager
 def rpc_recording(registry, op: str, *, traceparent: Optional[str] = None,
                   detail: str = "", t0: Optional[float] = None):
     """Open the per-request stage context (transport edge only).
 
-    Opens an ``rpc.<op>`` span (adopting the caller's W3C traceparent so
-    OTLP traces stitch across worker processes), collects stage notes
-    from every layer underneath, and files the request with the flight
-    recorder on exit.  Re-entrant: a context already open on this thread
-    (e.g. worker host inside a serving thread) wins and this call is a
-    pass-through.  Where a front door's pool (hostwaits.StampedPool) ran
-    this thread's call, the request starts when the call was submitted:
-    its wait for a thread is stage ``pool_wait`` and part of the total, as
-    it is of the client's.
+    Collects stage notes from every layer underneath and files the
+    request with the flight recorder on exit.  An exporting tracer or an
+    embedder's ``tracer_wrapper`` also gets an ``rpc.<op>`` span (adopting
+    the caller's W3C traceparent so OTLP traces stitch across worker
+    processes); the base tracer, which keeps no ids, does not: its span
+    would only time again what ``keto_request_outcome_seconds`` holds.
+    Re-entrant: a context already open on this thread (e.g. worker host
+    inside a serving thread) wins and this call is a pass-through.  Where
+    a front door's pool (hostwaits.StampedPool) ran this thread's call,
+    the request starts when the call was submitted: its wait for a thread
+    is stage ``pool_wait`` and part of the total, as it is of the
+    client's, and the time from the thread's start to this context's
+    open (gRPC's receive, the interceptors, the handler's first lines)
+    is stage ``receive``.  Under a bound gRPC call (:func:`await_send`)
+    the close arms stage ``send``.
     """
     if getattr(_local, "ctx", None) is not None:
         yield
@@ -268,8 +304,10 @@ def rpc_recording(registry, op: str, *, traceparent: Optional[str] = None,
     _local.ctx = ctx
     if stamp is not None:
         note_stage("pool_wait", stamp[1] - stamp[0])
+    span = (nullcontext() if type(tracer) is Tracer
+            else tracer.span(f"rpc.{op}", _parent=traceparent, detail=detail))
     try:
-        with tracer.span(f"rpc.{op}", _parent=traceparent, detail=detail):
+        with span:
             # capture the trace id while the span is OPEN (the recorder
             # files the entry after it closes, when an exporting tracer
             # no longer answers): the span's own id when the tracer mints
@@ -287,10 +325,13 @@ def rpc_recording(registry, op: str, *, traceparent: Optional[str] = None,
                 ctx.info.setdefault("traceparent", tp)
             parsed = parse_traceparent(ctx.info.get("traceparent"))
             ctx.trace_id = parsed[0] if parsed else None
+            if stamp is not None:
+                note_stage("receive", time.perf_counter() - stamp[1])
             yield ctx
     finally:
         _local.ctx = None
-        total = time.perf_counter() - ctx.t0
+        t_end = time.perf_counter()
+        total = t_end - ctx.t0
         if metrics is not None:
             status = ctx.info.get("status")
             outcome = "ok"
@@ -315,6 +356,7 @@ def rpc_recording(registry, op: str, *, traceparent: Optional[str] = None,
             recorder.record(total, entry)
         if trace is not None:
             _complete_trace(ctx, trace, total)
+        _time_send(metrics, op, t_end)
 
 
 def _complete_trace(ctx: _ReqCtx, trace, total: float) -> None:

@@ -13,15 +13,18 @@ it, exactly like a slow device.  This module measures both:
   worker thread; ``flightrec.rpc_recording`` takes it as the request's
   ``t0`` and notes the difference as stage ``pool_wait``
   (``keto_rpc_stage_seconds{op,stage="pool_wait"}``, the flight recorder,
-  promoted traces).
+  promoted traces), and the time from the work's start to the context's
+  open as stage ``receive``.
 * :class:`PauseWatch` — ``keto_host_pause_seconds{cause}``, a counter of
   seconds that only pauses of :data:`PAUSE_MIN_S` and more add to, so
   nothing is observed per request.  Causes: ``gc`` (``gc.callbacks``, one
   collection from start to stop; the callback may run under any lock, so
   it files nothing itself: the probe thread does, a tick later), ``sched`` (a daemon thread sleeps
   :data:`SCHED_TICK_S` and counts how late it wakes, less the collections
-  that fell into the sleep), ``store_lock`` (:class:`TimedRLock`, the
-  in-memory store's lock: the wait to acquire it, per waiting thread).  A
+  that fell into the sleep; every tick's lateness, however short, also
+  adds to :data:`SCHED_LAG_SECONDS` and :data:`SCHED_TICKS`),
+  ``store_lock`` (:class:`TimedRLock`, the in-memory store's lock: the
+  wait to acquire it, per waiting thread).  A
   pause of :data:`PAUSE_LOG_S` or more logs one line with the cause, the
   seconds, the thread that paused (for ``store_lock`` the one that
   waited, for ``gc`` the one that collected) and the engine spans open at
@@ -52,6 +55,14 @@ SCHED_TICK_S = 0.02
 
 PAUSE_METRIC = "keto_host_pause_seconds"
 _PAUSE_HELP = "seconds of host pauses of 50 ms and more, by cause"
+
+#: every probe tick's lateness (collections taken out) and the ticks, from
+#: the first bind (scrape: ``keto_host_sched_lag_seconds_total`` and
+#: ``keto_host_sched_ticks_total``): a thread that wakes from a sleep has to
+#: get the interpreter back, so the lateness a tick is what each wake-up
+#: pays, short ones included
+SCHED_LAG_SECONDS = 0.0
+SCHED_TICKS = 0
 
 _local = threading.local()
 
@@ -240,11 +251,15 @@ class PauseWatch:
                       generation=generation, collected=collected)
 
     def _watch_sched(self) -> None:
+        global SCHED_LAG_SECONDS, SCHED_TICKS
         while True:
             gc_before = self._gc[0]
             t0 = time.perf_counter()
             time.sleep(SCHED_TICK_S)
-            self.note("sched", self._late(t0, time.perf_counter(), gc_before))
+            late = self._late(t0, time.perf_counter(), gc_before)
+            SCHED_LAG_SECONDS += max(late, 0.0)  # the one thread that adds
+            SCHED_TICKS += 1
+            self.note("sched", late)
             self.file_collections()
 
     def _late(self, t0: float, now: float, gc_before: float) -> float:

@@ -25,6 +25,7 @@ import logging
 import math
 import threading
 import time
+from collections import deque
 from contextlib import contextmanager
 from typing import Dict, Iterable, List, Optional, Tuple
 
@@ -42,6 +43,9 @@ _BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
 #: public histogram bucket bounds (seconds) — the SLO engine snaps its
 #: latency target onto one of these so "fraction under target" is exact
 BUCKETS = _BUCKETS
+
+#: histogram samples queued before the observe that finds them files them
+_FILE_EVERY = 256
 
 
 # -- W3C trace context (traceparent) -----------------------------------------
@@ -82,6 +86,8 @@ class Metrics:
         self._hists: Dict[Tuple[str, Tuple[Tuple[str, str], ...]], List] = {}
         self._gauges: Dict[Tuple[str, Tuple[Tuple[str, str], ...]], float] = {}
         self._help: Dict[str, str] = {}
+        # histogram samples not yet filed (observe)
+        self._samples: deque = deque()
 
     def counter(self, name: str, value: float = 1.0, help: str = "", **labels):
         key = (name, tuple(sorted(labels.items())))
@@ -91,14 +97,29 @@ class Metrics:
             self._counters[key] = self._counters.get(key, 0.0) + value
 
     def observe(self, name: str, value: float, help: str = "", **labels):
-        key = (name, tuple(sorted(labels.items())))
-        with self._lock:
+        """One sample of histogram ``name``.  It is queued without the lock
+        (a deque's append is atomic) and filed by the next reader, or with
+        the others by the observe that finds :data:`_FILE_EVERY` queued: a
+        serving thread that waits for the lock has to win the interpreter
+        back afterwards, and under load (a request's several stages, 64
+        handler threads, gRPC's poller) that wait is what the rate pays."""
+        self._samples.append((name, value, help, labels))
+        if len(self._samples) >= _FILE_EVERY:
+            with self._lock:
+                self._file_samples()
+
+    def _file_samples(self) -> None:
+        """File the queued histogram samples (the caller holds the lock)."""
+        samples = self._samples
+        while samples:
+            name, value, help, labels = samples.popleft()
+            key = (name, tuple(sorted(labels.items())))
             if help:
                 self._help.setdefault(name, help)
             h = self._hists.get(key)
             if h is None:
                 h = self._hists[key] = [[0] * (len(_BUCKETS) + 1), 0.0, 0]
-            buckets, _, _ = h
+            buckets = h[0]
             for i, ub in enumerate(_BUCKETS):
                 if value <= ub:
                     buckets[i] += 1
@@ -142,6 +163,7 @@ class Metrics:
         """{label-tuple: (sum, count)} for every series of ``name`` — the
         scrape surface bench.py uses to publish stage/phase breakdowns."""
         with self._lock:
+            self._file_samples()
             return {
                 labels: (h[1], h[2])
                 for (n, labels), h in self._hists.items()
@@ -155,6 +177,7 @@ class Metrics:
         every series of ``name``.  Bucket bounds are :data:`BUCKETS`; the
         SLO engine reads cumulative-under-target counts off this."""
         with self._lock:
+            self._file_samples()
             return {
                 labels: (list(h[0]), h[1], h[2])
                 for (n, labels), h in self._hists.items()
@@ -182,6 +205,7 @@ class Metrics:
         """Prometheus text format 0.0.4."""
         lines: List[str] = []
         with self._lock:
+            self._file_samples()
             names = sorted(
                 {n for n, _ in self._counters}
                 | {n for n, _ in self._hists}

@@ -5,10 +5,13 @@ The reference chains logging/metrics middleware onto every gRPC server
 Python servers.  `AccessLogInterceptor` wraps every unary handler to
 
 * observe ``keto_grpc_request_duration_seconds{method}`` on the shared
-  Metrics registry, and
+  Metrics registry,
 * emit one INFO access line per RPC (method, status, duration, peer)
   when ``log.request_log`` is enabled — health-check RPCs are metered
-  but not logged, like the REST access log's health exclusion.
+  but not logged, like the REST access log's health exclusion, and
+* bind the call for the request context the handler opens, which times
+  stage ``send`` from its close to the RPC's end
+  (``flightrec.await_send``).
 
 Embedder-supplied interceptors (ketoctx ``grpc_interceptors``) still run;
 this one is prepended so the duration covers the whole chain.
@@ -105,12 +108,14 @@ class AccessLogInterceptor(grpc.ServerInterceptor):
         def wrapped(request, context):
             t0 = time.perf_counter()
             status = "OK"
+            flightrec.await_send(context)
             try:
                 return inner(request, context)
             except Exception:
                 status = "ERROR"
                 raise
             finally:
+                flightrec.await_send(None)
                 dt = time.perf_counter() - t0
                 # abort()/set_code() paths: report the code the handler set
                 code = getattr(context, "code", lambda: None)()

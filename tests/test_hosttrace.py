@@ -5,13 +5,16 @@ coalescer's thread states, the tier scopes of the device programs).
 """
 
 import gc
+import sys
 import threading
 import time
+import types
 
 import pytest
 
 from ketotpu import flightrec, hostwaits
 from ketotpu.api.types import RelationTuple, SubjectSet
+from ketotpu.engine import coalesce
 from ketotpu.engine import expand_device as xd
 from ketotpu.engine import fused as fdx
 from ketotpu.engine.coalesce import CoalescingEngine
@@ -139,6 +142,64 @@ def test_coalescer_thread_states_partition_wall_time(thread, states):
         ) == pytest.approx(seconds)
 
 
+class _CollectSleeps:
+    """A check engine with the submit / collect pair whose collect takes
+    ``seconds``; it keeps when each ticket was submitted and collected."""
+
+    def __init__(self, seconds):
+        self.seconds = seconds
+        self.halves = []
+
+    def submit(self, batch, rest_depth=0):
+        return time.perf_counter(), len(batch)
+
+    def collect(self, ticket, errs=None):
+        time.sleep(self.seconds)
+        self.halves.append((ticket[0], time.perf_counter()))
+        return [True] * ticket[1]
+
+
+class _LateEvent(threading.Event):
+    """An event whose set reaches the waiter 20 ms late, as a wake-up that
+    waits for the interpreter does."""
+
+    def set(self):
+        time.sleep(0.02)
+        super().set()
+
+
+@pytest.mark.parametrize("how", ["check_is_member", "batch_check"])
+def test_a_coalesced_wait_is_coalesce_wait_device_compute_and_wake(
+        how, monkeypatch):
+    reg = _Registry()
+    inner = _CollectSleeps(0.03)
+    co = CoalescingEngine(inner, window=0.002, batch_max=8)
+    monkeypatch.setattr(coalesce, "threading",
+                        types.SimpleNamespace(Event=_LateEvent))
+    try:
+        with flightrec.rpc_recording(reg, "check") as ctx:
+            t0 = time.perf_counter()
+            if how == "check_is_member":
+                assert co.check_is_member(T("Doc:d#view@u")) is True
+            else:
+                assert co.batch_check(
+                    [T("Doc:d#view@u1"), T("Doc:d#view@u2")]) == [True] * 2
+            waited = time.perf_counter() - t0
+            stages = dict(ctx.stages)
+    finally:
+        monkeypatch.undo()
+        co.close()
+    parts = stages["coalesce_wait"] + stages["device_compute"] + stages["wake"]
+    assert parts == pytest.approx(waited, rel=0.02)
+    (submitted, collected), = inner.halves
+    # device_compute ends at the scatter, after the collect, and leaves the
+    # late wake-up to stage wake
+    scatter = t0 + stages["coalesce_wait"] + stages["device_compute"]
+    assert collected <= scatter + 1e-4 < collected + 0.01
+    assert stages["device_compute"] >= collected - submitted >= 0.03
+    assert stages["wake"] >= 0.02
+
+
 # -- pool wait -----------------------------------------------------------------
 
 
@@ -186,6 +247,86 @@ def test_pool_wait_is_noted_when_an_rpc_queues_for_a_thread():
     assert slow["stages_ms"]["pool_wait"] >= 55.0
     # outside a pool call nothing is stamped
     assert hostwaits.take_pool_stamp() is None
+
+
+def test_receive_is_noted_once_a_pool_call_from_its_thread_start():
+    reg = _Registry()
+    pool = hostwaits.StampedPool(max_workers=2)
+
+    def rpc():
+        time.sleep(0.02)  # gRPC's receive and the interceptors
+        with flightrec.rpc_recording(reg, "check") as ctx:
+            with flightrec.rpc_recording(reg, "check"):  # pass-through
+                pass
+            first = dict(ctx.stages)
+        with flightrec.rpc_recording(reg, "check") as ctx:  # same call
+            return first, dict(ctx.stages)
+
+    try:
+        got = [f.result(30.0) for f in [pool.submit(rpc) for _ in range(3)]]
+    finally:
+        pool.shutdown()
+    for first, again in got:
+        assert first["receive"] >= 0.02
+        assert "receive" not in again and "pool_wait" not in again
+    _, count = reg.metrics().histogram_values(flightrec.STAGE_METRIC)[
+        (("op", "check"), ("stage", "receive"))]
+    assert count == 3
+    # a request whose thread no pool stamped has no receive
+    with flightrec.rpc_recording(reg, "check") as ctx:
+        pass
+    assert "receive" not in ctx.stages
+
+
+class _RecvRegistry(_Registry):
+    config = {"log.request_log": False}
+
+
+def test_send_is_observed_once_per_unary_call_after_it_ends():
+    import grpc
+
+    from ketotpu.server.interceptors import AccessLogInterceptor
+
+    reg = _RecvRegistry()
+
+    def check(request, context):
+        with flightrec.rpc_recording(reg, "check"):
+            return request
+
+    server = grpc.server(
+        hostwaits.StampedPool(4, door="grpc"),
+        interceptors=(AccessLogInterceptor(reg),),
+    )
+    server.add_generic_rpc_handlers((grpc.method_handlers_generic_handler(
+        "keto.test.Stages",
+        {"Check": grpc.unary_unary_rpc_method_handler(check)}),))
+    port = server.add_insecure_port("127.0.0.1:0")
+    server.start()
+
+    def counts():
+        hist = reg.metrics().histogram_values(flightrec.STAGE_METRIC)
+        return {dict(k)["stage"]: v[1] for k, v in hist.items()}
+
+    try:
+        with grpc.insecure_channel(f"127.0.0.1:{port}") as ch:
+            call = ch.unary_unary("/keto.test.Stages/Check")
+            for _ in range(3):
+                assert call(b"x", timeout=30.0) == b"x"
+        deadline = time.monotonic() + 10.0
+        while counts().get("send", 0) < 3 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        time.sleep(0.05)  # and no more come
+    finally:
+        server.stop(0)
+    got = counts()
+    assert got["send"] == 3 and got["receive"] == 3
+    total, _ = reg.metrics().histogram_values(flightrec.STAGE_METRIC)[
+        (("op", "check"), ("stage", "send"))]
+    assert total >= 0.0
+    # the request's total closes before its send
+    (_, outcomes), = reg.metrics().histogram_values(
+        flightrec.OUTCOME_METRIC).values()
+    assert outcomes == 3
 
 
 # -- host pauses ---------------------------------------------------------------
@@ -254,6 +395,40 @@ def test_sched_probe_leaves_a_collection_to_cause_gc(gc_state, late):
     watch._gc = gc_state
     woke = 100.0 + hostwaits.SCHED_TICK_S + 0.4
     assert watch._late(100.0, woke, 0.0) == pytest.approx(late)
+
+
+def _lag_per_tick(seconds: float) -> tuple:
+    lag, ticks = hostwaits.SCHED_LAG_SECONDS, hostwaits.SCHED_TICKS
+    time.sleep(seconds)
+    n = hostwaits.SCHED_TICKS - ticks
+    return (hostwaits.SCHED_LAG_SECONDS - lag) / max(n, 1), n
+
+
+def test_sched_probe_counts_every_ticks_lateness():
+    watch = hostwaits.pauses()
+    if not watch._armed:
+        watch.bind()  # the process's probe, as a registry starts it
+    idle, idle_ticks = _lag_per_tick(0.5)
+    stop = threading.Event()
+
+    def spin():  # holds the interpreter but at each switch interval
+        while not stop.is_set():
+            pass
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(0.01)
+    busy_thread = threading.Thread(target=spin, daemon=True)
+    try:
+        busy_thread.start()
+        busy, busy_ticks = _lag_per_tick(0.5)
+    finally:
+        stop.set()
+        sys.setswitchinterval(interval)
+        busy_thread.join(10.0)
+    assert not busy_thread.is_alive()
+    assert idle_ticks >= 5 and busy_ticks >= 5
+    assert 0.0 <= idle < busy
+    assert busy - idle >= 0.002  # a wake-up waits for the spinning thread
 
 
 @pytest.mark.parametrize("seconds, lines", [(0.2, 0), (0.3, 1)])

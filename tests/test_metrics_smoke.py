@@ -256,6 +256,9 @@ def test_projection_metric_vocabulary(scrape):
         'keto_fused_probe_gathers_total{table="om"}',
         'keto_host_lazy_build_seconds_total{what="vocab_index"}',
         'keto_host_lazy_build_seconds_total{what="store_fwd"}',
+        # PR 37: every tick of the scheduling probe, however short
+        "keto_host_sched_lag_seconds_total ",
+        "keto_host_sched_ticks_total ",
     ):
         assert g in text, g
     proj = scrape["projection"]
@@ -298,6 +301,8 @@ def test_wave_ring_gauges_gone_and_window_series_present(scrape):
     stages = set(re.findall(
         r'keto_rpc_stage_seconds_count\{op="check",stage="([^"]+)"', text))
     assert {"pool_wait", "coalesce_wait", "device_compute"} <= stages, stages
+    # PR 37: the gRPC check's receive, wake and send
+    assert {"receive", "wake", "send"} <= stages, stages
     states = set(re.findall(
         r'keto_coalescer_thread_seconds\{state="([^"]+)",thread="([^"]+)"',
         text))

@@ -8,6 +8,7 @@ the drop-on-error contract are verified over a real socket.
 
 import http.server
 import json
+import sys
 import threading
 import time
 
@@ -99,6 +100,48 @@ class TestTraceparent:
         vals = m.histogram_values("keto_span_duration_seconds")
         assert (("span", "outer"),) in vals
         assert (("span", "inner"),) in vals
+
+
+def test_queued_samples_are_all_filed_under_contention():
+    """observe() queues without the lock and a reader or every 256th call
+    files the queue: with threads switching every microsecond and a reader
+    filing beside them, no sample is lost or filed twice."""
+    m = Metrics()
+    n_threads, each = 16, 1000
+    stop = threading.Event()
+
+    def observe(k):
+        for i in range(each):
+            m.observe("h", 0.001, op=str(k % 2))
+
+    def read():
+        while not stop.is_set():
+            m.histogram_values("h")
+            m.exposition()
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    reader = threading.Thread(target=read, daemon=True)
+    workers = [threading.Thread(target=observe, args=(k,))
+               for k in range(n_threads)]
+    try:
+        reader.start()
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join(60.0)
+    finally:
+        stop.set()
+        sys.setswitchinterval(interval)
+        reader.join(60.0)
+    assert not reader.is_alive() and not any(w.is_alive() for w in workers)
+    got = m.histogram_values("h")
+    assert sum(c for _, c in got.values()) == n_threads * each
+    assert sum(s for s, _ in got.values()) == pytest.approx(
+        0.001 * n_threads * each)
+    (buckets, _, count), = [
+        v for k, v in m.histogram_buckets("h").items() if k == (("op", "0"),)]
+    assert count == buckets[1] == n_threads // 2 * each  # le 0.001
 
 
 class _Collector(http.server.BaseHTTPRequestHandler):
@@ -260,9 +303,12 @@ class TestRpcRecording:
         assert vals[(("op", "check"), ("stage", "compute"))] == (
             pytest.approx(0.004), 1,
         )
-        # the span histogram saw the rpc.<op> wrapper span
+        # the base tracer opens no rpc.<op> wrapper span (PR 37): the
+        # request's wall time is its outcome sample alone
         spans = reg._m.histogram_values("keto_span_duration_seconds")
-        assert (("span", "rpc.check"),) in spans
+        assert (("span", "rpc.check"),) not in spans
+        outcomes = reg._m.histogram_values(flightrec.OUTCOME_METRIC)
+        assert outcomes[(("op", "check"), ("outcome", "ok"))][1] == 1
         (entry,) = reg._fr.snapshot()
         assert entry["op"] == "check"
         assert entry["detail"] == "GET /check"
