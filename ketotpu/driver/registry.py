@@ -1562,6 +1562,12 @@ class Registry:
             m.gauge("keto_engine_overflow_rows_total", rows,
                     help="rows a wave's capacity overflowed on, per tier",
                     tier=tier)
+        for rung, roots in eng.expand_roots.items():
+            m.gauge("keto_engine_expand_roots_total", roots,
+                    help="subject-set Expand roots by what answered them: "
+                         "the first rung of level capacities, the full "
+                         "rung, or the host oracle",
+                    rung=rung)
         # fused tiered dispatch (engine/fused.py): whole-cascade waves
         # and per-tier row attribution from the returned device masks
         m.gauge("keto_fused_waves_total", eng.fused_waves,

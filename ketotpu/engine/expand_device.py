@@ -24,9 +24,15 @@ Split the work instead:
   expand are exactly the items the DFS replay prunes via its visited set
   before looking at their children, so the superset is always sufficient.
 
-Per-root arena overflow surfaces as an ``over`` bit; the engine answers
-those roots with the sequential oracle.  Trees produced here are
-bit-identical to `oracle.ExpandEngine.build_tree` (tests/test_expand_device.py).
+Level capacities come in two rungs.  The first (``rung="first"``) clamps
+the geometric schedule at ``FIRST_RUNG`` slots a padded root: served trees
+are widest at level 1 and hold a few dozen items a level, so the deep
+levels are sized for the tree and not for ``cap``.  Per-root arena
+overflow surfaces as an ``over`` bit; the engine runs those roots again
+on the second rung (``rung="full"``, the schedule clamped at ``cap``
+alone), and answers a root that overflows that too with the sequential
+oracle.  Trees produced here are bit-identical to
+`oracle.ExpandEngine.build_tree` (tests/test_expand_device.py).
 """
 
 from __future__ import annotations
@@ -72,8 +78,10 @@ def _run_expand(
     schedule: Tuple[int, ...],
 ):
     """One fused dispatch for all levels.  ``schedule[l]`` is the item
-    capacity of level l (level 0 must hold all roots).  Returns per-level
-    item records + per-root overflow flags."""
+    capacity of level l (level 0 must hold all roots).  Returns one int32
+    buffer, so the host fetches it at once: each level's item records
+    (``RECORD`` rows of its capacity, ``unpack``), then the per-root
+    overflow flags."""
     R = r_ns.shape[0]
     C0 = schedule[0]
 
@@ -94,8 +102,7 @@ def _run_expand(
     for l, cap in enumerate(schedule):
         with jax.named_scope(f"expand/level{l}"):
             deg = jnp.where(live, _mem_deg(g, node), 0)
-            levels.append(dict(parent=parent, subj=subj, node=node, d=d,
-                               deg=deg, root=root, live=live))
+            levels.append(jnp.stack([parent, subj, d, deg]))
             if l == len(schedule) - 1:
                 break
             A = schedule[l + 1]
@@ -133,7 +140,25 @@ def _run_expand(
             live = expandable
             anc = [jnp.where(src_ok, a[aps], -2) for a in anc]
             anc.append(jnp.where(src_ok & c_is_set, c_subj, -2))
-    return levels, over
+    return jnp.concatenate(
+        [lv.astype(jnp.int32).ravel() for lv in levels]
+        + [over.astype(jnp.int32)]
+    )
+
+
+#: the rows of a level's item records in ``_run_expand``'s buffer
+RECORD = ("parent", "subj", "d", "deg")
+
+
+def unpack(buf: np.ndarray, schedule: Tuple[int, ...]):
+    """``_run_expand``'s fetched buffer -> (per-level records, each a dict
+    of ``RECORD`` columns, and the per-root overflow flags)."""
+    levels, at = [], 0
+    for cap in schedule:
+        rec = buf[at:at + len(RECORD) * cap].reshape(len(RECORD), cap)
+        levels.append(dict(zip(RECORD, rec)))
+        at += len(RECORD) * cap
+    return levels, buf[at:] > 0
 
 
 def expand_schedule(n_roots: int, fanout: int, max_depth: int,
@@ -144,6 +169,16 @@ def expand_schedule(n_roots: int, fanout: int, max_depth: int,
     for _ in range(max_depth - 1):
         out.append(min(out[-1] * fanout, cap))
     return tuple(out)
+
+
+#: the first rung's level capacity, in slots a padded root
+FIRST_RUNG = 256
+
+
+def rung_cap(rung: str, n_padded: int, cap: int) -> int:
+    """The level clamp of a rung for ``n_padded`` roots: the first rung
+    holds ``FIRST_RUNG`` slots a root, the full rung ``cap``."""
+    return min(cap, FIRST_RUNG * n_padded) if rung == "first" else cap
 
 
 class _Decoder:
@@ -265,6 +300,7 @@ def assemble(
     roots: List[SubjectSet],
     ov: Optional[OverlayMembers] = None,
     sub_expand=None,
+    skip: Optional[np.ndarray] = None,
 ) -> List[Optional[Tree]]:
     """Exact DFS replay of expand/engine.go:54-124 over the device records.
 
@@ -272,7 +308,9 @@ def assemble(
     deleted pairs plus added pairs; added subject-set members (which the
     device never expanded) recurse through ``sub_expand(subject, depth,
     visited)`` — the sequential engine sharing THIS tree's visited set, so
-    the reference's global-DFS-visited semantics hold across the merge."""
+    the reference's global-DFS-visited semantics hold across the merge.
+    Roots flagged in ``skip`` (overflowed: their records are partial) get
+    ``None`` without a replay."""
     dec = _Decoder(vocab)
     sub_ns, sub_obj, sub_rel = sub_dec
     n_snap_subj = len(sub_ns)
@@ -294,6 +332,9 @@ def assemble(
 
     out: List[Optional[Tree]] = []
     for r, root_subject in enumerate(roots):
+        if skip is not None and skip[r]:
+            out.append(None)
+            continue
         visited = set()
 
         def build(level: int, slot: int, subject: Subject, depth: int):
@@ -352,43 +393,53 @@ def run_expand(
     max_depth: int = 5,
     fanout: int = 16,
     cap: int = 65536,
+    rung: str = "first",
+    pad_to: int = 0,
     ov: Optional[OverlayMembers] = None,
     sub_expand=None,
     span=profiler.null_span,
 ):
-    """Device traversal + host assembly for a batch of subject-set roots.
+    """Device traversal + host assembly for a batch of subject-set roots,
+    on one rung of level capacities (``rung_cap``); the roots pad to the
+    power of two that holds ``max(len(roots), pad_to)``.
 
     Returns ``(trees, over)``: per-root Optional[Tree] (None = prune/404)
-    and per-root overflow flags (True = answer with the oracle instead).
-    ``span`` (the engine's ``_span``) times the phases VERDICT asks for:
-    ``expand_device`` (encode + jitted traversal dispatch),
-    ``expand_sync`` (D2H fetch of every level record), ``expand_assemble``
-    (host DFS reassembly + tree construction).
+    and per-root overflow flags (True = the rung could not hold the tree;
+    its entry in ``trees`` is None).  ``span`` (the engine's ``_span``)
+    times the phases, each with ``rung=``: ``expand_device`` (encode +
+    jitted traversal dispatch), ``expand_sync`` (the one D2H fetch of the
+    level records), ``expand_assemble`` (host DFS reassembly + tree
+    construction).
     """
     vocab = snap.vocab
     if rest_depth <= 0 or max_depth < rest_depth:
         rest_depth = max_depth
     R = len(roots)
-    with span("expand_device", roots=R):
-        levels, over = _dispatch_roots(
-            g, vocab, roots, rest_depth, fanout, cap
+    with span("expand_device", roots=R, rung=rung):
+        buf, sched = _dispatch_roots(
+            g, vocab, roots, rest_depth, fanout, cap, rung, pad_to
         )
-    with span("expand_sync", roots=R):
-        levels = [
-            {k: np.asarray(v) for k, v in lvl.items()} for lvl in levels
-        ]
-        over = np.asarray(over)[:R]
-    with span("expand_assemble", roots=R):
+    with span("expand_sync", roots=R, rung=rung):
+        levels, over = unpack(np.asarray(buf), sched)
+        over = over[:R]
+    with span("expand_assemble", roots=R, rung=rung):
         trees = assemble(
             levels, (snap.sub_ns, snap.sub_obj, snap.sub_rel), vocab,
-            roots, ov=ov, sub_expand=sub_expand,
+            roots, ov=ov, sub_expand=sub_expand, skip=over,
         )
     return trees, over
 
 
-def _dispatch_roots(g, vocab, roots, rest_depth: int, fanout: int, cap: int):
+#: (full-rung schedule, the graph arrays' shapes) already dispatched once
+_FULL_RUNG_WARM: set = set()
+
+
+def _dispatch_roots(g, vocab, roots, rest_depth: int, fanout: int, cap: int,
+                    rung: str = "first", pad_to: int = 0):
     """Encode the roots and enqueue the traversal; returns the uncollected
-    ``(levels, over)`` device records."""
+    device buffer and the schedule it runs at.  The first dispatch of a
+    first rung also dispatches its full rung once, on padding roots
+    alone: a root that overflows later finds that program compiled."""
     R = len(roots)
     # JIT-audit finding: the raw root count used to feed both the input
     # array shapes and schedule[0], so EVERY distinct batch size compiled
@@ -398,13 +449,23 @@ def _dispatch_roots(g, vocab, roots, rest_depth: int, fanout: int, cap: int):
     # the walk never expands and `assemble` never visits (it enumerates
     # only the first len(roots) level-0 slots).
     Rp = 8
-    while Rp < R:
+    while Rp < max(R, pad_to):
         Rp <<= 1
     r_ns = np.full(Rp, -1, np.int32)
     r_obj = np.full(Rp, -1, np.int32)
     r_rel = np.full(Rp, -1, np.int32)
     r_subj = np.full(Rp, -1, np.int32)
     r_depth = np.zeros(Rp, np.int32)
+    if rung == "first":
+        # a graph of other shapes is another program; the root arrays
+        # hold padding alone until they are filled below
+        full = expand_schedule(Rp, fanout, rest_depth, cap)
+        key = (full, tuple((k, v.shape) for k, v in sorted(g.items())))
+        if key not in _FULL_RUNG_WARM:
+            with compilewatch.scope("expand", lambda: f"R={Rp} sched={full}"):
+                _run_expand(g, r_ns, r_obj, r_rel, r_subj, r_depth,
+                            schedule=full)
+            _FULL_RUNG_WARM.add(key)
     r_ns[:R] = np.fromiter(
         (vocab.namespaces.lookup(s.namespace) for s in roots), np.int32, R)
     r_obj[:R] = np.fromiter(
@@ -414,8 +475,8 @@ def _dispatch_roots(g, vocab, roots, rest_depth: int, fanout: int, cap: int):
     r_subj[:R] = np.fromiter(
         (vocab.subject_key(s) for s in roots), np.int32, R)
     r_depth[:R] = rest_depth
-    sched = expand_schedule(Rp, fanout, rest_depth, cap)
+    sched = expand_schedule(Rp, fanout, rest_depth, rung_cap(rung, Rp, cap))
     with compilewatch.scope("expand", lambda: f"R={Rp} sched={sched}"):
         return _run_expand(
             g, r_ns, r_obj, r_rel, r_subj, r_depth, schedule=sched
-        )
+        ), sched

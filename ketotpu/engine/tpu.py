@@ -240,6 +240,9 @@ class DeviceCheckEngine:
         # left unanswered on a wave's first pass, by tier
         self.tickets = self.ticket_waves = 0
         self.overflow_rows = {"fast": 0, "general": 0}
+        # subject-set roots of batch_expand by what answered them: the
+        # first rung of level capacities, the full rung, or the oracle
+        self.expand_roots = {"first": 0, "full": 0, "oracle": 0}
         self.projection_build_s = 0.0  # host-side snapshot build
         self.projection_upload_s = 0.0  # device upload (blocked)
         self._expand_extra = None  # lazily shipped expand tables
@@ -971,6 +974,7 @@ class DeviceCheckEngine:
                 },
                 "tag_rejects": dict(hashtab.TAG_REJECTS),
                 "device_bytes": self._device_bytes(),
+                "expand_roots": dict(self.expand_roots),
             }
         # the served hash tables, as the device programs unroll them
         # (engine/hashtab.py): probe rounds, gathers a lookup, the tag
@@ -2114,8 +2118,11 @@ class DeviceCheckEngine:
         membership deltas host-side (expand_device.OverlayMembers) —
         added subject-set subtrees recurse through the sequential engine
         with the shared visited set, so writes stay exactly visible
-        without the blanket fall-to-oracle r2 shipped.  Only overflowed
-        roots fall back to the sequential oracle expand (live store)."""
+        without the blanket fall-to-oracle r2 shipped.  The dispatch runs
+        on the first rung of level capacities; the roots it overflows run
+        again on the full rung (``cap`` a level), and only the roots that
+        overflow that too fall back to the sequential oracle expand (live
+        store).  ``expand_roots`` counts which of the three answered."""
         from ketotpu.api.types import SubjectID, SubjectSet, Tree, TreeNodeType
         from ketotpu.engine import expand_device as xd
         from ketotpu.engine.oracle import ExpandEngine
@@ -2151,16 +2158,28 @@ class DeviceCheckEngine:
             for i in set_idx:
                 self.fallbacks += 1
                 out[i] = oracle.build_tree(subjects[i], rest_depth)
+            self.expand_roots["oracle"] += len(set_idx)
             return out
+
+        def run(batch, **kw):
+            return xd.run_expand(
+                xarrays, snap, batch, rest_depth,
+                max_depth=self.max_depth, fanout=fanout, cap=cap, ov=ov,
+                sub_expand=oracle._build, span=self._span, **kw,
+            )
+
         try:
             faults.inject("device_dispatch")
-            trees, over = xd.run_expand(
-                xarrays, snap, roots, rest_depth,
-                max_depth=self.max_depth, fanout=fanout, cap=cap,
-                ov=ov,
-                sub_expand=oracle._build,
-                span=self._span,
-            )
+            trees, over = run(roots, rung="first")
+            by = ["first"] * len(roots)
+            redo = np.flatnonzero(over)
+            if len(redo):
+                # padded like the first rung: the full program it warmed
+                full, over_full = run(
+                    [roots[k] for k in redo], rung="full", pad_to=len(roots)
+                )
+                for k, tree, o in zip(redo, full, over_full):
+                    trees[k], over[k], by[k] = tree, o, "full"
         except KetoAPIError:
             raise
         except Exception:  # noqa: BLE001
@@ -2173,22 +2192,24 @@ class DeviceCheckEngine:
                     deadline.check("oracle fallback")
                     self.fallbacks += 1
                     out[i] = oracle.build_tree(subjects[i], rest_depth)
+            self.expand_roots["oracle"] += len(set_idx)
             self._rpc_fallback_stage("expand", time.perf_counter() - t_fb)
             return out
-        if not over.any():
-            for k, i in enumerate(set_idx):
-                out[i] = trees[k]
-            return out
-        t_fb = time.perf_counter()
-        with self._span("expand_oracle_fallback", roots=int(over.sum())):
-            for k, i in enumerate(set_idx):
-                if over[k]:
+        for k, i in enumerate(set_idx):
+            out[i] = trees[k]
+        if over.any():
+            t_fb = time.perf_counter()
+            with self._span("expand_oracle_fallback", roots=int(over.sum())):
+                for k in np.flatnonzero(over):
                     deadline.check("oracle fallback")
                     self.fallbacks += 1
-                    out[i] = oracle.build_tree(subjects[i], rest_depth)
-                else:
-                    out[i] = trees[k]
-        self._rpc_fallback_stage("expand", time.perf_counter() - t_fb)
+                    by[k] = "oracle"
+                    out[set_idx[k]] = oracle.build_tree(
+                        roots[k], rest_depth
+                    )
+            self._rpc_fallback_stage("expand", time.perf_counter() - t_fb)
+        for rung in by:
+            self.expand_roots[rung] += 1
         return out
 
     def batch_check_device_only(

@@ -146,8 +146,8 @@ def test_fused_wave_compiles_for_v5e(topo, no_cache, engine, graph,
 
 
 def test_expand_compiles_for_v5e(topo, no_cache, engine, monkeypatch):
-    """The batched Expand program as batch_expand dispatches a depth-5
-    request (its default fan-out and cap)."""
+    """The batched Expand programs as batch_expand dispatches a depth-5
+    request (its default fan-out and cap): the full rung and the first."""
     from ketotpu.api.types import SubjectSet
 
     roots = [SubjectSet("Folder", f"f{i}", "viewers") for i in range(8)]
@@ -155,12 +155,15 @@ def test_expand_compiles_for_v5e(topo, no_cache, engine, monkeypatch):
         monkeypatch, xd, "_run_expand",
         lambda: xd.run_expand(
             engine._expand_arrays(), engine.snapshot(), roots, 5,
-            max_depth=5,
+            max_depth=5, rung="full",
         ),
     )
     assert static["schedule"] == (8, 128, 2048, 32768, 65536)
+    first = xd.expand_schedule(8, 16, 5, xd.rung_cap("first", 8, 65536))
+    assert first == (8, 128, 2048, 2048, 2048)
     one_chip = _on(SingleDeviceSharding(topo.devices[0]))
-    _compiled(xd._run_expand, one_chip(args), static)
+    for schedule in (static["schedule"], first):
+        _compiled(xd._run_expand, one_chip(args), {"schedule": schedule})
 
 
 def test_leopard_probe_compiles_for_v5e(topo, no_cache, engine):

@@ -263,3 +263,93 @@ class TestOverlayMultiplicity:
         oracle = ExpandEngine(graph.store, max_depth=eng.max_depth)
         assert _trees_equal(out[0], oracle.build_tree(s))
         assert str(out[0].to_json()).count("twice") == 2
+
+
+class TestRungs:
+    """Two rungs of level capacities: the first clamps the schedule at
+    ``FIRST_RUNG`` slots a padded root, the roots it overflows run again
+    at ``cap``, and only a root that overflows that too goes to the
+    oracle; ``expand_roots`` counts which answered."""
+
+    @pytest.mark.parametrize("n_roots,levels", [
+        (8, (8, 128, 2048, 2048, 2048)),
+        (16, (16, 256, 4096, 4096, 4096)),
+        (64, (64, 1024, 16384, 16384, 16384)),
+    ])
+    def test_first_rung_schedule(self, n_roots, levels):
+        cap = xd.rung_cap("first", n_roots, 65536)
+        assert xd.expand_schedule(n_roots, 16, 5, cap) == levels
+        assert xd.rung_cap("full", n_roots, 65536) == 65536
+
+    @staticmethod
+    def _wide_store():
+        # level 1: 100 groups, level 2: 1,600, level 3: 3,200 users, past
+        # the first rung's 2,048 slots and inside the full rung's 32,768
+        lines = [f"g:wide#m@g:a{i}#m" for i in range(100)]
+        for i in range(100):
+            lines += [f"g:a{i}#m@g:b{i}_{j}#m" for j in range(16)]
+            for j in range(16):
+                lines += [f"g:b{i}_{j}#m@u{i}_{j}_{k}" for k in range(2)]
+        lines += ["g:small#m@g:s1#m", "g:small#m@zoe", "g:s1#m@yan",
+                  "g:tiny#m@xia"]
+        return _store(lines)
+
+    def _expand(self, roots, **kw):
+        store = self._wide_store()
+        eng = DeviceCheckEngine(store, None)
+        oracle = ExpandEngine(store, max_depth=eng.max_depth)
+        out = eng.batch_expand(roots, **kw)
+        for root, got in zip(roots, out):
+            assert _trees_equal(got, oracle.build_tree(root)), root
+        return eng, out
+
+    def test_overflow_answered_by_full_rung(self):
+        eng, out = self._expand([SubjectSet("g", "wide", "m")])
+        assert str(out[0].to_json()).count("'u99_15_1'") == 1
+        assert eng.expand_roots == {"first": 0, "full": 1, "oracle": 0}
+        assert eng.fallbacks == 0
+
+    def test_overflow_of_both_rungs_goes_to_the_oracle(self):
+        # the full rung clamped at 3,000 slots cannot hold level 3 either
+        eng, _ = self._expand([SubjectSet("g", "wide", "m")], cap=3000)
+        assert eng.expand_roots == {"first": 0, "full": 0, "oracle": 1}
+        assert eng.fallbacks == 1
+
+    def test_mixed_batch_reruns_only_the_overflowed_root(self, monkeypatch):
+        calls = []
+        real = xd.run_expand
+
+        def spy(g, snap, roots, rest_depth, **kw):
+            trees, over = real(g, snap, roots, rest_depth, **kw)
+            calls.append((kw["rung"], list(roots), list(trees)))
+            return trees, over
+
+        monkeypatch.setattr(xd, "run_expand", spy)
+        small = [SubjectSet("g", "small", "m"), SubjectSet("g", "tiny", "m"),
+                 SubjectSet("g", "s1", "m")]
+        wide = SubjectSet("g", "wide", "m")
+        eng, out = self._expand(small + [wide])
+        assert [(rung, roots) for rung, roots, _ in calls] == [
+            ("first", small + [wide]), ("full", [wide])]
+        assert calls[0][2][:3] == out[:3]  # the first rung's trees, as such
+        assert eng.expand_roots == {"first": 3, "full": 1, "oracle": 0}
+
+    def test_first_dispatch_warms_the_full_rung(self, monkeypatch):
+        """The full rung's program is dispatched beside the first rung's
+        first dispatch, once: an overflow later compiles nothing."""
+        scheds = []
+        real = xd._run_expand
+
+        def spy(*args, schedule):
+            scheds.append(schedule)
+            return real(*args, schedule=schedule)
+
+        monkeypatch.setattr(xd, "_run_expand", spy)
+        monkeypatch.setattr(xd, "_FULL_RUNG_WARM", set())
+        store = _store(["g:a#m@g:b#m", "g:b#m@carol"])
+        eng = DeviceCheckEngine(store, None)
+        for _ in range(2):
+            eng.batch_expand([SubjectSet("g", "a", "m")])
+        assert scheds == [(8, 128, 2048, 32768, 65536),
+                          (8, 128, 2048, 2048, 2048),
+                          (8, 128, 2048, 2048, 2048)]

@@ -513,7 +513,7 @@ def test_expand_program_carries_every_level_scope(engine, monkeypatch):
 
     def catch(g, *roots, schedule):
         caught.update(args=(g, *roots), schedule=schedule)
-        return None, None
+        return None
 
     monkeypatch.setattr(xd, "_run_expand", catch)
     xd._dispatch_roots(

@@ -222,6 +222,17 @@ def test_columnar_metric_vocabulary(scrape):
     assert {"decode", "encode_ids", "wave_wait", "respond"} <= stages, stages
 
 
+def test_expand_rung_vocabulary(scrape):
+    """What answered each Expand root: the smoke's tree fits the first
+    rung of level capacities, so neither the full rung nor the oracle
+    answered one."""
+    text = scrape["metrics_text"]
+    for rung in ("first", "full", "oracle"):
+        assert f'keto_engine_expand_roots_total{{rung="{rung}"}}' in text
+    roots = scrape["projection"]["expand_roots"]
+    assert roots["first"] >= 1 and roots["full"] == roots["oracle"] == 0
+
+
 def test_projection_metric_vocabulary(scrape):
     """ISSUE 8: projection/compaction observability — generation and
     fold/rebuild/compaction counters as gauges, per-phase build seconds,
