@@ -370,13 +370,17 @@ def _leopard_rates(eng, graph, *, calls: int, seed: int):
 def _leopard_deep(*, depth, n_chains, n_queries, seed):
     """(p50_batch_ms, oracle_fallback_delta) for deep nested-group checks.
 
-    A dedicated rewrite-free chain graph (utils/synth.build_deep_groups):
-    every check needs ``depth`` containment hops, so on the closure path
-    each one is a single binary search and NO device program is ever
-    compiled — the whole batch is answered pre-dispatch.  n_users stays at
-    the default 64 so the deepest groups sit under leopard's max_width
-    taint threshold (wider groups would route the workload back to the
-    device, which is a different benchmark)."""
+    A dedicated rewrite-free chain graph (utils/synth.build_deep_groups)
+    with the engine called directly: every check needs ``depth``
+    containment hops, so on the closure path each one is a single binary
+    search and NO device program is ever compiled — the whole batch is
+    answered pre-dispatch.  n_users stays at the default 64 so the
+    deepest groups sit under leopard's max_width taint threshold.  The
+    served form of this workload is the benchmark cell
+    ``groups-deep32.members1k`` (benchmark/configs/groups-deep32.json):
+    chains 2 to 32 deep and 10 to 100 wide behind the REST door, where
+    wide groups do send their chains' rows to the device's BFS beside
+    the closure tier, in one wave."""
     from ketotpu.engine.tpu import DeviceCheckEngine
     from ketotpu.utils.synth import build_deep_groups, deep_queries
 

@@ -1714,6 +1714,13 @@ class Registry:
                     help="closure index full builds")
             m.gauge("keto_leopard_build_seconds", ls["build_s"],
                     help="last closure build wall time")
+        for outcome, rows in eng.leopard_rows.items():
+            m.gauge("keto_leopard_rows_total", rows,
+                    help="check rows the closure index was asked about, by "
+                         "what became of them: answered, or declined as "
+                         "tainted, dirty, ineligible, or hit beyond the "
+                         "depth budget (counted at collect)",
+                    outcome=outcome)
         if eng._gen_fast_ema is not None:
             m.gauge("keto_engine_occupancy", float(eng._gen_fast_ema),
                     help="EMA per-level frontier occupancy",

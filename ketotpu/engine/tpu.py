@@ -287,6 +287,9 @@ class DeviceCheckEngine:
         self._leo_device = None
         self.leopard_answered = 0  # checks answered from the index
         self.leopard_hits = 0  # of those, answered allowed
+        # rows the index was asked about, by what became of them
+        # (keto_leopard_rows_total{outcome}; counted at collect)
+        self.leopard_rows = dict.fromkeys(leo.OUTCOMES, 0)
         self.leopard_list_fallbacks = 0  # listings served by the host oracle
         # warm heuristic for the compile observatory: after this many
         # consecutive check dispatches that triggered zero XLA compiles,
@@ -1438,22 +1441,24 @@ class DeviceCheckEngine:
         )
 
     def _leopard_answers(self, enc, err, general):
-        """(allowed, answered) bool arrays from the closure index, or None
-        while the index is off.  Runs under the sync lock so verdicts are
+        """``((allowed, answered), why)`` from the closure index (bool
+        arrays, and closure.WHY_* a row), or ``(None, None)`` while the
+        index is off.  Runs under the sync lock so verdicts are
         exact against the latest folded write (same contract as overlay
         probes); the probe itself is one binary search over the sorted
         pairs — on-device for large chunks, host numpy otherwise."""
         if self._leopard is None or self.strict_mode:
-            return None
+            return None, None
         q_ns, q_obj, q_rel, q_subj, q_depth = enc
         n = len(q_ns)
         if n == 0:
-            return None
+            return None, None
         with self._sync_lock:
             idx = self._leopard
             if idx is None:
-                return None
+                return None, None
             nodes, node_hi = idx.node_ids_np(q_ns, q_obj, q_rel)
+            why = idx.why_declined(nodes, node_hi)
             probed = None
             if self._leo_device is not None and n >= leodev.DEVICE_PROBE_MIN:
                 keys = np.where(
@@ -1470,11 +1475,12 @@ class DeviceCheckEngine:
                     # probed=None: the host searchsorted answers below
                     self._device_failure("leopard probe")
             allowed, answered = idx.answer_checks(
-                nodes, q_subj, node_hi, int(q_depth[0]), probed=probed
+                nodes, q_subj, why, int(q_depth[0]), probed=probed
             )
         answered &= ~(err | general)
         allowed &= answered
-        return allowed, answered
+        why[err | general] = leo.WHY_INELIGIBLE
+        return (allowed, answered), why
 
     def _dispatch(self, queries: Sequence[RelationTuple], rest_depth: int,
                   fused: Optional[bool] = None, like=(0, 0)):
@@ -1500,14 +1506,15 @@ class DeviceCheckEngine:
                 # the program finishes the closure probe itself and masks
                 # the rows it answers; the cache is told which rows the
                 # host already KNOWS are answered
-                known, probe = self._leopard_modes(
+                known, probe, leo_why = self._leopard_modes(
                     enc, err, general, rest_depth)
             else:
                 # Leopard first: closure-eligible fast queries resolve as
                 # one sorted-pair binary search and leave the device walk
                 # entirely (their active bit drops, so the BFS does no
                 # work for them)
-                known = leo_res = self._leopard_answers(enc, err, general)
+                leo_res, leo_why = self._leopard_answers(enc, err, general)
+                known = leo_res
                 if leo_res is not None:
                     active &= ~leo_res[1]
             # hot-spot shield after Leopard: cached verdicts drop their
@@ -1520,7 +1527,7 @@ class DeviceCheckEngine:
                 n=n, qpad=wv.wave_rows(max(n, like[0]), self.frontier),
                 enc=enc, err=err, general=general, cursor=cursor,
                 arrays=arrays, leo_res=leo_res, cache_res=cache_res,
-                gen_like=like[1])
+                leo_why=leo_why, gen_like=like[1])
             active = self._route(queries, rest_depth, wave, active)
             padded = self._pad(enc, n, wave.qpad)
             if use_fused:
@@ -1586,26 +1593,26 @@ class DeviceCheckEngine:
         probe modes; answered-masks gate the fast tier in-program, so
         resolved rows are dead weight instead of host-filtered between
         dispatches.  Returns ``(known, (lmode, leo_set, leo_elt,
-        leo_dev))``: ``known`` is what the result cache is told — ``(None,
-        rows the host already KNOWS are answered)``, or None with the
-        index off."""
+        leo_dev), why)``: ``known`` is what the result cache is told —
+        ``(None, rows the host already KNOWS are answered)``, or None with
+        the index off; ``why`` the rows' closure.WHY_* (None: off).  The
+        host half is the engine phase ``check_leopard_prep``."""
         q_ns, q_obj, q_rel, q_subj, q_depth = enc
         n = len(q_ns)
         lmode = np.zeros(n, np.int32)
         leo_set = np.full(n, -1, np.int32)
         leo_elt = np.full(n, -1, np.int32)
-        leo_dev = None
-        has_leo = False
+        leo_dev = why = None
         if self._leopard is not None and not self.strict_mode:
-            with self._sync_lock:
+            with self._sync_lock, self._span("check_leopard_prep", rows=n):
                 idx = self._leopard
                 if idx is not None:
-                    has_leo = True
                     nodes, node_hi = idx.node_ids_np(q_ns, q_obj, q_rel)
+                    why = idx.why_declined(nodes, node_hi)
                     leo_dev = self._leo_device
                     if leo_dev is not None:
                         lmode = idx.prep_fused_checks(
-                            nodes, q_subj, node_hi, rest_depth
+                            nodes, q_subj, why, rest_depth
                         )
                         probe_ok = (nodes >= 0) & (q_subj >= 0)
                         leo_set = np.where(probe_ok, nodes, -1).astype(
@@ -1620,7 +1627,7 @@ class DeviceCheckEngine:
                         # as pre-resolved modes — LM_ALLOW/LM_DENY need
                         # no pairs on the device
                         allowed, answered = idx.answer_checks(
-                            nodes, q_subj, node_hi, int(q_depth[0])
+                            nodes, q_subj, why, int(q_depth[0])
                         )
                         lmode[answered & allowed] = leo.LM_ALLOW
                         lmode[answered & ~allowed] = leo.LM_DENY
@@ -1628,9 +1635,10 @@ class DeviceCheckEngine:
         # the cache sees every row the host KNOWS is unanswered; rows the
         # device probe may yet answer keep leopard precedence at collect
         known = None
-        if has_leo:
+        if why is not None:
+            why[err | general] = leo.WHY_INELIGIBLE
             known = (None, (lmode == leo.LM_ALLOW) | (lmode == leo.LM_DENY))
-        return known, (lmode, leo_set, leo_elt, leo_dev)
+        return known, (lmode, leo_set, leo_elt, leo_dev), why
 
     def _encode_fused(self, wave, padded, fast_elig, has_leo, probe):
         """Host half of the fused launch (inside ``_dispatch``'s
@@ -1945,7 +1953,7 @@ class DeviceCheckEngine:
             return self._collect_fused(wave)
         n = wave.n
         if wave.leo_res is not None:
-            self._count_leopard(*wave.leo_res)
+            self._count_leopard(*wave.leo_res, wave.leo_why)
         boosted = retry and self.retry_scale > 1
         g_is = np.zeros(n, bool)
         g_fb = np.zeros(n, bool)
@@ -1991,12 +1999,15 @@ class DeviceCheckEngine:
         self._after_collect(wave, allowed, fallback)
         return allowed, fallback
 
-    def _count_leopard(self, allowed, answered) -> None:
-        """Leopard's answers are counted where their wave is collected,
-        whichever launcher ran it: every counter a wave moves after its
-        launch moves on the collecting thread."""
+    def _count_leopard(self, allowed, answered, why) -> None:
+        """Leopard's answers, and every row it was asked about by what
+        became of it (``why``: closure.WHY_*), are counted where their wave
+        is collected, whichever launcher ran it: every counter a wave
+        moves after its launch moves on the collecting thread."""
         self.leopard_answered += int(answered.sum())
         self.leopard_hits += int(allowed.sum())
+        for outcome, rows in leo.outcomes(why, answered).items():
+            self.leopard_rows[outcome] += rows
 
     def _collect_fused(self, wave):
         """Sync one fused wave: ONE D2H fetch returns the verdict codes
@@ -2026,7 +2037,7 @@ class DeviceCheckEngine:
             self.overflow_rows[tier] += int(rows.sum())
         if meta["has_leo"]:
             wave.leo_res = (bits.leo_allow, bits.leo_ans)
-            self._count_leopard(*wave.leo_res)
+            self._count_leopard(*wave.leo_res, wave.leo_why)
         # fast_fb: of the fast-active rows alone (no leopard or cache hit)
         allowed, fallback = wv.merge(
             wave.err, wave.general, wv.general_allowed(bits.general),

@@ -39,6 +39,9 @@ class Wave:
     leo_res: Optional[Tuple[Optional[np.ndarray], np.ndarray]] = None
     cache_res: Optional[Tuple[np.ndarray, np.ndarray]] = None
     # (allowed, answered) and (cached, verdicts); None: off, or no hit.
+    #: why the closure index may not answer each row (closure.WHY_*);
+    #: None while the index is off
+    leo_why: Optional[np.ndarray] = None
     # The launcher's uncollected device results: the cascade's fast tier
     # and its occupancy, its general rows and their (codes, occ, rows,
     # fast_b); or a fused wave's one array, which ``meta`` describes

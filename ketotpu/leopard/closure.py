@@ -18,11 +18,14 @@ repeatedly self-joined (frontier doubling — min-plus matrix squaring, so
 CSR expansion and packed-int64 ``lexsort`` dedup, the same idiom
 ``delta.build_snapshot_cols`` uses.  No per-tuple Python loops.
 
-Hop counts make check interception *depth-exact*: a pair at ``h`` hops is
-found by the reference engine iff the remaining depth budget is at least
-``h + 2`` (one level to enter the relation, one to match the subject —
-see ``CheckEngine._check_is_allowed``'s depth guards).  A hit below that
-budget simply declines, falling through to the normal device walk.
+Hop counts make check interception *depth-safe*: a pair at ``h`` hops is
+found by the reference engine whenever the remaining depth budget is at
+least ``h + 2`` (one level to enter the relation, one to match the
+subject — see ``CheckEngine._check_is_allowed``'s depth guards).  A hit
+below that budget simply declines, falling through to the normal device
+walk.  The rule is one level cautious for ``h >= 1``: the expansion's
+EXISTS probe reads a child set's own tuples, so the reference finds such
+a pair at ``h + 1`` already, and those rows are the walk's.
 
 Exactness envelope.  Closure verdicts are the BFS-complete answer, which
 is exactly the upper end of the engine's documented arbitration band
@@ -53,9 +56,10 @@ import numpy as np
 
 from ketotpu.api.types import RelationTuple, SubjectSet
 
-# Containment chains of h hops need h + 2 depth budget in the reference
-# engine (each _check_* level spends one unit; the final traverser match
-# happens one level below the last expansion).
+# Containment chains of h hops are found within h + 2 depth budget in the
+# reference engine (each _check_* level spends one unit; the final
+# traverser match happens one level below the last expansion); h + 1
+# suffices for h >= 1, where the expansion's EXISTS probe matches.
 DEPTH_SLACK = 2
 
 # Fused-dispatch probe row modes (engine/fused.py): prep_fused_checks
@@ -70,7 +74,28 @@ LM_ALLOW = 2  # pre-answered allow (delta pair within the depth budget)
 LM_DENY = 3  # pre-answered deny (unknown node, rewrite-free relation)
 LM_HIT_ONLY = 4  # delta pair beyond budget: answer only on base hit+depth
 
+# Why the index may not answer a row (``why_declined``); an eligible row
+# that still comes back unanswered met its pair beyond the depth budget.
+WHY_ELIGIBLE, WHY_TAINTED, WHY_DIRTY, WHY_INELIGIBLE = 0, 1, 2, 3
+#: what became of each row the index was asked about, one of these
+#: (keto_leopard_rows_total{outcome}; ``outcomes``)
+OUTCOMES = ("answered", "tainted", "ineligible", "beyond_depth", "dirty")
+
 _EMPTY32 = np.empty(0, np.int32)
+
+
+def outcomes(why: np.ndarray, answered: np.ndarray) -> Dict[str, int]:
+    """Rows by what became of them: answered, or declined by cause
+    (``why``, from :meth:`ClosureIndex.why_declined`); every row in
+    exactly one."""
+    left = why[~answered]
+    return {
+        "answered": int(answered.sum()),
+        "tainted": int((left == WHY_TAINTED).sum()),
+        "ineligible": int((left == WHY_INELIGIBLE).sum()),
+        "beyond_depth": int((left == WHY_ELIGIBLE).sum()),
+        "dirty": int((left == WHY_DIRTY).sum()),
+    }
 
 
 def _dedup_min(src: np.ndarray, dst: np.ndarray, hop: np.ndarray):
@@ -441,19 +466,55 @@ class ClosureIndex:
     def _is_tainted(self, node: int) -> bool:
         return bool(self.tainted[node]) or node in self._d_taint
 
+    def why_declined(
+        self, nodes: np.ndarray, node_hi: np.ndarray
+    ) -> np.ndarray:
+        """Per query, whether the index may answer it (WHY_ELIGIBLE) or
+        why not: its node is tainted (base or delta), dirtied by a
+        deletion, or unknown under a relation a rewrite could reach
+        (WHY_INELIGIBLE).  Counts the known queries on dirty nodes as
+        declines (``fallbacks``): call it once a batch."""
+        why = np.zeros(len(nodes), np.int8)
+        known = nodes >= 0
+        if self._rewrite_his:
+            rw = np.isin(
+                node_hi,
+                np.fromiter(
+                    self._rewrite_his, np.int64, len(self._rewrite_his)
+                ),
+            )
+            why[~known & rw] = WHY_INELIGIBLE
+        if known.any() and self.n_nodes:
+            kn = np.flatnonzero(known)
+            node_k = nodes[kn]
+            tainted = self.tainted[node_k]
+            if self._d_taint:
+                tainted = tainted | np.isin(node_k, np.fromiter(
+                    self._d_taint, np.int64, len(self._d_taint)))
+            why_k = np.where(tainted, WHY_TAINTED, WHY_ELIGIBLE)
+            if self.dirty:
+                # observability: checks that had to decline because a
+                # deletion dirtied the set they touch
+                dirty = np.isin(node_k, np.fromiter(
+                    self.dirty, np.int64, len(self.dirty)))
+                self.fallbacks += int(dirty.sum())
+                why_k[dirty & ~tainted] = WHY_DIRTY
+            why[kn] = why_k
+        return why
+
     def answer_checks(
         self,
         nodes: np.ndarray,
         subjects: np.ndarray,
-        node_hi: np.ndarray,
+        why: np.ndarray,
         rest_depth: int,
         probed: Optional[Tuple[np.ndarray, np.ndarray]] = None,
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Batched membership verdicts: (allowed, answered) bool arrays.
 
         ``nodes`` is int32 node ids (-1 = node unknown to the index);
-        ``node_hi`` is the packed ``ns * R + rel`` per query (for the
-        rewrite-eligibility test of unknown nodes); ``probed`` optionally
+        ``why`` the batch's :meth:`why_declined` (the rewrite-eligibility
+        test of unknown nodes, taint, dirt); ``probed`` optionally
         carries precomputed whole-batch (hit, hop) arrays from the device
         probe (leopard/device.py) — bit-identical to the host search.  A
         query is answered iff its verdict is provably what the engine
@@ -476,29 +537,12 @@ class ClosureIndex:
         # unknown node: no tuples => deny, unless a rewrite could reach
         # members anyway (node_hi = -1 means the namespace or relation
         # string is not even interned, so no rewrite can exist for it)
-        if self._rewrite_his:
-            rw = np.isin(
-                node_hi,
-                np.fromiter(
-                    self._rewrite_his, np.int64, len(self._rewrite_his)
-                ),
-            )
-        else:
-            rw = np.zeros(n, bool)
-        answered |= ~known & ~rw
+        answered |= ~known & (why == WHY_ELIGIBLE)
 
         if known.any() and self.n_nodes:
             kn = np.flatnonzero(known)
             node_k = nodes[kn]
-            clean = ~self.tainted[node_k]
-            if self._d_taint or self.dirty:
-                bad = self._d_taint | self.dirty
-                clean &= ~np.isin(node_k, np.fromiter(bad, np.int64, len(bad)))
-            if self.dirty:
-                # observability: checks that had to decline because a
-                # deletion dirtied the set they touch
-                darr = np.fromiter(self.dirty, np.int64, len(self.dirty))
-                self.fallbacks += int(np.isin(node_k, darr).sum())
+            clean = why[kn] == WHY_ELIGIBLE
             if probed is not None:
                 hit = probed[0][kn].copy()
                 hop = probed[1][kn]
@@ -537,7 +581,7 @@ class ClosureIndex:
         self,
         nodes: np.ndarray,
         subjects: np.ndarray,
-        node_hi: np.ndarray,
+        why: np.ndarray,
         rest_depth: int,
     ) -> np.ndarray:
         """Host half of ``answer_checks`` for the fused wave cascade:
@@ -561,36 +605,20 @@ class ClosureIndex:
         * LM_NONE — tainted/dirty node, or unknown node with a reachable
           rewrite: answer_checks declines, the device must not answer.
 
-        The dirty-set decline counter increments here with the same
-        coverage as answer_checks (all known rows at probe time).
+        Both read the batch's :meth:`why_declined` (``why``), so the
+        dirty-set decline counter moves with the same coverage (all known
+        rows at probe time).
         """
         n = len(nodes)
         lmode = np.zeros(n, np.int32)
         if n == 0:
             return lmode
         known = nodes >= 0
-        if self._rewrite_his:
-            rw = np.isin(
-                node_hi,
-                np.fromiter(
-                    self._rewrite_his, np.int64, len(self._rewrite_his)
-                ),
-            )
-        else:
-            rw = np.zeros(n, bool)
-        lmode[~known & ~rw] = LM_DENY
+        lmode[~known & (why == WHY_ELIGIBLE)] = LM_DENY
         if known.any() and self.n_nodes:
             kn = np.flatnonzero(known)
             node_k = nodes[kn]
-            clean = ~self.tainted[node_k]
-            if self._d_taint or self.dirty:
-                bad = self._d_taint | self.dirty
-                clean &= ~np.isin(
-                    node_k, np.fromiter(bad, np.int64, len(bad))
-                )
-            if self.dirty:
-                darr = np.fromiter(self.dirty, np.int64, len(self.dirty))
-                self.fallbacks += int(np.isin(node_k, darr).sum())
+            clean = why[kn] == WHY_ELIGIBLE
             mode_k = np.where(clean, LM_PROBE, LM_NONE).astype(np.int32)
             if self._d_elt:
                 for j in np.flatnonzero(clean).tolist():

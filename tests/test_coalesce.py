@@ -215,8 +215,12 @@ class TwoPhase:
     that raises for it ("submit" or "collect")."""
 
     def __init__(self, collect_s=0.0, fail=None, retry_ok=False,
-                 row_errors=None):
+                 row_errors=None, hold=None):
         self.collect_s = collect_s
+        # ``hold``: a serial whose collect waits (10 s at most) until the
+        # serial after it is submitted
+        self.hold = hold
+        self.submitted = {}  # serial -> threading.Event
         self.fail = dict(fail or {})
         self.retry_ok = retry_ok
         self.row_errors = dict(row_errors or {})
@@ -230,6 +234,10 @@ class TwoPhase:
     def _note(self, event, serial):
         self.log.append((event, serial, threading.get_ident()))
 
+    def _submitted(self, serial):
+        with self.lock:
+            return self.submitted.setdefault(serial, threading.Event())
+
     def submit(self, queries, depth=0):
         with self.lock:
             self.serial += 1
@@ -238,12 +246,15 @@ class TwoPhase:
             self.most_uncollected = max(
                 self.most_uncollected, self.uncollected)
             self._note("submit", serial)
+            self.submitted.setdefault(serial, threading.Event()).set()
         if self.fail.get(serial) == "submit":
             raise RuntimeError(f"submit {serial} failed")
         return serial, queries
 
     def collect(self, ticket, errs=None):
         serial, queries = ticket
+        if serial == self.hold:
+            self._submitted(serial + 1).wait(10)
         time.sleep(self.collect_s)
         with self.lock:
             self.uncollected -= 1
@@ -266,9 +277,11 @@ class TwoPhase:
         return self.collect(self.submit(block, depth), errs={})
 
 
-def _closed_loop(eng, callers, each):
+def _closed_loop(eng, callers, each, lead=None):
     """``callers`` threads, each sending ``each`` distinct checks one after
-    the other; returns the outcomes (a verdict or the exception)."""
+    the other; returns the outcomes (a verdict or the exception).  With
+    ``lead``, the first caller starts alone and the others once ``lead()``
+    returns."""
     out = {}
 
     def run(c):
@@ -279,7 +292,10 @@ def _closed_loop(eng, callers, each):
                 out[c, k] = e
 
     threads = [threading.Thread(target=run, args=(c,)) for c in range(callers)]
-    for t in threads:
+    threads[0].start()
+    if lead is not None:
+        lead()
+    for t in threads[1:]:
         t.start()
     for t in threads:
         t.join(30)
@@ -288,9 +304,14 @@ def _closed_loop(eng, callers, each):
 
 
 def test_next_wave_is_submitted_while_the_wave_before_is_collected():
-    inner = TwoPhase(collect_s=0.02)
+    # closed-loop callers that share a wave stay in step, and a wave ahead
+    # needs callers out of step: the first caller's wave goes alone, and
+    # its collect is held until the others' wave is submitted, however
+    # slowly a loaded host schedules the threads
+    inner = TwoPhase(collect_s=0.02, hold=1)
     eng = CoalescingEngine(inner, window=0.001)
-    out = _closed_loop(eng, callers=8, each=6)
+    out = _closed_loop(eng, callers=8, each=6,
+                       lead=lambda: inner._submitted(1).wait(10))
     eng.close()
     assert list(out.values()) == [True] * 48
     assert inner.direct == []  # everything through the pair

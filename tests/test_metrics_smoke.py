@@ -233,6 +233,15 @@ def test_expand_rung_vocabulary(scrape):
     assert roots["first"] >= 1 and roots["full"] == roots["oracle"] == 0
 
 
+def test_leopard_rows_vocabulary(scrape):
+    """Every row the closure index is asked about lands in one outcome of
+    ``keto_leopard_rows_total``; each outcome is on the scrape."""
+    text = scrape["metrics_text"]
+    for outcome in ("answered", "tainted", "ineligible", "beyond_depth",
+                    "dirty"):
+        assert f'keto_leopard_rows_total{{outcome="{outcome}"}}' in text
+
+
 def test_projection_metric_vocabulary(scrape):
     """ISSUE 8: projection/compaction observability — generation and
     fold/rebuild/compaction counters as gauges, per-phase build seconds,
