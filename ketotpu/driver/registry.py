@@ -1584,6 +1584,13 @@ class Registry:
                          "added a fused wave at its collect (over "
                          "keto_fused_waves_total: gathers a lookup)",
                     table=table)
+        for rung, levels in eng.fast_rung_levels.items():
+            m.gauge("keto_fused_fast_rung_levels_total", levels,
+                    help="folded levels of the fast BFS by the rung they "
+                         "ran at: a quarter of the wave's rows, its rows, "
+                         "or the level's full size (a narrow rung's redo "
+                         "counts as full; counted at collect)",
+                    rung=rung)
         for tier, rows in eng.fused_tier_rows.items():
             m.gauge("keto_fused_tier_rows_total", rows,
                     help="fused-wave rows attributed per answering tier",

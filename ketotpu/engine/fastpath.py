@@ -208,13 +208,17 @@ def expand_phase(
     arena: int,
     max_width: int,
     probe_only: bool = False,
+    rewrites: bool = True,
 ) -> Tuple[Dict[str, jax.Array], jax.Array, jax.Array]:
-    """Probes + child construction.  Returns (children[A] cols + alive, found, over)."""
+    """Probes + child construction.  Returns (children[A] cols + alive, found, over).
+    ``rewrites=False`` leaves out the computed-subject-set and
+    tuple-to-userset columns: exact only where no item's (namespace,
+    relation) has one (``has_rewrites``)."""
     A = arena
     F = s["f_qid"].shape[0]
     NS, R = g["f_direct_ok"].shape
-    Kc = g["f_css_rel"].shape[2]
-    Kt = g["f_ttu_via"].shape[2]
+    Kc = g["f_css_rel"].shape[2] if rewrites else 0
+    Kt = g["f_ttu_via"].shape[2] if rewrites else 0
     Q = s["q_found"].shape[0]
 
     qid, ns, obj, rel = s["f_qid"], s["f_ns"], s["f_obj"], s["f_rel"]
@@ -242,10 +246,11 @@ def expand_phase(
 
     # batched computed-subject-set probes (rewrites.go:62-93); the rewrite
     # level guard is depth-dec >= 1 (rewrites.go:39)
-    css_rel = jnp.where(cfg[:, None], g["f_css_rel"][nsc, relc], -1)  # [F,Kc]
-    css_dec = g["f_css_dec"][nsc, relc]
-    css_probe = g["f_css_probe"][nsc, relc]
-    css_ok = live[:, None] & (css_rel >= 0) & (d[:, None] - css_dec >= 1)
+    if Kc:
+        css_rel = jnp.where(cfg[:, None], g["f_css_rel"][nsc, relc], -1)  # [F,Kc]
+        css_dec = g["f_css_dec"][nsc, relc]
+        css_probe = g["f_css_probe"][nsc, relc]
+        css_ok = live[:, None] & (css_rel >= 0) & (d[:, None] - css_dec >= 1)
     for k in range(Kc):
         cnode = _node_lookup(g, ns, obj, css_rel[:, k])
         found = found | (css_ok[:, k] & css_probe[:, k] & _member(g, cnode, subj))
@@ -286,16 +291,18 @@ def expand_phase(
         nd = _node_dirty(g, node)
         q_dirty = q_dirty.at[qc].max(exp_read & nd)
         exp_deg = jnp.where(nd | (node >= g["ov_nbase"]), 0, exp_deg)
-    css_need = (css_ok & live2[:, None] & (d[:, None] - css_dec - 1 >= 1)).astype(
-        jnp.int32
-    )
-    ttu_via = jnp.where(cfg[:, None], g["f_ttu_via"][nsc, relc], -1)  # [F,Kt]
-    ttu_tgt = g["f_ttu_tgt"][nsc, relc]
-    ttu_dec = g["f_ttu_dec"][nsc, relc]
-    # TTU guard is depth < 0 (rewrites.go:247) but children recurse at
-    # depth-dec-1 with the root <=0 guard, so rows only matter when
-    # d - dec >= 2
-    ttu_ok = live2[:, None] & (ttu_via >= 0) & (d[:, None] - ttu_dec >= 2)
+    if Kc:
+        css_need = (
+            css_ok & live2[:, None] & (d[:, None] - css_dec - 1 >= 1)
+        ).astype(jnp.int32)
+    if Kt:
+        ttu_via = jnp.where(cfg[:, None], g["f_ttu_via"][nsc, relc], -1)  # [F,Kt]
+        ttu_tgt = g["f_ttu_tgt"][nsc, relc]
+        ttu_dec = g["f_ttu_dec"][nsc, relc]
+        # TTU guard is depth < 0 (rewrites.go:247) but children recurse at
+        # depth-dec-1 with the root <=0 guard, so rows only matter when
+        # d - dec >= 2
+        ttu_ok = live2[:, None] & (ttu_via >= 0) & (d[:, None] - ttu_dec >= 2)
     ttu_node_cols = []
     ttu_deg_cols = []
     for k in range(Kt):
@@ -307,7 +314,8 @@ def expand_phase(
             q_dirty = q_dirty.at[qc].max(ttu_ok[:, k] & nd)
             deg_k = jnp.where(nd | (tn >= g["ov_nbase"]), 0, deg_k)
         ttu_deg_cols.append(deg_k)
-    ttu_nodes = jnp.stack(ttu_node_cols, axis=1)  # [F,Kt]
+    if Kt:
+        ttu_nodes = jnp.stack(ttu_node_cols, axis=1)  # [F,Kt]
 
     seg_len = jnp.stack(
         [exp_deg] + [css_need[:, k] for k in range(Kc)] + ttu_deg_cols, axis=1
@@ -323,59 +331,67 @@ def expand_phase(
     aps = jnp.clip(ap, 0, F - 1)
     src_ok = (ap >= 0) & fits[aps]
 
-    # -- segment decomposition per arena slot -------------------------------
-    cum_p = seg_cum[aps]  # [A, S]
-    S = 1 + Kc + Kt
-    seg_idx = jnp.clip(
-        jnp.sum((ao[:, None] >= cum_p).astype(jnp.int32), axis=1), 0, S - 1
-    )
-    prev_cum = jnp.where(
-        seg_idx > 0,
-        jnp.take_along_axis(cum_p, jnp.clip(seg_idx - 1, 0, S - 1)[:, None], 1)[:, 0],
-        0,
-    )
-    off = ao - prev_cum
+    if Kc or Kt:
+        # -- segment decomposition per arena slot ---------------------------
+        cum_p = seg_cum[aps]  # [A, S]
+        S = 1 + Kc + Kt
+        seg_idx = jnp.clip(
+            jnp.sum((ao[:, None] >= cum_p).astype(jnp.int32), axis=1), 0, S - 1
+        )
+        prev_cum = jnp.where(
+            seg_idx > 0,
+            jnp.take_along_axis(cum_p, jnp.clip(seg_idx - 1, 0, S - 1)[:, None], 1)[:, 0],
+            0,
+        )
+        off = ao - prev_cum
 
-    p_ns, p_obj, p_d = ns[aps], obj[aps], d[aps]
-    p_qid = qid[aps]
+        p_ns, p_obj, p_d = ns[aps], obj[aps], d[aps]
+        p_qid = qid[aps]
 
-    is_exp = src_ok & (seg_idx == 0)
-    is_css = src_ok & (seg_idx >= 1) & (seg_idx <= Kc)
-    css_k = jnp.clip(seg_idx - 1, 0, Kc - 1)
-    is_ttu = src_ok & (seg_idx > Kc)
-    ttu_k = jnp.clip(seg_idx - 1 - Kc, 0, Kt - 1)
+        is_exp = src_ok & (seg_idx == 0)
+        is_css = src_ok & (seg_idx >= 1) & (seg_idx <= Kc)
+        css_k = jnp.clip(seg_idx - 1, 0, Kc - 1)
+        is_ttu = src_ok & (seg_idx > Kc)
+        ttu_k = jnp.clip(seg_idx - 1 - Kc, 0, Kt - 1)
 
-    # edge gathers for expansion / ttu rows
-    rp = g["row_ptr"]
-    base_exp = rp[jnp.clip(node[aps], 0, rp.shape[0] - 2)]
-    ttu_node_p = jnp.take_along_axis(ttu_nodes[aps], ttu_k[:, None], 1)[:, 0]
-    base_ttu = rp[jnp.clip(ttu_node_p, 0, rp.shape[0] - 2)]
-    eidx = jnp.clip(
-        jnp.where(is_ttu, base_ttu, base_exp) + off, 0, g["edge_hi"].shape[0] - 1
-    )
-    # one packed gather for (ns, rel) + one for obj; div/mod decode is VPU
-    # arithmetic, each avoided gather is an arena-sized HBM read
-    e_hi, e_obj = g["edge_hi"][eidx], g["edge_obj"][eidx]
-    e_ns = jnp.where(e_hi >= 0, e_hi // R, -1)
-    e_rel = jnp.where(e_hi >= 0, e_hi % R, -1)
+        # edge gathers for expansion / ttu rows
+        rp = g["row_ptr"]
+        base_exp = rp[jnp.clip(node[aps], 0, rp.shape[0] - 2)]
+        ttu_node_p = jnp.take_along_axis(ttu_nodes[aps], ttu_k[:, None], 1)[:, 0]
+        base_ttu = rp[jnp.clip(ttu_node_p, 0, rp.shape[0] - 2)]
+        eidx = jnp.clip(
+            jnp.where(is_ttu, base_ttu, base_exp) + off, 0, g["edge_hi"].shape[0] - 1
+        )
+        e_ns, e_obj, e_rel = _edges(g, eidx)
 
-    css_rel_p = jnp.take_along_axis(css_rel[aps], css_k[:, None], 1)[:, 0]
-    css_dec_p = jnp.take_along_axis(css_dec[aps], css_k[:, None], 1)[:, 0]
-    ttu_tgt_p = jnp.take_along_axis(ttu_tgt[aps], ttu_k[:, None], 1)[:, 0]
-    ttu_dec_p = jnp.take_along_axis(ttu_dec[aps], ttu_k[:, None], 1)[:, 0]
+        css_rel_p = jnp.take_along_axis(css_rel[aps], css_k[:, None], 1)[:, 0]
+        css_dec_p = jnp.take_along_axis(css_dec[aps], css_k[:, None], 1)[:, 0]
+        ttu_tgt_p = jnp.take_along_axis(ttu_tgt[aps], ttu_k[:, None], 1)[:, 0]
+        ttu_dec_p = jnp.take_along_axis(ttu_dec[aps], ttu_k[:, None], 1)[:, 0]
 
-    ch_ns = jnp.where(is_css, p_ns, e_ns)
-    ch_obj = jnp.where(is_css, p_obj, e_obj)
-    ch_rel = jnp.select([is_css, is_ttu], [css_rel_p, ttu_tgt_p], e_rel)
-    ch_d = jnp.select(
-        [is_css, is_ttu],
-        [p_d - css_dec_p - 1, p_d - ttu_dec_p - 1],
-        p_d - 1,
-    )
-    # expansion children skip the direct re-check — the EXISTS bit just
-    # tested it (engine.go:161); batched CSS children likewise
-    # (rewrites.go:86); TTU children do not (rewrites.go:281-286)
-    ch_skip = is_exp | is_css
+        ch_ns = jnp.where(is_css, p_ns, e_ns)
+        ch_obj = jnp.where(is_css, p_obj, e_obj)
+        ch_rel = jnp.select([is_css, is_ttu], [css_rel_p, ttu_tgt_p], e_rel)
+        ch_d = jnp.select(
+            [is_css, is_ttu],
+            [p_d - css_dec_p - 1, p_d - ttu_dec_p - 1],
+            p_d - 1,
+        )
+        # expansion children skip the direct re-check — the EXISTS bit just
+        # tested it (engine.go:161); batched CSS children likewise
+        # (rewrites.go:86); TTU children do not (rewrites.go:281-286)
+        ch_skip = is_exp | is_css
+    else:  # no rewrite segments: every child is an expansion edge
+        p_d, p_qid = d[aps], qid[aps]
+        off = ao
+        is_exp = src_ok
+        rp = g["row_ptr"]
+        base_exp = rp[jnp.clip(node[aps], 0, rp.shape[0] - 2)]
+        ch_ns, ch_obj, ch_rel = _edges(
+            g, jnp.clip(base_exp + off, 0, g["edge_hi"].shape[0] - 1)
+        )
+        ch_d = p_d - 1
+        ch_skip = is_exp
     ch_qid = jnp.where(src_ok, p_qid, -1)
 
     # width truncation applies to recursion only (engine.go:141-150)
@@ -407,6 +423,17 @@ def expand_phase(
     return children, q_found, q_over, q_dirty
 
 
+def _edges(g, eidx):
+    """(ns, obj, rel) of the CSR edges at ``eidx``: one packed gather for
+    (ns, rel) + one for obj; the div/mod decode is VPU arithmetic, each
+    avoided gather is an arena-sized HBM read."""
+    R = g["f_direct_ok"].shape[1]
+    e_hi, e_obj = g["edge_hi"][eidx], g["edge_obj"][eidx]
+    e_ns = jnp.where(e_hi >= 0, e_hi // R, -1)
+    e_rel = jnp.where(e_hi >= 0, e_hi % R, -1)
+    return e_ns, e_obj, e_rel
+
+
 def _pack_bits(n: int) -> int:
     return max(int(n - 1).bit_length(), 1)
 
@@ -419,9 +446,13 @@ def pack_phase(
     frontier: int,
     ns_dim: int = 0,
     rel_dim: int = 0,
+    merge_arena: int = 0,
 ) -> Tuple[Dict[str, jax.Array], jax.Array]:
     """Dedup by (query, node) — max depth, min skip, max force — and compact
     the survivors into the next frontier.  Returns (frontier cols, q_over).
+    ``merge_arena`` sizes the hash merge for that many children instead of
+    ``children``'s own length: a narrow rung of a folded level passes its
+    level's full arena, so its children merge as the full level's would.
 
     When (qid, ns, rel) fit one int32 (pass ``ns_dim``/``rel_dim``, the
     padded table dims), dedup runs as **linear hash-scatter merge** instead
@@ -440,7 +471,8 @@ def pack_phase(
     relb = _pack_bits(rel_dim) if rel_dim else 31
     if qb + nsb + relb <= 31:
         return _pack_scatter(
-            children, q_found, q_over, frontier=frontier, nsb=nsb, relb=relb
+            children, q_found, q_over, frontier=frontier, nsb=nsb, relb=relb,
+            merge_arena=merge_arena,
         )
     return _pack_sort(children, q_found, q_over, frontier=frontier)
 
@@ -453,11 +485,12 @@ def _pack_scatter(
     frontier: int,
     nsb: int,
     relb: int,
+    merge_arena: int = 0,
 ) -> Tuple[Dict[str, jax.Array], jax.Array]:
     F = frontier
     Q = q_found.shape[0]
     A = children["qid"].shape[0]
-    H = 1 << max((2 * A - 1).bit_length(), 4)
+    H = 1 << max((2 * max(A, merge_arena) - 1).bit_length(), 4)
     alive = (children["qid"] >= 0) & ~q_found[jnp.clip(children["qid"], 0, Q - 1)]
     k1 = (
         (children["qid"] << (nsb + relb)) | (children["ns"] << relb) | children["rel"]
@@ -589,12 +622,21 @@ def step_impl(
     max_width: int = 100,
 ) -> Dict[str, jax.Array]:
     """One whole level: expand + pack (single-shard path)."""
+    return _level(g, s, arena=arena, nxt_f=frontier, max_width=max_width)
+
+
+def _level(g, s, *, arena, nxt_f, max_width, probe_only=False,
+           merge_arena=0, rewrites=True):
+    """Expand ``s`` into ``arena`` children and pack them into a frontier
+    of ``nxt_f`` slots: the next level's state."""
     NS, R = g["f_direct_ok"].shape
     children, q_found, q_over, q_dirty = expand_phase(
-        g, s, arena=arena, max_width=max_width
+        g, s, arena=arena, max_width=max_width, probe_only=probe_only,
+        rewrites=rewrites,
     )
     nxt, q_over = pack_phase(
-        children, q_found, q_over, frontier=frontier, ns_dim=NS, rel_dim=R
+        children, q_found, q_over, frontier=nxt_f, ns_dim=NS, rel_dim=R,
+        merge_arena=merge_arena,
     )
     return dict(
         nxt, q_found=q_found, q_over=q_over, q_dirty=q_dirty,
@@ -663,8 +705,14 @@ def _fused_body(
 ) -> "FastResult":
     """All BFS levels in ONE device program: one dispatch per batch instead
     of one per level (each dispatch costs real host-link latency), with the
-    per-level buffer sizes of ``schedule``."""
-    NS, R = g["f_direct_ok"].shape
+    per-level buffer sizes of ``schedule``.
+
+    A run of equal levels (``folded_runs``) is one loop whose body runs
+    each level at the smallest rung that holds its live items
+    (``_rung_level``), and the probe-only level after such a run probes
+    the same way.  Returns the verdicts and int32[len(schedule) +
+    folded_levels(schedule)]: the live items entering each level, then
+    the rung (``RUNGS`` index) each folded level ran at."""
     s = _init_state(
         q_ns, q_obj, q_rel, q_subj, q_depth, act, frontier=schedule[0][0]
     )
@@ -673,26 +721,225 @@ def _fused_body(
     # decreases per level).  Callers pass rest_depth <= max_depth anyway
     # (engine.go:82-84 global-cap precedence); clamp defensively.
     s["f_depth"] = jnp.minimum(s["f_depth"], len(schedule))
+    q = q_ns.shape[0]
+    last = len(schedule) - 1
+    runs = dict(folded_runs(schedule))
     occ = []  # live items ENTERING each level (occ[0] = roots)
-    for i, (f, a) in enumerate(schedule):
+    rungs = []
+    i = 0
+    while i <= last:
+        f, a = schedule[i]
+        if i in runs:
+            hi = runs[i]
+            with jax.named_scope(f"level{i}-{hi - 1}"):
+                s, run_occ, run_rungs = _folded_run(
+                    g, s, levels=hi - i, f=f, a=a, q=q, max_width=max_width
+                )
+            occ.extend(run_occ[j] for j in range(hi - i))
+            rungs.append(run_rungs)
+            i = hi
+            continue
         with jax.named_scope(f"level{i}"):
             occ.append(jnp.sum((s["f_qid"] >= 0).astype(jnp.int32)))
-            nxt_f = schedule[i + 1][0] if i + 1 < len(schedule) else 1
-            children, q_found, q_over, q_dirty = expand_phase(
-                g, s, arena=a, max_width=max_width,
-                probe_only=(i == len(schedule) - 1),
-            )
-            nxt, q_over = pack_phase(
-                children, q_found, q_over, frontier=nxt_f, ns_dim=NS,
-                rel_dim=R,
-            )
-            s = dict(
-                nxt, q_found=q_found, q_over=q_over, q_dirty=q_dirty,
-                q_subj=s["q_subj"],
-            )
-    return FastResult(
-        found=s["q_found"], over=s["q_over"], dirty=s["q_dirty"]
-    ), jnp.stack(occ)
+            if i == last and last in runs.values():
+                s = dict(s, q_found=_probe_rungs(
+                    g, s, n=occ[-1], q=q, f=f, max_width=max_width
+                ))
+            else:
+                level = functools.partial(
+                    _level, g, arena=a,
+                    nxt_f=schedule[i + 1][0] if i < last else 1,
+                    max_width=max_width, probe_only=(i == last),
+                )
+                # where the schedule folds (a deep walk), the levels
+                # outside the loop drop the rewrite columns too where no
+                # item has one; a schedule that folds nothing keeps its
+                # program
+                s = _by_rewrites(g, s, level) if runs else level(s)
+        i += 1
+    res = FastResult(found=s["q_found"], over=s["q_over"], dirty=s["q_dirty"])
+    if rungs:
+        return res, jnp.concatenate([jnp.stack(occ), *rungs])
+    return res, jnp.stack(occ)
+
+
+#: what a folded level ran at: a frontier of a quarter of the wave's rows,
+#: of its rows, or the level's own (keto_fused_fast_rung_levels_total{rung})
+RUNGS = ("quarter", "roots", "full")
+_FULL = RUNGS.index("full")
+_FRONTIER_COLS = ("f_qid", "f_ns", "f_obj", "f_rel", "f_depth", "f_skip",
+                  "f_force")
+
+
+#: the fewest equal levels that run as a loop: its body holds five
+#: variants of the level (two narrow rungs, each with and without the
+#: rewrite columns, and the full size), so a shorter run would compile
+#: larger looped than unrolled; every schedule of depth 7 or less stays
+#: unrolled, as it was
+FOLD_MIN = 6
+
+
+def folded_runs(schedule) -> Tuple[Tuple[int, int], ...]:
+    """``(first, end)`` of each run of ``FOLD_MIN`` or more consecutive
+    non-final levels with one (frontier, arena) that ``_fused_body`` runs
+    as one loop.  A run starts at level 1 or later (level 0 holds the
+    roots in their rows; a later level's items are packed into the
+    frontier's prefix, which the rungs rely on) and its last level packs
+    into a frontier of its own size."""
+    out = []
+    last = len(schedule) - 1
+    i = 1
+    while i < last:
+        end = i + 1
+        while end < last and schedule[end] == schedule[i]:
+            end += 1
+        first = i
+        i = end
+        if schedule[end][0] != schedule[first][0]:
+            end -= 1  # its successor's frontier differs: unrolled
+        if end - first >= FOLD_MIN:
+            out.append((first, end))
+    return tuple(out)
+
+
+def folded_levels(schedule) -> int:
+    """How many levels of ``schedule`` run inside a loop: the rung codes
+    after the occupancy counts of ``_fused_body``'s second result."""
+    return sum(end - first for first, end in folded_runs(schedule))
+
+
+def _narrow_rungs(q: int, f: int, a: int):
+    """``(code, frontier, arena)`` of the rungs narrower than a level of
+    ``f`` / ``a``: a quarter of the wave's ``q`` rows and all of them, each
+    with twice its frontier of arena (the schedule's ratio after level 0)."""
+    out = []
+    for code, r in ((RUNGS.index("quarter"), q // 4), (RUNGS.index("roots"), q)):
+        if 1 <= r < f and (not out or r > out[-1][1]):
+            out.append((code, r, min(2 * r, a)))
+    return tuple(out)
+
+
+def _smallest_holding(n, sizes):
+    """Index of the first of the ascending ``sizes`` that is >= ``n``
+    (``len(sizes)`` when none is)."""
+    k = jnp.int32(0)
+    for r in sizes:
+        k = k + (n > r).astype(jnp.int32)
+    return k
+
+
+def _folded_run(g, s, *, levels, f, a, q, max_width):
+    """``levels`` equal levels of frontier ``f`` and arena ``a`` as one
+    loop over ``_rung_level``; returns the state after them, and for each
+    level the live items entering it and the rung it ran at."""
+
+    def body(j, carry):
+        st, occ, rung = carry
+        n = jnp.sum((st["f_qid"] >= 0).astype(jnp.int32))
+        st, code = _rung_level(g, st, n=n, f=f, a=a, q=q, max_width=max_width)
+        return st, occ.at[j].set(n), rung.at[j].set(code)
+
+    zeros = jnp.zeros((levels,), jnp.int32)
+    return jax.lax.fori_loop(0, levels, body, (s, zeros, zeros))
+
+
+def _rung_level(g, s, *, n, f, a, q, max_width):
+    """One folded level on a state whose ``n`` live items fill the prefix of
+    its ``f`` slots, at the smallest rung that holds them.  A narrow rung
+    expands the prefix alone into its own arena and packs into all ``f``
+    slots, merging as the full level does, so its next state is the full
+    level's.  Where its arena or the frontier cannot hold every child (an
+    over bit the attempt would set), the level runs again at full size
+    from the same state and nothing of the attempt is kept.  Returns the
+    next state and the rung's ``RUNGS`` index."""
+
+    def full(st):
+        with jax.named_scope("rung/full"):
+            return _level(g, st, arena=a, nxt_f=f, max_width=max_width)
+
+    narrow = _narrow_rungs(q, f, a)
+    if not narrow:
+        return full(s), jnp.int32(_FULL)
+
+    def attempt(code, r, ra, rewrites):
+        def run(st):
+            with jax.named_scope(f"rung/{RUNGS[code]}"):
+                sub = {c: st[c][:r] for c in _FRONTIER_COLS}
+                out = _level(
+                    g, dict(st, **sub, q_over=jnp.zeros_like(st["q_over"])),
+                    arena=ra, nxt_f=f, max_width=max_width, merge_arena=a,
+                    rewrites=rewrites,
+                )
+            return dict(out, q_over=st["q_over"]), ~jnp.any(out["q_over"])
+        return run
+
+    def skip(st):
+        return st, jnp.zeros((), bool)
+
+    # each narrow rung twice: without the rewrite columns where no item
+    # has one (a chain of subject sets), then with them
+    k = _smallest_holding(n, [r for _, r, _ in narrow])
+    pick = jnp.where(
+        k < len(narrow), k + len(narrow) * has_rewrites(g, s), 2 * len(narrow)
+    )
+    tried, ok = jax.lax.switch(
+        pick,
+        [attempt(*x, rewrites=False) for x in narrow]
+        + [attempt(*x, rewrites=True) for x in narrow] + [skip],
+        s,
+    )
+    out = jax.lax.cond(ok, lambda st: tried, full, s)
+    codes = jnp.array([c for c, _, _ in narrow] + [_FULL], jnp.int32)
+    return out, jnp.where(ok, codes[k], _FULL)
+
+
+def _by_rewrites(g, s, level):
+    """``level(s, rewrites=...)`` without the rewrite columns where no item
+    of ``s`` has one."""
+    return jax.lax.cond(
+        has_rewrites(g, s),
+        functools.partial(level, rewrites=True),
+        functools.partial(level, rewrites=False),
+        s,
+    )
+
+
+def has_rewrites(g, s):
+    """Has any item of ``s`` a computed-subject-set or tuple-to-userset
+    column?  Where none has, ``expand_phase(rewrites=False)`` gives the
+    same children and bits with the item's own node and membership
+    lookups alone."""
+    NS, R = g["f_direct_ok"].shape
+    ns, rel = s["f_ns"], s["f_rel"]
+    cfg = (s["f_qid"] >= 0) & (ns >= 0) & (ns < NS) & (rel >= 0) & (rel < R)
+    nsc, relc = jnp.clip(ns, 0, NS - 1), jnp.clip(rel, 0, R - 1)
+    rw = jnp.any(g["f_css_rel"][nsc, relc] >= 0, axis=1) | jnp.any(
+        g["f_ttu_via"][nsc, relc] >= 0, axis=1
+    )
+    return jnp.any(cfg & rw)
+
+
+def _probe_rungs(g, s, *, n, q, f, max_width):
+    """The probe-only level after a folded run: its probes on the prefix of
+    the smallest rung that holds the ``n`` live items, without the rewrite
+    columns where no item has one.  Returns q_found."""
+
+    def probe(r, rewrites):
+        def run(st):
+            sub = {c: st[c][:r] for c in _FRONTIER_COLS}
+            return expand_phase(
+                g, dict(st, **sub), arena=PROBE_ONLY_ARENA,
+                max_width=max_width, probe_only=True, rewrites=rewrites,
+            )[1]
+        return run
+
+    sizes = [r for _, r, _ in _narrow_rungs(q, f, PROBE_ONLY_ARENA)] + [f]
+    k = _smallest_holding(n, sizes[:-1])
+    return jax.lax.switch(
+        jnp.where(has_rewrites(g, s), k + len(sizes), k),
+        [probe(r, False) for r in sizes] + [probe(r, True) for r in sizes],
+        s,
+    )
 
 
 _run_fused = functools.partial(

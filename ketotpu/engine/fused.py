@@ -37,7 +37,8 @@ Exactly ONE D2H fetch returns everything the collector needs: per-row
 verdict codes AND per-tier attribution masks packed into one int32 bit
 field, concatenated with the two occupancy vectors the adaptive
 scheduler feeds on.  Layout of the returned int32[Q + F + G] array
-(Q = padded wave rows, F = len(fast_sched), G = general occ length;
+(Q = padded wave rows, F = len(fast_sched) occupancy counts plus
+``fp.folded_levels(fast_sched)`` rung codes, G = general occ length;
 the general lanes are inside the program only: their bits come back
 on the wave rows they were gathered from):
 

@@ -243,6 +243,10 @@ class DeviceCheckEngine:
         # subject-set roots of batch_expand by what answered them: the
         # first rung of level capacities, the full rung, or the oracle
         self.expand_roots = {"first": 0, "full": 0, "oracle": 0}
+        # the fast BFS's folded levels by the rung they ran at
+        # (fastpath.RUNGS), counted at collect from the codes a program
+        # returns after its occupancy counts
+        self.fast_rung_levels = dict.fromkeys(fp.RUNGS, 0)
         self.projection_build_s = 0.0  # host-side snapshot build
         self.projection_upload_s = 0.0  # device upload (blocked)
         self._expand_extra = None  # lazily shipped expand tables
@@ -1314,6 +1318,14 @@ class DeviceCheckEngine:
                 return (1, *rung)
         return None  # worst case: the F_MULT default
 
+    def _take_fast_occ(self, occ: np.ndarray, levels: int) -> None:
+        """A fast tier's returned occupancy: the counts of its ``levels``
+        levels feed the EMA, the rung codes after them are counted."""
+        self._update_occ(occ[:levels])
+        ran = np.bincount(occ[levels:], minlength=len(fp.RUNGS))
+        for rung, n in zip(fp.RUNGS, ran):
+            self.fast_rung_levels[rung] += int(n)
+
     def _update_occ(self, occ: np.ndarray) -> None:
         """Fold one batch's per-level occupancy counts into the EMA
         (normalized by the batch's active-root count, occ[0])."""
@@ -1695,7 +1707,9 @@ class DeviceCheckEngine:
                      leo_elts=leo_dev["elts"], leo_hops=leo_dev["hops"])
         wave.meta = {
             "has_leo": has_leo,
-            "flen": len(fast_sched) if fast_sched is not None else 0,
+            "fast_levels": len(fast_sched) if fast_sched is not None else 0,
+            "flen": len(fast_sched) + fp.folded_levels(fast_sched)
+                    if fast_sched is not None else 0,
             "glen": (len(gen[0]) + 2 + len(gen[2])) if gen is not None
                     else 0,
             "gen_fast_b": gen[1] if gen is not None else 0,
@@ -1977,7 +1991,7 @@ class DeviceCheckEngine:
         with self._fetch_span("check_collect_sync"):
             f = self._fast_bits(wave.fast, n)
             if wave.occ is not None:
-                self._update_occ(np.asarray(wave.occ))
+                self._take_fast_occ(np.asarray(wave.occ), self.max_depth)
         again = ~(wave.err | wave.general) & wv.fast_retry_rows(f)
         self.overflow_rows["fast"] += int(again.sum())
         if boosted and again.any():
@@ -2028,7 +2042,7 @@ class DeviceCheckEngine:
         # occupancy EMA feeds (absent tiers ship no occupancy at all)
         f_end = wave.qpad + meta["flen"]
         if meta["flen"]:
-            self._update_occ(packed[wave.qpad:f_end])
+            self._take_fast_occ(packed[wave.qpad:f_end], meta["fast_levels"])
         if meta["glen"]:
             self._update_gen_occ(
                 packed[f_end:f_end + meta["glen"]], meta["gen_fast_b"])

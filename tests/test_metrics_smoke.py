@@ -233,6 +233,14 @@ def test_expand_rung_vocabulary(scrape):
     assert roots["first"] >= 1 and roots["full"] == roots["oracle"] == 0
 
 
+def test_fast_rung_vocabulary(scrape):
+    """Each rung a folded level of the fast BFS can run at is on the
+    scrape (the smoke's depth-5 wave folds none)."""
+    text = scrape["metrics_text"]
+    for rung in ("quarter", "roots", "full"):
+        assert f'keto_fused_fast_rung_levels_total{{rung="{rung}"}}' in text
+
+
 def test_leopard_rows_vocabulary(scrape):
     """Every row the closure index is asked about lands in one outcome of
     ``keto_leopard_rows_total``; each outcome is on the scrape."""

@@ -832,13 +832,14 @@ class TestWorkerMode:
                        "mesh_devices": 0, "mesh_axis": "shard",
                        "coalesce_ms": 25.0},
         }))
+        n = 12
         owner.store().migrate_up()
         owner.store().write_relation_tuples(
             *[RelationTuple.from_string(s) for s in [
                 "Group:dev#members@bob",
                 "Folder:keto#viewers@Group:dev#members",
                 "File:keto/README.md#parents@Folder:keto",
-            ]]
+            ] + [f"Group:dev#members@u{i}" for i in range(n)]]
         )
         owner.init()
         eng = owner.check_engine()
@@ -846,17 +847,20 @@ class TestWorkerMode:
         sock = str(tmp_path / "wc.sock")
         host = EngineHostServer(owner, sock).start()
         try:
-            q = RelationTuple.from_string("File:keto/README.md#view@bob")
             # warm the engine outside the measured window (first dispatch
             # compiles; a slow compile would serialize the waves)
-            RemoteCheckEngine(sock).check(q)
+            RemoteCheckEngine(sock).check(
+                RelationTuple.from_string("File:keto/README.md#view@bob"))
             w0, c0 = eng.waves, eng.coalesced
-            n = 12
             results = [None] * n
             # one RemoteCheckEngine per thread = one socket connection
-            # each, like N worker serving threads
+            # each, like N worker serving threads; each asks its own
+            # check, so neither the cache nor an identical in-flight check
+            # answers it in place of a wave slot
             def one(i):
-                results[i] = RemoteCheckEngine(sock).check(q)
+                results[i] = RemoteCheckEngine(sock).check(
+                    RelationTuple.from_string(
+                        f"File:keto/README.md#view@u{i}"))
 
             threads = [
                 threading.Thread(target=one, args=(i,)) for i in range(n)

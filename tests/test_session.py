@@ -286,7 +286,11 @@ class TestLaneWire:
         _hello(sock, rfile)
         assert broker.active() == base + 1
         _send_block(sock, 0, [c for c, _ in CASES])
-        sock.close()  # no end frame, verdicts possibly still in flight
+        # no end frame, verdicts possibly still in flight.  The reader
+        # holds a reference to the socket: close it too, or the close is
+        # deferred and the lane sees no hangup, only its idle expiry
+        rfile.close()
+        sock.close()
         deadline = time.monotonic() + 30.0
         while broker.active() != base:
             assert time.monotonic() < deadline, \
